@@ -56,10 +56,36 @@ STALLS = dict(stall_rate_per_us=2.5e-4, stall_mean_us=150.0)
 HEDGE_LADDER = (0.0, 80.0, 40.0, 20.0)
 
 
-def _fleet_env(duration_us: float):
+def fleet_env(duration_us: float):
     from repro.runtime import SimRunConfig
 
     return SimRunConfig(duration_us=duration_us, **STALLS)
+
+
+def ladder_grid(fleet):
+    """One fleet at the per-host load ``RHO``, over the hedge ladder."""
+    from repro.runtime import FleetGrid
+
+    return FleetGrid.product(
+        fleet=fleet, t_s_us=(T_S_US,), t_l_us=(T_L_US,),
+        rate_mpps=(RHO * MU_MPPS * fleet.n_hosts,), m=(M,),
+        hedge_deadline_us=HEDGE_LADDER)
+
+
+def scale_sweep(quick: bool):
+    """The whole-cluster sweep run as ONE jit call: 1000 hosts x 8
+    operating points (hedge ladder x 2 loads).  Returns the grid, its
+    environment and its slot width."""
+    from repro.runtime import FleetConfig, FleetGrid
+
+    n_hosts = 100 if quick else 1000
+    duration, slot_us = (2_000.0, 1.0) if quick else (5_000.0, 1.0)
+    fgrid = FleetGrid.product(
+        fleet=FleetConfig(n_hosts=n_hosts), t_s_us=(T_S_US,),
+        t_l_us=(T_L_US,), m=(M,),
+        rate_mpps=(0.35 * MU_MPPS * n_hosts, 0.55 * MU_MPPS * n_hosts),
+        hedge_deadline_us=HEDGE_LADDER)
+    return fgrid, fleet_env(duration), slot_us
 
 
 def _busy_poll_mean_us(cfg) -> float:
@@ -73,7 +99,6 @@ def _busy_poll_mean_us(cfg) -> float:
 def fleet_bench(quick: bool = False) -> ROWS:
     from repro.runtime import (
         FleetConfig,
-        FleetGrid,
         hedged_latency_quantile,
         simulate_fleet,
     )
@@ -81,7 +106,7 @@ def fleet_bench(quick: bool = False) -> ROWS:
     duration = 20_000.0 if quick else 60_000.0
     slot_us = 1.0 if quick else 0.5
     sizes = (4, 16) if quick else (4, 16, 64)
-    cfg = _fleet_env(duration)
+    cfg = fleet_env(duration)
     tail_prob = min(STALLS["stall_rate_per_us"] * STALLS["stall_mean_us"],
                     0.5)
     tail_scale = STALLS["stall_mean_us"]
@@ -109,10 +134,7 @@ def fleet_bench(quick: bool = False) -> ROWS:
             f"cpu_cores={H};spin=True"))
 
         for lb, make in lbs.items():
-            fgrid = FleetGrid.product(
-                fleet=make(H), t_s_us=(T_S_US,), t_l_us=(T_L_US,),
-                rate_mpps=(RHO * MU_MPPS * H,), m=(M,),
-                hedge_deadline_us=HEDGE_LADDER)
+            fgrid = ladder_grid(make(H))
             fs = simulate_fleet(fgrid, cfg, slot_us=slot_us)
             for i in range(len(fs)):
                 d = float(fgrid.hedge_deadline_us[i])
@@ -147,14 +169,9 @@ def fleet_bench(quick: bool = False) -> ROWS:
     # scale row: a whole-cluster sweep in ONE jit call — 1000 hosts x
     # 8 operating points (hedge ladder x 2 loads), point axis sharded
     # across however many devices are visible
-    H_big = 100 if quick else 1000
-    dur_big, slot_big = (2_000.0, 1.0) if quick else (5_000.0, 1.0)
-    cfg_big = _fleet_env(dur_big)
-    fgrid = FleetGrid.product(
-        fleet=FleetConfig(n_hosts=H_big), t_s_us=(T_S_US,),
-        t_l_us=(T_L_US,), m=(M,),
-        rate_mpps=(0.35 * MU_MPPS * H_big, 0.55 * MU_MPPS * H_big),
-        hedge_deadline_us=HEDGE_LADDER)
+    fgrid, cfg_big, slot_big = scale_sweep(quick)
+    H_big = fgrid.fleet.n_hosts
+    dur_big = cfg_big.duration_us
     t0 = time.time()
     fs = simulate_fleet(fgrid, cfg_big, slot_us=slot_big)
     np.asarray(fs.serviced)            # block on the device computation

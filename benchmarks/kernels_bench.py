@@ -1,7 +1,7 @@
-"""Kernel substrate benchmark: us/call of the jnp reference paths on this
-host (CPU) + interpret-mode kernel-vs-oracle max error.  Wall-clock kernel
-timing is only meaningful on real TPU; the CPU numbers track the substrate
-the engine drives and catch regressions."""
+"""Kernel substrate benchmark: us/call of the jnp reference paths plus the
+Pallas kernel's max error against them.  The kernel is compiled on a TPU
+and interpreted elsewhere (``repro.kernels.backend``); wall-clock times
+mean something only on the chip."""
 
 from __future__ import annotations
 
@@ -39,7 +39,7 @@ def kernels(quick: bool = False):
     kk = flash_attention(q, k, v, block_q=128, block_k=128)
     rr = flash_attention(q, k, v, use_kernel=False)
     err = float(np.abs(np.asarray(kk) - np.asarray(rr)).max())
-    rows.append((f"kernels/flash_attention_s{s}", us, f"interp_max_err={err:.2e}"))
+    rows.append((f"kernels/flash_attention_s{s}", us, f"kernel_max_err={err:.2e}"))
 
     # decode attention
     t = 2048 if quick else 8192
@@ -51,7 +51,7 @@ def kernels(quick: bool = False):
     kk = decode_attention(q1, k1, v1, pos, block_k=512)
     rr = decode_attention(q1, k1, v1, pos, use_kernel=False)
     err = float(np.abs(np.asarray(kk) - np.asarray(rr)).max())
-    rows.append((f"kernels/decode_attention_t{t}", us, f"interp_max_err={err:.2e}"))
+    rows.append((f"kernels/decode_attention_t{t}", us, f"kernel_max_err={err:.2e}"))
 
     # ssd scan
     L = 512 if quick else 1024
@@ -65,5 +65,5 @@ def kernels(quick: bool = False):
     yr, hr = ssd_scan(x, dt, a, bm, cm, chunk=128, use_kernel=False)
     err = float(max(np.abs(np.asarray(yk) - np.asarray(yr)).max(),
                     np.abs(np.asarray(hk) - np.asarray(hr)).max()))
-    rows.append((f"kernels/ssd_scan_L{L}", us, f"interp_max_err={err:.2e}"))
+    rows.append((f"kernels/ssd_scan_L{L}", us, f"kernel_max_err={err:.2e}"))
     return rows

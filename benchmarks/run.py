@@ -141,4 +141,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch import jax_cache
+
+    jax_cache.enable()
     main()

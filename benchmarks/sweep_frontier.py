@@ -57,26 +57,29 @@ NOISY_TARGET_MEAN_LAT_US = 30.0
 NOISY_MAX_LOSS = 0.05
 
 
+def lattice(quick: bool) -> dict:
+    """The (T_S x T_L x M x load x seed) calibration lattice, its
+    simulated duration and slot width.  The full one is 2,016 points of
+    50 ms at 0.5 us slots."""
+    if quick:
+        return dict(t_s_grid=np.linspace(4.0, 60.0, 8),
+                    t_l_grid=np.asarray([120.0, 500.0]), m_grid=(2, 3),
+                    rhos=np.asarray([0.15, 0.35, 0.55, 0.75]), seeds=(0,),
+                    duration_us=30_000.0, slot_us=1.0)
+    return dict(t_s_grid=np.linspace(3.0, 80.0, 14),
+                t_l_grid=np.asarray([120.0, 250.0, 500.0, 900.0]),
+                m_grid=(2, 3, 4),
+                rhos=np.asarray([0.1, 0.25, 0.4, 0.55, 0.7, 0.85]),
+                seeds=(0, 1), duration_us=50_000.0, slot_us=0.5)
+
+
 def _sweep(quick: bool, noisy: bool = False):
     from repro.runtime import SimRunConfig, SweepGrid, simulate_batch
 
-    if quick:
-        t_s_grid = np.linspace(4.0, 60.0, 8)
-        t_l_grid = np.asarray([120.0, 500.0])
-        m_grid = (2, 3)
-        rhos = np.asarray([0.15, 0.35, 0.55, 0.75])
-        seeds = (0,)
-        duration = 30_000.0
-        slot_us = 1.0
-    else:
-        t_s_grid = np.linspace(3.0, 80.0, 14)
-        t_l_grid = np.asarray([120.0, 250.0, 500.0, 900.0])
-        m_grid = (2, 3, 4)
-        rhos = np.asarray([0.1, 0.25, 0.4, 0.55, 0.7, 0.85])
-        seeds = (0, 1)
-        duration = 50_000.0
-        slot_us = 0.5
-    cfg = SimRunConfig(duration_us=duration,
+    lat = lattice(quick)
+    t_s_grid, t_l_grid, m_grid = lat["t_s_grid"], lat["t_l_grid"], lat["m_grid"]
+    rhos, seeds, slot_us = lat["rhos"], lat["seeds"], lat["slot_us"]
+    cfg = SimRunConfig(duration_us=lat["duration_us"],
                        **(NOISY_ENV if noisy else {}))
     grid = SweepGrid.product(t_s_us=t_s_grid, t_l_us=t_l_grid, m=m_grid,
                              rate_mpps=rhos * MU_MPPS, seeds=seeds)

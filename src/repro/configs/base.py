@@ -2,8 +2,8 @@
 
 Every architecture is a ``ModelConfig``; the four assigned input shapes are
 ``ShapeConfig``s.  ``reduced()`` produces the family-preserving small config
-used by CPU smoke tests (full configs are only ever lowered via the dry-run,
-never allocated).
+used by CPU smoke tests; full configs are allocated only on a chip
+(``chip_smoke.py`` serves gemma-2b at published widths).
 """
 
 from __future__ import annotations

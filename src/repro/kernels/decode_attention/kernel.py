@@ -1,9 +1,9 @@
 """Single-token GQA decode attention over a long KV cache (Pallas TPU).
 
 The serve_step hot loop: one query token per sequence against a KV cache
-of up to 524288 positions.  Memory-bound by construction (every KV byte is
-read once), so the kernel's job is streaming the cache through VMEM in
-(bk, hd) tiles at full HBM bandwidth while accumulating the online softmax.
+of up to 524288 positions.  Memory-bound: the kernel streams the cache
+through VMEM in (bk, KV, hd) blocks, once per query head, while
+accumulating the online softmax.
 
   grid = (batch, q_head, T/bk); kv-block innermost/sequential.
   Per-sequence valid length arrives via scalar prefetch (SMEM) — tokens
@@ -22,14 +22,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import resolve_interpret
+
 NEG_INF = -2.3819763e38
+
+
+def _kv_head(ref, kvh):
+    """(bk, hd) fp32 tile of KV head ``kvh`` from a (1, bk, KV, hd) block.
+    Each head is read at a static offset and the dynamic one is selected
+    arithmetically: packed dtypes store pairs of heads in one sublane, so
+    a dynamic index along that axis does not lower."""
+    kv = ref.shape[2]
+    out = ref[0, :, 0].astype(jnp.float32)
+    for i in range(1, kv):
+        out = jnp.where(kvh == i, ref[0, :, i].astype(jnp.float32), out)
+    return out
 
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *,
                    scale: float, softcap: float, window: int,
-                   bk: int, kv_blocks: int):
+                   bk: int, kv_blocks: int, group: int):
     b = pl.program_id(0)
+    kvh = pl.program_id(1) // group
     kj = pl.program_id(2)
 
     @pl.when(kj == 0)
@@ -38,9 +53,9 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)               # (1, hd)
-    k = k_ref[0, :, 0].astype(jnp.float32)         # (bk, hd)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    q = q_ref[0, 0].astype(jnp.float32)            # (1, hd)
+    k = _kv_head(k_ref, kvh)                       # (bk, hd)
+    v = _kv_head(v_ref, kvh)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -66,17 +81,20 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(kj == kv_blocks - 1)
     def _finalize():
         l = l_ref[0, 0]
-        o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-                    ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+                       ).astype(o_ref.dtype)
 
 
 def decode_attention_pallas(q, k, v, pos, *, softcap: float = 0.0,
                             window: int = 0, scale: float | None = None,
-                            block_k: int = 1024, interpret: bool = True):
+                            block_k: int = 1024,
+                            interpret: bool | None = None):
     """q: (B, H, hd); k, v: (B, T, KV, hd); pos: (B,) int32.
 
-    Returns (B, H, hd).  KV layout is the cache layout (seq-major) — the
-    kernel transposes per-tile via the index map, not in HBM.
+    Returns (B, H, hd).  KV layout is the cache layout (seq-major).  The
+    TPU tiles the last two dims of every block, so q travels as
+    (B, H, 1, hd) and each K/V block spans the whole (KV, hd) tail; the
+    kernel picks its KV head (q head // group) inside the block.
     """
     b, h, hd = q.shape
     t, kv = k.shape[1], k.shape[2]
@@ -88,19 +106,18 @@ def decode_attention_pallas(q, k, v, pos, *, softcap: float = 0.0,
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, softcap=softcap, window=window,
-        bk=bk, kv_blocks=kv_blocks)
+        bk=bk, kv_blocks=kv_blocks, group=group)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, h, kv_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b_, h_, j, pos_: (b_, h_, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b_, h_, j, pos_, g=group: (b_, j, h_ // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b_, h_, j, pos_, g=group: (b_, j, h_ // g, 0)),
+            pl.BlockSpec((1, 1, 1, hd), lambda b_, h_, j, pos_: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, bk, kv, hd), lambda b_, h_, j, pos_: (b_, j, 0, 0)),
+            pl.BlockSpec((1, bk, kv, hd), lambda b_, h_, j, pos_: (b_, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b_, h_, j, pos_: (b_, h_, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, hd),
+                               lambda b_, h_, j, pos_: (b_, h_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
@@ -110,7 +127,7 @@ def decode_attention_pallas(q, k, v, pos, *, softcap: float = 0.0,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
-        interpret=interpret,
-    )(pos, q, k, v)
-    return out
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, hd), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(pos, q[:, :, None, :], k, v)
+    return out[:, :, 0, :]

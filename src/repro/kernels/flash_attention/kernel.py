@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import resolve_interpret
+
 NEG_INF = -2.3819763e38
 
 
@@ -81,7 +83,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            softcap: float = 0.0, scale: float | None = None,
                            block_q: int = 512, block_k: int = 512,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q: (B, H, S, hd); k, v: (B, KV, T, hd).  Returns (B, H, S, hd)."""
     b, h, s, hd = q.shape
     _, kv, t, _ = k.shape
@@ -115,5 +117,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),      # running sum l
             pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -2,8 +2,8 @@
 
 ``flash_attention`` takes model-layout tensors (B, S, H, hd) / (B, T, KV,
 hd) like models/attention.py produces, transposes to the kernel layout,
-and dispatches to the Pallas kernel (interpret mode off-TPU) or the
-reference oracle.
+and dispatches to the Pallas kernel (compiled on a TPU, interpreted
+elsewhere) or the reference oracle.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ from .ref import reference_attention
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     softcap: float = 0.0, use_kernel: bool = True,
                     block_q: int = 512, block_k: int = 512,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (B, S, H, hd); k, v: (B, T, KV, hd) -> (B, S, H, hd)."""
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..backend import resolve_interpret
+
 
 def _ssd_kernel(x_ref, da_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref,
                 h_ref, *, q: int, nc: int):
@@ -37,36 +39,42 @@ def _ssd_kernel(x_ref, da_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref,
         h_ref[...] = jnp.zeros_like(h_ref)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, hd)
-    da = da_ref[0, 0].astype(jnp.float32)        # (Q,)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q,)
+    da = da_ref[0, 0].astype(jnp.float32)        # (1, Q)
+    dt = dt_ref[0, 0].astype(jnp.float32)        # (1, Q)
     bmat = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
     cmat = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
 
-    cum = jnp.cumsum(da)                         # inclusive in-chunk decay
-    # intra-chunk: decay[i,j] = exp(cum_i - cum_j) for i >= j
-    seg = cum[:, None] - cum[None, :]
+    # inclusive in-chunk decay cumsum, as a column and as a row.  Masked
+    # sums stand in for cumsum and transpose, which Mosaic does not lower.
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     tri = ii >= jj
-    decay = jnp.where(tri, jnp.exp(seg), 0.0)
+    eye = ii == jj
+    cum_col = jnp.sum(jnp.where(tri, da, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(eye, cum_col, 0.0), axis=0, keepdims=True)
+    cum_last = jnp.sum(da)
+    # intra-chunk: decay[i,j] = exp(cum_i - cum_j) for i >= j
+    decay = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)
     scores = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    att = scores * decay * dt[None, :]
+    att = scores * decay * dt
     y = jax.lax.dot_general(att, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
     # inter-chunk: contribution of the carried state h_prev (hd, N)
     h_prev = h_ref[...]
-    y += jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cum_col) * jax.lax.dot_general(
         cmat, h_prev, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    # state update: h = exp(cum_last) h_prev + sum_j w_j x_j B_j^T
-    decay_out = jnp.exp(cum[-1] - cum) * dt      # (Q,)
-    s_chunk = jax.lax.dot_general(x * decay_out[:, None], bmat,
+    # state update: h = exp(cum_last) h_prev + sum_j w_j x_j B_j^T, with
+    # w_j = exp(cum_last - cum_j) dt_j scaling the rows of x
+    w_row = jnp.exp(cum_last - cum_row) * dt      # (1, Q)
+    w_col = jnp.sum(jnp.where(eye, w_row, 0.0), axis=1, keepdims=True)
+    s_chunk = jax.lax.dot_general(x * w_col, bmat,
                                   (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    h_ref[...] = jnp.exp(cum[-1]) * h_prev + s_chunk  # (hd, N)
+    h_ref[...] = jnp.exp(cum_last) * h_prev + s_chunk  # (hd, N)
 
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
@@ -75,7 +83,7 @@ def _ssd_kernel(x_ref, da_ref, dt_ref, b_ref, c_ref, y_ref, hout_ref,
         hout_ref[0] = h_ref[...].astype(hout_ref.dtype)
 
 
-def ssd_scan_pallas(x, da, dt, bmat, cmat, *, interpret: bool = True):
+def ssd_scan_pallas(x, da, dt, bmat, cmat, *, interpret: bool | None = None):
     """Chunked SSD scan.
 
     x:    (BH, NC, Q, hd)  per-(batch*head) chunked inputs
@@ -84,6 +92,9 @@ def ssd_scan_pallas(x, da, dt, bmat, cmat, *, interpret: bool = True):
     bmat: (BH, NC, Q, N)   input projections  (already head-broadcast)
     cmat: (BH, NC, Q, N)   output projections
     Returns (y: (BH, NC, Q, hd), h_final: (BH, hd, N)), fp32.
+
+    The TPU tiles the last two dims of every block, so ``da``/``dt``
+    travel as (BH, NC, 1, Q) rows.
     """
     bh, nc, q, hd = x.shape
     n = bmat.shape[-1]
@@ -93,8 +104,8 @@ def ssd_scan_pallas(x, da, dt, bmat, cmat, *, interpret: bool = True):
         grid=(bh, nc),
         in_specs=[
             pl.BlockSpec((1, 1, q, hd), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, q), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, 1, q), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((1, 1, 1, q), lambda b, c: (b, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, q), lambda b, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda b, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda b, c: (b, c, 0, 0)),
         ],
@@ -107,5 +118,5 @@ def ssd_scan_pallas(x, da, dt, bmat, cmat, *, interpret: bool = True):
             jax.ShapeDtypeStruct((bh, hd, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hd, n), jnp.float32)],
-        interpret=interpret,
-    )(x, da, dt, bmat, cmat)
+        interpret=resolve_interpret(interpret),
+    )(x, da[:, :, None, :], dt[:, :, None, :], bmat, cmat)

@@ -13,7 +13,7 @@ from .ref import reference_ssd_scan
 
 @functools.partial(jax.jit, static_argnames=("chunk", "use_kernel", "interpret"))
 def ssd_scan(x, dt, a, bmat, cmat, *, chunk: int = 256,
-             use_kernel: bool = True, interpret: bool = True):
+             use_kernel: bool = True, interpret: bool | None = None):
     """Model-layout SSD scan (drop-in for models.mamba2.ssd_chunked).
 
     x: (B, L, nh, hd); dt: (B, L, nh); a: (nh,); bmat/cmat: (B, L, N).

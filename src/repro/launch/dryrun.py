@@ -13,7 +13,6 @@ import traceback     # noqa: E402
 
 import jax           # noqa: E402
 
-from repro.compat import cost_analysis_dict               # noqa: E402
 from repro.configs.base import SHAPES, cells, get_config  # noqa: E402
 from repro.launch.inputs import build_cell                # noqa: E402
 from repro.launch.mesh import make_production_mesh        # noqa: E402
@@ -72,7 +71,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             c = build_cell(arch, shape_name, mesh, probe_groups=k,
                            **(extra or {}))
             comp = c.lower().compile()
-            ca = cost_analysis_dict(comp)
+            ca = comp.cost_analysis()
             coll = collective_bytes(comp.as_text())
             return (float(ca.get("flops", 0.0)),
                     float(ca.get("bytes accessed", 0.0)),
@@ -90,7 +89,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         print(f"== {arch} x {shape_name} @ {mesh_name} "
               f"(compile {dt:.1f}s) ==")
         print("   memory_analysis:", compiled.memory_analysis())
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         print(f"   cost_analysis: flops/dev={ca.get('flops', 0):.3e} "
               f"bytes/dev={ca.get('bytes accessed', 0):.3e}")
         print(f"   collectives/dev: {rep.coll_detail}")

@@ -144,8 +144,7 @@ def _attention(cfg: ModelConfig, q, k, v, mask, *, causal: bool, window: int):
             from repro.kernels.flash_attention import flash_attention
             return flash_attention(
                 q, k, v, causal=True, window=window,
-                softcap=cfg.attn_softcap, block_q=bq, block_k=bk,
-                interpret=jax.default_backend() != "tpu")
+                softcap=cfg.attn_softcap, block_q=bq, block_k=bk)
     return _sdpa(cfg, q, k, v, mask)
 
 

@@ -14,8 +14,6 @@ the MODEL_FLOPS/HLO_FLOPs roofline ratio checks.
 from __future__ import annotations
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -130,7 +128,7 @@ def _moe_shard_map(p, cfg: ModelConfig, x, mesh, rules):
     shared_specs = ({"w_gate": P(None, model_ax), "w_up": P(None, model_ax),
                      "w_down": P(model_ax, None)}
                     if cfg.n_shared_experts else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bx, None), P(None, None),
                   P(data_ax, None, model_ax), P(data_ax, None, model_ax),

@@ -27,8 +27,6 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from repro.compat import cost_analysis_dict
-
 HW = {
     "peak_flops": 197e12,     # bf16 FLOP/s per chip
     "hbm_bw": 819e9,          # bytes/s per chip
@@ -204,7 +202,7 @@ class CellReport:
 def analyze_compiled(arch, shape_name, mesh_name, compiled, *,
                      model_flops_global: float, n_devices: int,
                      compile_s: float = 0.0) -> CellReport:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     coll = collective_bytes(compiled.as_text())
     rep = CellReport(

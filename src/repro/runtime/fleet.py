@@ -47,7 +47,7 @@ fleet-vs-merged-single-host parity test pins against the *event*
 engine within the existing quiet bands.
 
 **Device sharding.**  ``simulate_fleet(..., shard=True)`` splits the
-point axis across local devices via ``repro.compat.shard_map`` (each
+point axis across local devices via ``jax.shard_map`` (each
 device vmaps its slice of points over all hosts); ``shard=None`` auto-
 enables when more than one device is visible, and ``shard=False``
 forces the pure-vmap path.  CI exercises the sharded path with
@@ -799,12 +799,10 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
     if n_shards > 1:
         from jax.sharding import Mesh, PartitionSpec
 
-        from ..compat import shard_map
-
         mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("pts",))
         spec = PartitionSpec("pts")
-        inner = shard_map(inner, mesh=mesh, in_specs=(spec,) * 11,
-                          out_specs=spec)
+        inner = jax.shard_map(inner, mesh=mesh, in_specs=(spec,) * 11,
+                              out_specs=spec, check_vma=False)
     return jax.jit(inner)
 
 

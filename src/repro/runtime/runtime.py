@@ -108,6 +108,7 @@ class Runtime:
         self._threads: list[threading.Thread] = []
         self._cycles_q = [0] * len(queues)
         self._lat_every = max(latency_sample_every, 1)
+        self._error: Exception | None = None
 
     # -- lifecycle -------------------------------------------------------------
     def start(self) -> None:
@@ -134,9 +135,11 @@ class Runtime:
                               policy=getattr(self.policy, "name", ""),
                               started_ns=now,
                               latency_us=Reservoir(self._lat_cap))
+        with self._stats_lock:
+            self._error = None
         self._running.set()
         self._threads = [
-            threading.Thread(target=self._run, args=(slot,),
+            threading.Thread(target=self._poll, args=(slot,),
                              name=f"runtime-{i}", daemon=True)
             for i, slot in enumerate(self._slots)
         ]
@@ -152,7 +155,15 @@ class Runtime:
             for t in self._app_threads:
                 t.start()
 
+    @property
+    def error(self) -> Exception | None:
+        """The first exception that ended a poller thread, if any."""
+        with self._stats_lock:
+            return self._error
+
     def stop(self, timeout: float = 5.0) -> RunStats:
+        """Stop and join every thread and return the run's stats; if a
+        poller died, re-raise the first exception that ended one."""
         self._running.clear()
         for t in self._threads:
             t.join(timeout)
@@ -181,6 +192,9 @@ class Runtime:
             # full core per thread (the paper's DPDK baseline accounting).
             st.awake_ns = st.duration_ns * max(len(self._threads), 1)
         st.energy_uj = self._estimate_energy_uj(st)
+        err = self.error
+        if err is not None:
+            raise err
         return st
 
     def _estimate_energy_uj(self, st: RunStats) -> float:
@@ -211,6 +225,18 @@ class Runtime:
                      + tl_arms * em.arm_energy_uj(float(t_l_us)))
 
     # -- the paper's loop, policy-parameterized ----------------------------------
+    def _poll(self, slot: ThreadSlot) -> None:
+        """Poller thread body: on an exception (from ``process`` or
+        ``idle_work``) keep the first one for ``stop()`` to re-raise and
+        stop the other pollers, instead of dying with it unseen."""
+        try:
+            self._run(slot)
+        except Exception as e:
+            with self._stats_lock:
+                if self._error is None:
+                    self._error = e
+            self._running.clear()
+
     def _run(self, slot: ThreadSlot | None = None) -> None:
         if slot is None:        # direct callers (tests/shims) get default
             slot = ThreadSlot(self.policy, tuple(range(len(self.queues))))

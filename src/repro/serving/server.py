@@ -147,7 +147,14 @@ class Server:
         self.stats.backend = "server"
 
     def stop(self, timeout: float = 10.0) -> ServerStats:
+        """Stop the pollers; re-raises the first exception that ended
+        one (an engine failure inside ``pump()``, say)."""
         return self._runtime.stop(timeout)
+
+    @property
+    def error(self) -> Exception | None:
+        """The first exception that ended a poller thread, if any."""
+        return self._runtime.error
 
     @property
     def stats(self) -> ServerStats:

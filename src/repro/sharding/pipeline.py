@@ -15,8 +15,6 @@ Validated against the sequential reference in an 8-device subprocess
 from __future__ import annotations
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -72,7 +70,7 @@ def gpipe(block_fn, stacked_params, x, mesh, *, pipe_axis: str = "pipe",
         return outs
 
     other = tuple(a for a in mesh.axis_names if a != pipe_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(pipe_axis), staged), P()),
         out_specs=P(),

@@ -16,8 +16,6 @@ which tests/test_train_substrate.py asserts from the lowered text.
 from __future__ import annotations
 
 import jax
-
-from repro.compat import shard_map
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -73,7 +71,7 @@ def make_dp_grad_fn(loss_fn, mesh, *, compress: bool = True,
             g = jax.tree.map(lambda x: jax.lax.psum(x, data_axis), g)
         return jax.tree.map(lambda x: x / n, g)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_grads, mesh=mesh,
         in_specs=(P(), P(data_axis)),
         out_specs=P(),

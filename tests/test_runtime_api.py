@@ -339,6 +339,32 @@ def test_runtime_rearms_vacation_clock_on_start():
     assert vacs[0] < 200_000         # << the 250ms pre-start age
 
 
+def test_runtime_stop_reraises_the_first_poller_exception():
+    """A poller that dies in ``process`` stops the others; ``stop()``
+    re-raises its exception instead of returning as if all were well."""
+    q = BoundedQueue(64)
+
+    def process(batch):
+        raise ValueError(f"bad batch {batch}")
+
+    rt = Runtime([q], process=process,
+                 policy=FixedPeriodPolicy(200.0, threads=2))
+    rt.start()
+    assert rt.error is None
+    q.push(1)
+    deadline = time.monotonic() + 5.0
+    while rt.error is None and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert isinstance(rt.error, ValueError)
+    with pytest.raises(ValueError, match="bad batch"):
+        rt.stop()
+    # a restart clears the recorded failure
+    rt.process = lambda batch: None
+    rt.start()
+    assert rt.error is None
+    rt.stop()
+
+
 # ---------------------------------------------------------------------------
 # sim/real parity
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ import time
 
 import jax
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.core import MetronomeConfig
@@ -142,6 +143,32 @@ def test_metronome_server_retrieval_latency_tracks_target():
     assert stats.retrieval_lat_us
     med = float(np.median(stats.retrieval_lat_us))
     assert med < 50_000.0, med   # well below T_L; dominated by engine busy time
+
+
+def test_server_surfaces_an_engine_failure_from_stop():
+    """An exception in ``engine.pump()`` ends a poller; the server keeps
+    it as ``error`` and ``stop()`` re-raises it."""
+    from repro.runtime import MetronomePolicy
+    from repro.serving import Server
+
+    eng = _make_engine()
+
+    def broken_pump():
+        raise RuntimeError("device lost")
+
+    eng.pump = broken_pump
+    srv = Server(eng, MetronomePolicy(
+        MetronomeConfig(m=2, v_target_us=1_000.0, t_long_us=20_000.0)))
+    srv.start()
+    req = Request(prompt=[1, 2], max_new_tokens=2)
+    srv.submit(req)
+    deadline = time.monotonic() + 10.0
+    while srv.error is None and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert isinstance(srv.error, RuntimeError)
+    assert not req.wait(0.0)
+    with pytest.raises(RuntimeError, match="device lost"):
+        srv.stop()
 
 
 def test_server_shards_ingress_across_queues():

@@ -14,14 +14,8 @@ from repro.configs import get_config
 from repro.models import Model
 from repro.sharding.policy import param_pspecs
 
-# jax >= 0.4.36 takes a shape_tuple of (name, size) pairs; older versions
-# took (shape, axis_names) positionally.
-try:
-    MESH = AbstractMesh((("data", 16), ("model", 16)))
-    MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
-except TypeError:  # pragma: no cover - older jax
-    MESH = AbstractMesh((16, 16), ("data", "model"))
-    MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _specs(arch, mesh=MESH, mode="train"):
