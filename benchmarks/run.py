@@ -1,8 +1,8 @@
-"""Benchmark harness: one function per paper table/figure (+ kernels +
-roofline + the batched sweep frontier + the nonstationary adaptation
-matrix).  Prints ``name,us_per_call,derived`` CSV; ``--json out.json``
-additionally writes every row machine-readably (derived ``k=v;k=v``
-strings parsed into dicts — so policy/workload labels, p50/p99
+"""Benchmark harness: one function per paper table/figure (+ the
+batched sweep frontier + the nonstationary adaptation matrix).  Prints
+``name,us_per_call,derived`` CSV; ``--json out.json`` additionally
+writes every row machine-readably (derived ``k=v;k=v`` strings parsed
+into dicts — so policy/workload labels, p50/p99
 latencies, CPU fractions, and the adaptation rows' ``schedule``
 descriptor plus tracking fields — conv_us, overshoot_us,
 violation_frac, rho_rmse — land as fields) for a ``BENCH_*.json`` perf
@@ -56,7 +56,6 @@ def main() -> None:
     from benchmarks.adaptation import adaptation
     from benchmarks.cpu_sharing import cpu_sharing
     from benchmarks.fleet import fleet_bench
-    from benchmarks.kernels_bench import kernels
     from benchmarks.policy_matrix import matrix_policies_workloads
     from benchmarks.power import power
     from benchmarks.rss_skew import matrix_rss_skew
@@ -74,7 +73,6 @@ def main() -> None:
         table2_vbar_tuning,
         table3_nanosleep_loss,
     )
-    from benchmarks.roofline_table import roofline
 
     def compile_caches(quick: bool = False):
         """JIT compile-cache counters across everything that ran above —
@@ -95,7 +93,7 @@ def main() -> None:
         table3_nanosleep_loss, fig11_adaptation, fig12_dpdk_compare,
         matrix_policies_workloads, matrix_rss_skew, sweep_frontier,
         cpu_sharing, adaptation, fig15_applications, fleet_bench,
-        kernels, roofline, stepping_compare, power, compile_caches,
+        stepping_compare, power, compile_caches,
     ]
     print("name,us_per_call,derived")
     failures = 0

@@ -127,6 +127,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import tracing
 from .simcore import SimRunConfig
 from .stats import Reservoir, RunStats, WindowedSeries
 
@@ -153,9 +154,11 @@ class CompileCache:
     is a mysteriously slow process.  This cache (a) has a bound sized
     for fleet + single-host sweeps coexisting, (b) exposes hit / miss /
     eviction counters (``cache_info()``, surfaced by
-    ``benchmarks/run.py --json``), and (c) logs every eviction with the
+    ``benchmarks/run.py --json``), (c) logs every eviction with the
     evicted key, so a retrace storm is visible in logs instead of
-    silent.  Every instance self-registers for ``compile_cache_stats``.
+    silent, and (d) counts each build as ``built`` on the open tracing
+    span, so a profile says which call compiled.  Every instance
+    self-registers for ``compile_cache_stats``.
     """
 
     _registry: list["CompileCache"] = []
@@ -175,6 +178,7 @@ class CompileCache:
             fn = self._entries[key]
         except KeyError:
             self.misses += 1
+            tracing.count(built=1)
             fn = self._build(*key)
             self._entries[key] = fn
             if len(self._entries) > self.maxsize:
@@ -570,163 +574,173 @@ def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
             # no-ops: the scan length is bucketed (bucket_steps), so one
             # compiled kernel serves every nearby duration
             live = now < duration
-            kt_step = jax.random.fold_in(key, t)
-            if tail_prob > 0.0:
-                kt_step, kp, ku = jax.random.split(kt_step, 3)
-            if intf_prob > 0.0:
-                kt_step, kip, kie = jax.random.split(kt_step, 3)
-            if stall_p > 0.0:
-                kt_step, ksp, kse, ksu = jax.random.split(kt_step, 4)
-            # one fused normal draw covers arrivals + sleep noise
-            zs = jax.random.normal(kt_step, (q_max + m_max,))
+            with jax.named_scope("rng"):
+                kt_step = jax.random.fold_in(key, t)
+                if tail_prob > 0.0:
+                    kt_step, kp, ku = jax.random.split(kt_step, 3)
+                if intf_prob > 0.0:
+                    kt_step, kip, kie = jax.random.split(kt_step, 3)
+                if stall_p > 0.0:
+                    kt_step, ksp, kse, ksu = jax.random.split(kt_step, 4)
+                # one fused normal draw covers arrivals + sleep noise
+                zs = jax.random.normal(kt_step, (q_max + m_max,))
 
             # correlated stall windows: Bernoulli(1-exp(-rate*dt)) opens
             # an Exp(stall_mean)-long system-wide freeze; overlapping
             # windows extend (max), like the event engine's lazy merge
-            if stall_p > 0.0:
-                hit_s = jax.random.uniform(ksp, ()) < stall_p
-                win = now + stall_mean_us * jax.random.exponential(kse, ())
-                stall_end = jnp.where(hit_s,
-                                      jnp.maximum(stall_end, win), stall_end)
+            with jax.named_scope("stall"):
+                if stall_p > 0.0:
+                    hit_s = jax.random.uniform(ksp, ()) < stall_p
+                    win = now + stall_mean_us * jax.random.exponential(
+                        kse, ())
+                    stall_end = jnp.where(
+                        hit_s, jnp.maximum(stall_end, win), stall_end)
 
             # 1. arrivals: residual-carried Gaussian fluid ~ Poisson,
             # rate modulated by the point's load schedule when one is
             # compiled in (piecewise-constant scale looked up per slot)
-            if n_seg > 0:
-                si = jnp.clip(
-                    jnp.searchsorted(sched_edges, now, side="right") - 1,
-                    0, n_seg - 1)
-                mu_a = lam_q * sched_scales[si] * dt
-            else:
-                mu_a = lam_q * dt
-            raw = arr_res + mu_a + jnp.sqrt(mu_a) * zs[:q_max]
-            a = jnp.maximum(raw, 0.0)
-            arr_res = jnp.minimum(raw, 0.0)      # deficit carried forward
-            room = jnp.maximum(capacity - backlog, 0.0)
-            adm = jnp.minimum(a, room)
-            backlog = backlog + adm
-            offered = a.sum()
-            dropped = (a - adm).sum()
+            with jax.named_scope("arrivals"):
+                if n_seg > 0:
+                    si = jnp.clip(
+                        jnp.searchsorted(sched_edges, now, side="right") - 1,
+                        0, n_seg - 1)
+                    mu_a = lam_q * sched_scales[si] * dt
+                else:
+                    mu_a = lam_q * dt
+                raw = arr_res + mu_a + jnp.sqrt(mu_a) * zs[:q_max]
+                a = jnp.maximum(raw, 0.0)
+                arr_res = jnp.minimum(raw, 0.0)      # deficit carried forward
+                room = jnp.maximum(capacity - backlog, 0.0)
+                adm = jnp.minimum(a, room)
+                backlog = backlog + adm
+                offered = a.sum()
+                dropped = (a - adm).sum()
 
             # sleep overshoot draws for this slot (one per thread;
             # static zeros when the model is exact)
-            over = jnp.full((m_max,), base_us)
-            if sigma_us > 0.0:
-                over = over + sigma_us * jnp.abs(zs[q_max:])
-            if tail_prob > 0.0:
-                hit = jax.random.uniform(kp, (m_max,)) < tail_prob
-                over = over + hit * tail_mean_us * jax.random.exponential(
-                    ku, (m_max,))
-            # per-wake OS interference (paper Sec 5.6): each re-sleep is
-            # lengthened by Exp(mean) w.p. q — one independent draw per
-            # thread per slot, charged only on the slots where a thread
-            # actually re-arms (the same per-sleep draw the event engine
-            # makes after sampling the sleep model)
-            if intf_prob > 0.0:
-                ihit = jax.random.uniform(kip, (m_max,)) < intf_prob
-                over = over + ihit * intf_mean_us * jax.random.exponential(
-                    kie, (m_max,))
-            slp_s = t_s * (1.0 + slope) + over
-            slp_l = t_l * (1.0 + slope) + over
+            with jax.named_scope("overshoot"):
+                over = jnp.full((m_max,), base_us)
+                if sigma_us > 0.0:
+                    over = over + sigma_us * jnp.abs(zs[q_max:])
+                if tail_prob > 0.0:
+                    hit = jax.random.uniform(kp, (m_max,)) < tail_prob
+                    over = over + hit * tail_mean_us * jax.random.exponential(
+                        ku, (m_max,))
+                # per-wake OS interference (paper Sec 5.6): each re-sleep is
+                # lengthened by Exp(mean) w.p. q — one independent draw per
+                # thread per slot, charged only on the slots where a thread
+                # actually re-arms (the same per-sleep draw the event engine
+                # makes after sampling the sleep model)
+                if intf_prob > 0.0:
+                    ihit = jax.random.uniform(kip, (m_max,)) < intf_prob
+                    over = over + ihit * intf_mean_us * jax.random.exponential(
+                        kie, (m_max,))
+                slp_s = t_s * (1.0 + slope) + over
+                slp_l = t_l * (1.0 + slope) + over
 
             # 2. countdown + wake + claim (threads in index order)
-            sleeping = tmask & (attached < 0)
-            sleep_rem = jnp.where(sleeping, sleep_rem - dt, sleep_rem)
-            woken = sleeping & (sleep_rem <= 0.0)
-            if stall_p > 0.0:
-                # timers expiring inside an open stall window defer to its
-                # end (+U(0,1)us re-arm jitter) and are NOT counted as
-                # wakes — the event engine's deferred-wake semantics
-                push = woken & (now < stall_end)
-                woken = woken & ~push
-                sleep_rem = jnp.where(
-                    push,
-                    stall_end - now + jax.random.uniform(ksu, (m_max,)),
-                    sleep_rem)
-            n_wake = woken.sum().astype(jnp.float32)
+            with jax.named_scope("wake_claim"):
+                sleeping = tmask & (attached < 0)
+                sleep_rem = jnp.where(sleeping, sleep_rem - dt, sleep_rem)
+                woken = sleeping & (sleep_rem <= 0.0)
+                if stall_p > 0.0:
+                    # timers expiring inside an open stall window defer to its
+                    # end (+U(0,1)us re-arm jitter) and are NOT counted as
+                    # wakes — the event engine's deferred-wake semantics
+                    push = woken & (now < stall_end)
+                    woken = woken & ~push
+                    sleep_rem = jnp.where(
+                        push,
+                        stall_end - now + jax.random.uniform(ksu, (m_max,)),
+                        sleep_rem)
+                n_wake = woken.sum().astype(jnp.float32)
 
-            occ = (jax.nn.one_hot(attached, q_max).sum(axis=0) > 0)
-            busy_tries = jnp.float32(0.0)
-            cycles = jnp.float32(0.0)
-            vac_sum = jnp.float32(0.0)
-            nv_sum = jnp.float32(0.0)
-            ts_arm = jnp.float32(0.0)
-            for i in range(m_max):            # static unroll, m_max small
-                w = woken[i]
-                free_q = qmask & ~occ
-                claimable = free_q & (backlog >= 1.0)
-                qi = jnp.argmax(jnp.where(claimable, backlog, -1.0))
-                do_attach = w & claimable.any()
-                empty_claim = w & ~claimable.any() & free_q.any()
-                eqi = jnp.argmax(free_q)      # first free (empty) queue
-                blocked = w & ~free_q.any()
+                occ = (jax.nn.one_hot(attached, q_max).sum(axis=0) > 0)
+                busy_tries = jnp.float32(0.0)
+                cycles = jnp.float32(0.0)
+                vac_sum = jnp.float32(0.0)
+                nv_sum = jnp.float32(0.0)
+                ts_arm = jnp.float32(0.0)
+                for i in range(m_max):            # static unroll, m_max small
+                    w = woken[i]
+                    free_q = qmask & ~occ
+                    claimable = free_q & (backlog >= 1.0)
+                    qi = jnp.argmax(jnp.where(claimable, backlog, -1.0))
+                    do_attach = w & claimable.any()
+                    empty_claim = w & ~claimable.any() & free_q.any()
+                    eqi = jnp.argmax(free_q)      # first free (empty) queue
+                    blocked = w & ~free_q.any()
 
-                claim_hot = do_attach & (q_idx == qi)
-                claim_any = claim_hot | (empty_claim & (q_idx == eqi))
-                vac_sum = vac_sum + (vac_timer * claim_any).sum()
-                nv_sum = nv_sum + jnp.where(do_attach, backlog[qi], 0.0)
-                vac_timer = jnp.where(claim_any, 0.0, vac_timer)
-                cycles = cycles + (do_attach | empty_claim)
-                busy_tries = busy_tries + blocked
-                ts_arm = ts_arm + empty_claim
-                attached = attached.at[i].set(
-                    jnp.where(do_attach, qi, attached[i]))
-                occ = occ | claim_hot
-                # re-sleep adds onto the (negative) expired-timer
-                # residual: removes the slot-quantization wake-rate bias
-                sleep_rem = sleep_rem.at[i].add(
-                    jnp.where(empty_claim, slp_s[i],
-                              jnp.where(blocked, slp_l[i], 0.0)))
+                    claim_hot = do_attach & (q_idx == qi)
+                    claim_any = claim_hot | (empty_claim & (q_idx == eqi))
+                    vac_sum = vac_sum + (vac_timer * claim_any).sum()
+                    nv_sum = nv_sum + jnp.where(do_attach, backlog[qi], 0.0)
+                    vac_timer = jnp.where(claim_any, 0.0, vac_timer)
+                    cycles = cycles + (do_attach | empty_claim)
+                    busy_tries = busy_tries + blocked
+                    ts_arm = ts_arm + empty_claim
+                    attached = attached.at[i].set(
+                        jnp.where(do_attach, qi, attached[i]))
+                    occ = occ | claim_hot
+                    # re-sleep adds onto the (negative) expired-timer
+                    # residual: removes the slot-quantization wake-rate bias
+                    sleep_rem = sleep_rem.at[i].add(
+                        jnp.where(empty_claim, slp_s[i],
+                                  jnp.where(blocked, slp_l[i], 0.0)))
 
             # 3. locked queues drain at mu for the slot
-            serve = jnp.where(occ, jnp.minimum(backlog, mu * dt), 0.0)
-            backlog = backlog - serve
-            served = serve.sum()
+            with jax.named_scope("drain"):
+                serve = jnp.where(occ, jnp.minimum(backlog, mu * dt), 0.0)
+                backlog = backlog - serve
+                served = serve.sum()
 
             # 4. emptied queues release their thread (fresh T_S sleep)
-            q_done = occ & (backlog <= 1e-6)
-            att_q = jnp.clip(attached, 0, q_max - 1)
-            t_done = (attached >= 0) & q_done[att_q]
-            ts_arm = ts_arm + t_done.sum()
-            sleep_rem = jnp.where(t_done, slp_s, sleep_rem)
-            attached = jnp.where(t_done, -1, attached)
-            occ = occ & ~q_done
+            with jax.named_scope("release"):
+                q_done = occ & (backlog <= 1e-6)
+                att_q = jnp.clip(attached, 0, q_max - 1)
+                t_done = (attached >= 0) & q_done[att_q]
+                ts_arm = ts_arm + t_done.sum()
+                sleep_rem = jnp.where(t_done, slp_s, sleep_rem)
+                attached = jnp.where(t_done, -1, attached)
+                occ = occ & ~q_done
 
             # 5. vacations tick on unlocked queues; 6. Little integral
-            vac_timer = vac_timer + jnp.where(qmask & ~occ, dt, 0.0)
-            lat_area = backlog.sum() * dt
+            with jax.named_scope("accumulate"):
+                vac_timer = vac_timer + jnp.where(qmask & ~occ, dt, 0.0)
+                lat_area = backlog.sum() * dt
 
-            # energy: active power over the slot's awake time plus the
-            # per-arm C-state charges (blocked wakes re-arm T_L)
-            awake_step = n_wake * wake_cost_us + served / mu
-            energy_step = (active_power_w * awake_step
-                           + ts_arm * e_arm_s + busy_tries * e_arm_l)
+                # energy: active power over the slot's awake time plus the
+                # per-arm C-state charges (blocked wakes re-arm T_L)
+                awake_step = n_wake * wake_cost_us + served / mu
+                energy_step = (active_power_w * awake_step
+                               + ts_arm * e_arm_s + busy_tries * e_arm_l)
 
-            S = _SlotStats(
-                offered=S.offered + offered,
-                dropped=S.dropped + dropped,
-                serviced=S.serviced + served,
-                wakeups=S.wakeups + n_wake,
-                busy_tries=S.busy_tries + busy_tries,
-                cycles=S.cycles + cycles,
-                awake_us=S.awake_us + awake_step,
-                lat_area=S.lat_area + lat_area,
-                vac_sum=S.vac_sum + vac_sum,
-                nv_sum=S.nv_sum + nv_sum,
-                ts_arms=S.ts_arms + ts_arm,
-                energy_uj=S.energy_uj + energy_step,
-            )
-            if n_windows > 0:
-                # the event engine's WindowAccum convention: raw
-                # [offered, served, lat_area, awake, energy] per window
-                w = jnp.minimum((now / window_us).astype(jnp.int32),
-                                n_windows - 1)
-                win_acc = win_acc.at[w].add(jnp.stack([
-                    offered, served, lat_area, awake_step, energy_step]))
-            nxt = (sleep_rem, attached, backlog, vac_timer, arr_res,
-                   stall_end, S, win_acc)
-            gated = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(live, new, old), nxt, prev)
+                S = _SlotStats(
+                    offered=S.offered + offered,
+                    dropped=S.dropped + dropped,
+                    serviced=S.serviced + served,
+                    wakeups=S.wakeups + n_wake,
+                    busy_tries=S.busy_tries + busy_tries,
+                    cycles=S.cycles + cycles,
+                    awake_us=S.awake_us + awake_step,
+                    lat_area=S.lat_area + lat_area,
+                    vac_sum=S.vac_sum + vac_sum,
+                    nv_sum=S.nv_sum + nv_sum,
+                    ts_arms=S.ts_arms + ts_arm,
+                    energy_uj=S.energy_uj + energy_step,
+                )
+                if n_windows > 0:
+                    # the event engine's WindowAccum convention: raw
+                    # [offered, served, lat_area, awake, energy] per window
+                    w = jnp.minimum((now / window_us).astype(jnp.int32),
+                                    n_windows - 1)
+                    win_acc = win_acc.at[w].add(jnp.stack([
+                        offered, served, lat_area, awake_step, energy_step]))
+            with jax.named_scope("gate"):
+                nxt = (sleep_rem, attached, backlog, vac_timer, arr_res,
+                       stall_end, S, win_acc)
+                gated = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(live, new, old), nxt, prev)
             return gated, None
 
         z0 = jnp.float32(0.0)
@@ -742,7 +756,11 @@ def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
             step, init, jnp.arange(n_slots, dtype=jnp.int32))
         return S, win_acc, backlog_f.sum()
 
-    return jax.jit(jax.vmap(one_point))
+    def fixed_sweep(*points):
+        return jax.vmap(one_point)(*points)
+
+    # the function's name is the compiled module's: jit_fixed_sweep
+    return jax.jit(fixed_sweep)
 
 
 _compiled_sweep = CompileCache(_build_sweep, maxsize=64,
@@ -831,6 +849,13 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
     macro-slots (see ``batched_adaptive``) — same statistics, same
     parity bands, scan length proportional to event count instead of
     simulated time.
+
+    Spans (``repro.runtime.tracing``): ``batched.sweep`` around the
+    call, with counts ``points``, ``scan_steps`` and ``built`` (1 when
+    the kernel for this shape was built, and so compiled, by this
+    call); inside it ``batched.scan`` (the device scan, until its
+    outputs are ready) and ``batched.fetch`` (device-to-host copies and
+    ``BatchStats``).  Input staging is the sweep's self time.
     """
     cfg = cfg or SimRunConfig()
     validate_batched_config(cfg)
@@ -840,64 +865,64 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
     n = len(grid)
     n_windows = (int(math.ceil(cfg.duration_us / cfg.window_us))
                  if cfg.window_us > 0 else 0)
-    if stepping == "adaptive":
-        from .batched_adaptive import adaptive_sweep_arrays
-        vals, win_np, back_f, simt, scan_len = adaptive_sweep_arrays(
-            grid, cfg, float(slot_us))
-        return BatchStats(
-            grid=grid, cfg=cfg, slot_us=float(slot_us),
-            offered=vals["offered"], dropped=vals["dropped"],
-            serviced=vals["serviced"], wakeups=vals["wakeups"],
-            busy_tries=vals["busy_tries"], cycles=vals["cycles"],
-            awake_us=vals["awake_us"], lat_area=vals["lat_area"],
-            vac_sum=vals["vac_sum"], nv_sum=vals["nv_sum"],
-            ts_arms=vals["ts_arms"], energy_uj=vals["energy_uj"],
-            win=win_np, stepping="adaptive", scan_len=int(scan_len),
-            n_steps=vals["n_steps"], forced_steps=vals["forced_steps"],
-            sim_time_us=simt, final_backlog=back_f)
     n_slots_true = max(int(math.ceil(cfg.duration_us / slot_us)), 1)
-    n_slots = bucket_steps(n_slots_true)
-    n_win_pad = bucket_steps(n_windows, base=8) if n_windows else 0
-    m_max = int(grid.m.max())
-    q_max = int(grid.n_queues.max())
-    n_seg, sched_edges, sched_scales = _schedule_rows(grid, cfg)
-    sm = cfg.sleep_model
-    fn = _compiled_sweep(
-        n_slots, float(slot_us), m_max, q_max,
-        float(cfg.service_rate_mpps), float(cfg.queue_capacity),
-        float(cfg.wake_cost_us),
-        (float(sm.base_us), float(sm.slope), float(sm.sigma_us),
-         float(sm.tail_prob), float(sm.tail_mean_us)),
-        (float(cfg.interference_prob), float(cfg.interference_mean_us),
-         float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
-        cfg.energy_model.params(),
-        n_seg, n_win_pad, float(cfg.window_us))
-    seed64 = np.asarray(grid.seed, dtype=np.uint64)
-    out, win, back_f = fn(
-        jnp.asarray(grid.t_s_us, jnp.float32),
-        jnp.asarray(grid.t_l_us, jnp.float32),
-        jnp.asarray(grid.m, jnp.int32),
-        jnp.asarray(grid.n_queues, jnp.int32),
-        jnp.asarray(grid.rate_mpps, jnp.float32),
-        jnp.asarray((seed64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)),
-        jnp.asarray((seed64 >> np.uint64(32)).astype(np.uint32)),
-        jnp.full((n,), float(cfg.duration_us), jnp.float32),
-        jnp.asarray(sched_edges, jnp.float32),
-        jnp.asarray(sched_scales, jnp.float32))
-    vals = {k: np.asarray(v, dtype=np.float64)
-            for k, v in out._asdict().items()}
-    return BatchStats(grid=grid, cfg=cfg, slot_us=float(slot_us),
-                      offered=vals["offered"], dropped=vals["dropped"],
-                      serviced=vals["serviced"], wakeups=vals["wakeups"],
-                      busy_tries=vals["busy_tries"], cycles=vals["cycles"],
-                      awake_us=vals["awake_us"], lat_area=vals["lat_area"],
-                      vac_sum=vals["vac_sum"], nv_sum=vals["nv_sum"],
-                      ts_arms=vals["ts_arms"], energy_uj=vals["energy_uj"],
-                      win=(np.asarray(win, dtype=np.float64)[:, :n_windows]
-                           if n_windows else np.empty(0)),
-                      stepping="fixed", scan_len=n_slots,
-                      n_steps=np.full(n, float(n_slots_true)),
-                      forced_steps=np.zeros(n),
-                      # fixed slots overshoot duration by the ceil slot
-                      sim_time_us=np.full(n, n_slots_true * slot_us),
-                      final_backlog=np.asarray(back_f, dtype=np.float64))
+    with tracing.span("batched.sweep", points=n, built=0) as sp:
+        n_seg, sched_edges, sched_scales = _schedule_rows(grid, cfg)
+        if stepping == "adaptive":
+            from .batched_adaptive import adaptive_kernel
+            fn, scan_len = adaptive_kernel(grid, cfg, float(slot_us), n_seg,
+                                           n_windows)
+        else:
+            scan_len = bucket_steps(n_slots_true)
+            sm = cfg.sleep_model
+            fn = _compiled_sweep(
+                scan_len, float(slot_us), int(grid.m.max()),
+                int(grid.n_queues.max()),
+                float(cfg.service_rate_mpps), float(cfg.queue_capacity),
+                float(cfg.wake_cost_us),
+                (float(sm.base_us), float(sm.slope), float(sm.sigma_us),
+                 float(sm.tail_prob), float(sm.tail_mean_us)),
+                (float(cfg.interference_prob),
+                 float(cfg.interference_mean_us),
+                 float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
+                cfg.energy_model.params(),
+                n_seg, bucket_steps(n_windows, base=8) if n_windows else 0,
+                float(cfg.window_us))
+        sp.counts["scan_steps"] = scan_len
+        seed64 = np.asarray(grid.seed, dtype=np.uint64)
+        args = (jnp.asarray(grid.t_s_us, jnp.float32),
+                jnp.asarray(grid.t_l_us, jnp.float32),
+                jnp.asarray(grid.m, jnp.int32),
+                jnp.asarray(grid.n_queues, jnp.int32),
+                jnp.asarray(grid.rate_mpps, jnp.float32),
+                jnp.asarray((seed64 & np.uint64(0xFFFFFFFF))
+                            .astype(np.uint32)),
+                jnp.asarray((seed64 >> np.uint64(32)).astype(np.uint32)),
+                # staged from host memory like the other inputs: an eager
+                # jnp.full can re-trace on every call (JAX 0.9) once a
+                # broadcast to the same shape from another operand ran
+                # first in the process, a compile inside a timed window
+                jnp.asarray(np.full(n, cfg.duration_us, np.float32)),
+                jnp.asarray(sched_edges, jnp.float32),
+                jnp.asarray(sched_scales, jnp.float32))
+        with tracing.span("batched.scan"):
+            out, win, back_f, *simt = jax.block_until_ready(fn(*args))
+        with tracing.span("batched.fetch"):
+            vals = {k: np.asarray(v, dtype=np.float64)
+                    for k, v in out._asdict().items()}
+            if stepping == "adaptive":
+                n_steps = vals.pop("n_steps")
+                forced_steps = vals.pop("forced_steps")
+                sim_time_us = np.asarray(simt[0], dtype=np.float64)
+            else:
+                n_steps = np.full(n, float(n_slots_true))
+                forced_steps = np.zeros(n)
+                # fixed slots overshoot duration by the ceil slot
+                sim_time_us = np.full(n, n_slots_true * slot_us)
+            return BatchStats(
+                grid=grid, cfg=cfg, slot_us=float(slot_us),
+                win=(np.asarray(win, dtype=np.float64)[:, :n_windows]
+                     if n_windows else np.empty(0)),
+                stepping=stepping, scan_len=scan_len, n_steps=n_steps,
+                forced_steps=forced_steps, sim_time_us=sim_time_us,
+                final_backlog=np.asarray(back_f, dtype=np.float64), **vals)

@@ -102,7 +102,7 @@ import jax.numpy as jnp
 
 from .simcore import SimRunConfig
 
-__all__ = ["estimate_adaptive_steps", "adaptive_sweep_arrays"]
+__all__ = ["estimate_adaptive_steps", "adaptive_kernel"]
 
 # SimRunConfig fields this engine deliberately does NOT read — the same
 # declarations batched.py makes, proven complete by the engine-parity
@@ -224,90 +224,92 @@ def _build_adaptive_sweep(max_steps: int, slot_us: float, m_max: int,
              next_stall, rem_t, A, win_acc) = carry
             now = duration - rem_t
             live = rem_t > 0.0
-            kt_step = jax.random.fold_in(key, t)
-            if tail_prob > 0.0:
-                kt_step, kp, ku = jax.random.split(kt_step, 3)
-            if intf_prob > 0.0:
-                kt_step, kip, kie = jax.random.split(kt_step, 3)
-            if stall_rate > 0.0:
-                kt_step, kse, ksg, ksu = jax.random.split(kt_step, 4)
-            # one fused normal draw covers arrivals + sleep noise
-            zs = jax.random.normal(kt_step, (q_max + m_max,))
+            with jax.named_scope("rng"):
+                kt_step = jax.random.fold_in(key, t)
+                if tail_prob > 0.0:
+                    kt_step, kp, ku = jax.random.split(kt_step, 3)
+                if intf_prob > 0.0:
+                    kt_step, kip, kie = jax.random.split(kt_step, 3)
+                if stall_rate > 0.0:
+                    kt_step, kse, ksg, ksu = jax.random.split(kt_step, 4)
+                # one fused normal draw covers arrivals + sleep noise
+                zs = jax.random.normal(kt_step, (q_max + m_max,))
 
             # ---- the jump: distance to the next interesting boundary
-            sleeping = tmask & (attached < 0)
-            occ = (jax.nn.one_hot(attached, q_max).sum(axis=0) > 0)
-            wake_dt = jnp.min(jnp.where(
-                sleeping, jnp.maximum(sleep_rem, 0.0), jnp.inf))
-            if n_seg > 0:
-                si = jnp.clip(
-                    jnp.searchsorted(sched_edges, now, side="right") - 1,
-                    0, n_seg - 1)
-                scale_t = sched_scales[si]
-                nxt_si = jnp.clip(si + 1, 0, n_seg - 1)
-                seg_dt = jnp.where(si + 1 < n_seg,
-                                   sched_edges[nxt_si] - now, jnp.inf)
-            else:
-                scale_t = jnp.float32(1.0)
-                seg_dt = jnp.float32(jnp.inf)
-            lam_q = jnp.where(qmask, lam * scale_t / nq, 0.0)
-            # locked queues: expected time to drain out (thread release)
-            net_out = jnp.where(occ, mu - lam_q, 0.0)
-            drain_q = jnp.where(
-                occ & (net_out > _RATE_EPS),
-                jnp.maximum(backlog, 0.0)
-                / jnp.maximum(net_out, _RATE_EPS), jnp.inf)
-            drain_dt = jnp.min(drain_q)
-            # filling queues: expected time to reach capacity (drops
-            # start; within one packet of capacity the path is flat at
-            # cap and no further fill chasing is needed)
-            net_in = lam_q - jnp.where(occ, mu, 0.0)
-            fill_dt = jnp.min(jnp.where(
-                qmask & (net_in > _RATE_EPS)
-                & (backlog < capacity - _FILL_SLACK_PKTS),
-                (capacity - backlog) / jnp.maximum(net_in, _RATE_EPS),
-                jnp.inf))
-            if n_windows > 0:
-                win_dt = ((jnp.floor(now / window_us) + 1.0) * window_us
-                          - now)
-            else:
-                win_dt = jnp.float32(jnp.inf)
-            stall_dt = next_stall - now
-            dt_b = jnp.minimum(
-                jnp.minimum(jnp.minimum(wake_dt, drain_dt),
-                            jnp.minimum(fill_dt, seg_dt)),
-                jnp.minimum(jnp.minimum(win_dt, stall_dt), rem_t))
-            # completion guard: only inside the reserved tail (the last
-            # eighth of the budget) is the remaining-average pace
-            # enforced — with an adequate budget rem hits 0 long before
-            # the tail and no jump is ever distorted; with a short one
-            # the tail sweeps to the end at the average pace instead of
-            # truncating.  (Enforcing rem/steps_left from step 0 would
-            # coarsen every boundary finer than the whole-run average.)
-            steps_left = jnp.float32(max_steps) - t.astype(jnp.float32)
-            in_tail = steps_left <= jnp.float32(max(max_steps // 8, 2))
-            pace = jnp.where(in_tail, rem_t / steps_left, 0.0)
-            # the floor never steps PAST a wake or a drain-out: stepping
-            # past a wake coalesces two nearby wakes into one boundary
-            # and turns the second thread's claim into a busy try (it
-            # sees the first claimant's lock); stretching a sub-floor
-            # residual drain holds the queue lock past its true empty
-            # time.  Either way P(wake lands in a busy period) inflates
-            # and the T_L parking feedback amplifies it ~4x at m > 1.
-            # Sub-floor steps stay budget-safe: their count is bounded
-            # by wakes + drain-outs, both inside the step estimate
-            floor_eff = jnp.minimum(
-                floor_us,
-                jnp.maximum(jnp.minimum(wake_dt, drain_dt),
-                            _WAKE_EPS_US))
-            dt = jnp.minimum(
-                jnp.maximum(dt_b, jnp.maximum(floor_eff, pace)), rem_t)
-            # a floor_us clamp over a sub-slot boundary is expected
-            # (adaptive never steps finer than fixed); "forced" counts
-            # only genuine budget pressure from the tail pace
-            forced = (dt > jnp.maximum(dt_b, floor_us) + _WAKE_EPS_US) \
-                & live
-            t_new = now + dt
+            with jax.named_scope("jump"):
+                sleeping = tmask & (attached < 0)
+                occ = (jax.nn.one_hot(attached, q_max).sum(axis=0) > 0)
+                wake_dt = jnp.min(jnp.where(
+                    sleeping, jnp.maximum(sleep_rem, 0.0), jnp.inf))
+                if n_seg > 0:
+                    si = jnp.clip(
+                        jnp.searchsorted(sched_edges, now, side="right") - 1,
+                        0, n_seg - 1)
+                    scale_t = sched_scales[si]
+                    nxt_si = jnp.clip(si + 1, 0, n_seg - 1)
+                    seg_dt = jnp.where(si + 1 < n_seg,
+                                       sched_edges[nxt_si] - now, jnp.inf)
+                else:
+                    scale_t = jnp.float32(1.0)
+                    seg_dt = jnp.float32(jnp.inf)
+                lam_q = jnp.where(qmask, lam * scale_t / nq, 0.0)
+                # locked queues: expected time to drain out (thread release)
+                net_out = jnp.where(occ, mu - lam_q, 0.0)
+                drain_q = jnp.where(
+                    occ & (net_out > _RATE_EPS),
+                    jnp.maximum(backlog, 0.0)
+                    / jnp.maximum(net_out, _RATE_EPS), jnp.inf)
+                drain_dt = jnp.min(drain_q)
+                # filling queues: expected time to reach capacity (drops
+                # start; within one packet of capacity the path is flat at
+                # cap and no further fill chasing is needed)
+                net_in = lam_q - jnp.where(occ, mu, 0.0)
+                fill_dt = jnp.min(jnp.where(
+                    qmask & (net_in > _RATE_EPS)
+                    & (backlog < capacity - _FILL_SLACK_PKTS),
+                    (capacity - backlog) / jnp.maximum(net_in, _RATE_EPS),
+                    jnp.inf))
+                if n_windows > 0:
+                    win_dt = ((jnp.floor(now / window_us) + 1.0) * window_us
+                              - now)
+                else:
+                    win_dt = jnp.float32(jnp.inf)
+                stall_dt = next_stall - now
+                dt_b = jnp.minimum(
+                    jnp.minimum(jnp.minimum(wake_dt, drain_dt),
+                                jnp.minimum(fill_dt, seg_dt)),
+                    jnp.minimum(jnp.minimum(win_dt, stall_dt), rem_t))
+                # completion guard: only inside the reserved tail (the last
+                # eighth of the budget) is the remaining-average pace
+                # enforced — with an adequate budget rem hits 0 long before
+                # the tail and no jump is ever distorted; with a short one
+                # the tail sweeps to the end at the average pace instead of
+                # truncating.  (Enforcing rem/steps_left from step 0 would
+                # coarsen every boundary finer than the whole-run average.)
+                steps_left = jnp.float32(max_steps) - t.astype(jnp.float32)
+                in_tail = steps_left <= jnp.float32(max(max_steps // 8, 2))
+                pace = jnp.where(in_tail, rem_t / steps_left, 0.0)
+                # the floor never steps PAST a wake or a drain-out: stepping
+                # past a wake coalesces two nearby wakes into one boundary
+                # and turns the second thread's claim into a busy try (it
+                # sees the first claimant's lock); stretching a sub-floor
+                # residual drain holds the queue lock past its true empty
+                # time.  Either way P(wake lands in a busy period) inflates
+                # and the T_L parking feedback amplifies it ~4x at m > 1.
+                # Sub-floor steps stay budget-safe: their count is bounded
+                # by wakes + drain-outs, both inside the step estimate
+                floor_eff = jnp.minimum(
+                    floor_us,
+                    jnp.maximum(jnp.minimum(wake_dt, drain_dt),
+                                _WAKE_EPS_US))
+                dt = jnp.minimum(
+                    jnp.maximum(dt_b, jnp.maximum(floor_eff, pace)), rem_t)
+                # a floor_us clamp over a sub-slot boundary is expected
+                # (adaptive never steps finer than fixed); "forced" counts
+                # only genuine budget pressure from the tail pace
+                forced = (dt > jnp.maximum(dt_b, floor_us) + _WAKE_EPS_US) \
+                    & live
+                t_new = now + dt
 
             # 1. arrivals over dt: residual-carried Gaussian fluid ~
             # Poisson — one draw of variance lam*dt per queue; admission
@@ -319,83 +321,89 @@ def _build_adaptive_sweep(max_steps: int, slot_us: float, m_max: int,
             # negative one cannot shorten it — release happens at the
             # boundary either way), which stretches busy periods and
             # biases the wake rate down through the parking feedback
-            drain_now = occ & (drain_q <= dt + _WAKE_EPS_US)
-            mu_a = lam_q * dt
-            z_q = jnp.where(drain_now, 0.0, zs[:q_max])
-            raw = arr_res + mu_a + jnp.sqrt(mu_a) * z_q
-            a = jnp.maximum(raw, 0.0)
-            arr_res = jnp.minimum(raw, 0.0)      # deficit carried forward
-            room = jnp.maximum(capacity - backlog, 0.0) \
-                + jnp.where(occ, mu * dt, 0.0)
-            adm = jnp.minimum(a, room)
-            offered = a.sum()
-            dropped = (a - adm).sum()
+            with jax.named_scope("arrivals"):
+                drain_now = occ & (drain_q <= dt + _WAKE_EPS_US)
+                mu_a = lam_q * dt
+                z_q = jnp.where(drain_now, 0.0, zs[:q_max])
+                raw = arr_res + mu_a + jnp.sqrt(mu_a) * z_q
+                a = jnp.maximum(raw, 0.0)
+                arr_res = jnp.minimum(raw, 0.0)      # deficit carried forward
+                room = jnp.maximum(capacity - backlog, 0.0) \
+                    + jnp.where(occ, mu * dt, 0.0)
+                adm = jnp.minimum(a, room)
+                offered = a.sum()
+                dropped = (a - adm).sum()
 
             # 2. drain: no locked queue empties strictly inside the
             # macro-slot (drain-out is a boundary), so min(B0 + adm,
             # mu*dt) is the exact aggregate of the per-slot updates
-            serve = jnp.where(occ,
-                              jnp.minimum(backlog + adm, mu * dt), 0.0)
-            b_new = jnp.minimum(jnp.maximum(backlog + adm - serve, 0.0),
-                                capacity)
-            served = serve.sum()
+            with jax.named_scope("drain"):
+                serve = jnp.where(occ,
+                                  jnp.minimum(backlog + adm, mu * dt), 0.0)
+                b_new = jnp.minimum(jnp.maximum(backlog + adm - serve, 0.0),
+                                    capacity)
+                served = serve.sum()
 
             # 3. Little integral: trapezoid over the linear fluid path;
             # vacations accrue on old occupancy BEFORE end-boundary
             # claims, so a harvested vacation includes its final
             # partial interval and a queue released at this boundary
             # starts its vacation next step — both event-exact
-            lat_area = 0.5 * (backlog.sum() + b_new.sum()) * dt
-            vac_timer = vac_timer + jnp.where(qmask & ~occ, dt, 0.0)
-            backlog = b_new
+            with jax.named_scope("accumulate"):
+                lat_area = 0.5 * (backlog.sum() + b_new.sum()) * dt
+                vac_timer = vac_timer + jnp.where(qmask & ~occ, dt, 0.0)
+                backlog = b_new
 
             # 4. stall process at the boundary: the carried Exp
             # inter-arrival fires when the jump reaches it; overlapping
             # windows extend (max), the event engine's lazy merge.  At
             # most one window opens per macro-slot (a forced jump past
             # two starts opens the second one step late).
-            if stall_rate > 0.0:
-                fire = (next_stall <= t_new) & live
-                w_end = next_stall \
-                    + stall_mean_us * jax.random.exponential(kse, ())
-                stall_end = jnp.where(fire,
-                                      jnp.maximum(stall_end, w_end),
-                                      stall_end)
-                gap = jax.random.exponential(ksg, ()) / stall_rate
-                next_stall = jnp.where(fire, next_stall + gap, next_stall)
+            with jax.named_scope("stall"):
+                if stall_rate > 0.0:
+                    fire = (next_stall <= t_new) & live
+                    w_end = next_stall \
+                        + stall_mean_us * jax.random.exponential(kse, ())
+                    stall_end = jnp.where(fire,
+                                          jnp.maximum(stall_end, w_end),
+                                          stall_end)
+                    gap = jax.random.exponential(ksg, ()) / stall_rate
+                    next_stall = jnp.where(fire, next_stall + gap, next_stall)
 
             # sleep overshoot + per-wake OS interference draws — one per
             # thread per macro-slot, charged only on re-arm events, the
             # same per-sleep rate contract as the fixed engine
-            over = jnp.full((m_max,), base_us)
-            if sigma_us > 0.0:
-                over = over + sigma_us * jnp.abs(zs[q_max:])
-            if tail_prob > 0.0:
-                hit = jax.random.uniform(kp, (m_max,)) < tail_prob
-                over = over + hit * tail_mean_us * jax.random.exponential(
-                    ku, (m_max,))
-            if intf_prob > 0.0:
-                ihit = jax.random.uniform(kip, (m_max,)) < intf_prob
-                over = over + ihit * intf_mean_us * jax.random.exponential(
-                    kie, (m_max,))
-            slp_s = t_s * (1.0 + slope) + over
-            slp_l = t_l * (1.0 + slope) + over
+            with jax.named_scope("overshoot"):
+                over = jnp.full((m_max,), base_us)
+                if sigma_us > 0.0:
+                    over = over + sigma_us * jnp.abs(zs[q_max:])
+                if tail_prob > 0.0:
+                    hit = jax.random.uniform(kp, (m_max,)) < tail_prob
+                    over = over + hit * tail_mean_us * jax.random.exponential(
+                        ku, (m_max,))
+                if intf_prob > 0.0:
+                    ihit = jax.random.uniform(kip, (m_max,)) < intf_prob
+                    over = over + ihit * intf_mean_us * jax.random.exponential(
+                        kie, (m_max,))
+                slp_s = t_s * (1.0 + slope) + over
+                slp_l = t_l * (1.0 + slope) + over
 
             # 5. wakes at the boundary: timers land exactly on it when
             # the wake governed the jump; the (negative) residual carry
             # keeps wake rates unbiased when a forced jump overshot
-            sleep_rem = jnp.where(sleeping, sleep_rem - dt, sleep_rem)
-            woken = sleeping & (sleep_rem <= _WAKE_EPS_US) & live
-            if stall_rate > 0.0:
-                # timers expiring inside an open stall window defer to
-                # its end (+U(0,1)us re-arm jitter), not counted as wakes
-                push = woken & (t_new < stall_end)
-                woken = woken & ~push
-                sleep_rem = jnp.where(
-                    push,
-                    stall_end - t_new + jax.random.uniform(ksu, (m_max,)),
-                    sleep_rem)
-            n_wake = woken.sum().astype(jnp.float32)
+            with jax.named_scope("wake_claim"):
+                sleep_rem = jnp.where(sleeping, sleep_rem - dt, sleep_rem)
+                woken = sleeping & (sleep_rem <= _WAKE_EPS_US) & live
+                if stall_rate > 0.0:
+                    # timers expiring inside an open stall window defer to
+                    # its end (+U(0,1)us re-arm jitter), not counted as wakes
+                    push = woken & (t_new < stall_end)
+                    woken = woken & ~push
+                    sleep_rem = jnp.where(
+                        push,
+                        stall_end - t_new + jax.random.uniform(ksu, (m_max,)),
+                        sleep_rem)
+                n_wake = woken.sum().astype(jnp.float32)
 
             # 6. queues drained out by the boundary release their
             # thread (fresh T_S sleep, no residual) BEFORE boundary
@@ -403,81 +411,85 @@ def _build_adaptive_sweep(max_steps: int, slot_us: float, m_max: int,
             # time, so a thread waking at the boundary must see the
             # queue free — release-after-claim would misclassify it as
             # a busy try and park it on T_L
-            q_done = occ & (backlog <= 1e-6)
-            att_q = jnp.clip(attached, 0, q_max - 1)
-            t_done = (attached >= 0) & q_done[att_q]
-            sleep_rem = jnp.where(t_done, slp_s, sleep_rem)
-            attached = jnp.where(t_done, -1, attached)
-            occ = occ & ~q_done
+            with jax.named_scope("release"):
+                q_done = occ & (backlog <= 1e-6)
+                att_q = jnp.clip(attached, 0, q_max - 1)
+                t_done = (attached >= 0) & q_done[att_q]
+                sleep_rem = jnp.where(t_done, slp_s, sleep_rem)
+                attached = jnp.where(t_done, -1, attached)
+                occ = occ & ~q_done
 
             # 7. claim loop — identical to the fixed-slot kernel's
-            busy_tries = jnp.float32(0.0)
-            cycles = jnp.float32(0.0)
-            vac_sum = jnp.float32(0.0)
-            nv_sum = jnp.float32(0.0)
-            ts_arm = t_done.sum().astype(jnp.float32)
-            for i in range(m_max):            # static unroll, m_max small
-                w = woken[i]
-                free_q = qmask & ~occ
-                claimable = free_q & (backlog >= 1.0)
-                qi = jnp.argmax(jnp.where(claimable, backlog, -1.0))
-                do_attach = w & claimable.any()
-                empty_claim = w & ~claimable.any() & free_q.any()
-                eqi = jnp.argmax(free_q)      # first free (empty) queue
-                blocked = w & ~free_q.any()
+            with jax.named_scope("wake_claim"):
+                busy_tries = jnp.float32(0.0)
+                cycles = jnp.float32(0.0)
+                vac_sum = jnp.float32(0.0)
+                nv_sum = jnp.float32(0.0)
+                ts_arm = t_done.sum().astype(jnp.float32)
+                for i in range(m_max):            # static unroll, m_max small
+                    w = woken[i]
+                    free_q = qmask & ~occ
+                    claimable = free_q & (backlog >= 1.0)
+                    qi = jnp.argmax(jnp.where(claimable, backlog, -1.0))
+                    do_attach = w & claimable.any()
+                    empty_claim = w & ~claimable.any() & free_q.any()
+                    eqi = jnp.argmax(free_q)      # first free (empty) queue
+                    blocked = w & ~free_q.any()
 
-                claim_hot = do_attach & (q_idx == qi)
-                claim_any = claim_hot | (empty_claim & (q_idx == eqi))
-                vac_sum = vac_sum + (vac_timer * claim_any).sum()
-                nv_sum = nv_sum + jnp.where(do_attach, backlog[qi], 0.0)
-                vac_timer = jnp.where(claim_any, 0.0, vac_timer)
-                cycles = cycles + (do_attach | empty_claim)
-                busy_tries = busy_tries + blocked
-                ts_arm = ts_arm + empty_claim
-                attached = attached.at[i].set(
-                    jnp.where(do_attach, qi, attached[i]))
-                occ = occ | claim_hot
-                # re-sleep adds onto the expired-timer residual
-                sleep_rem = sleep_rem.at[i].add(
-                    jnp.where(empty_claim, slp_s[i],
-                              jnp.where(blocked, slp_l[i], 0.0)))
+                    claim_hot = do_attach & (q_idx == qi)
+                    claim_any = claim_hot | (empty_claim & (q_idx == eqi))
+                    vac_sum = vac_sum + (vac_timer * claim_any).sum()
+                    nv_sum = nv_sum + jnp.where(do_attach, backlog[qi], 0.0)
+                    vac_timer = jnp.where(claim_any, 0.0, vac_timer)
+                    cycles = cycles + (do_attach | empty_claim)
+                    busy_tries = busy_tries + blocked
+                    ts_arm = ts_arm + empty_claim
+                    attached = attached.at[i].set(
+                        jnp.where(do_attach, qi, attached[i]))
+                    occ = occ | claim_hot
+                    # re-sleep adds onto the expired-timer residual
+                    sleep_rem = sleep_rem.at[i].add(
+                        jnp.where(empty_claim, slp_s[i],
+                                  jnp.where(blocked, slp_l[i], 0.0)))
 
-            rem_t = rem_t - dt
-            # energy: active power over this step's awake time plus the
-            # per-arm C-state charges (blocked wakes re-arm T_L)
-            awake_step = n_wake * wake_cost_us + served / mu
-            energy_step = (active_power_w * awake_step
-                           + ts_arm * e_arm_s + busy_tries * e_arm_l)
-            A = _AdaptiveStats(
-                offered=A.offered + offered,
-                dropped=A.dropped + dropped,
-                serviced=A.serviced + served,
-                wakeups=A.wakeups + n_wake,
-                busy_tries=A.busy_tries + busy_tries,
-                cycles=A.cycles + cycles,
-                awake_us=A.awake_us + awake_step,
-                lat_area=A.lat_area + lat_area,
-                vac_sum=A.vac_sum + vac_sum,
-                nv_sum=A.nv_sum + nv_sum,
-                ts_arms=A.ts_arms + ts_arm,
-                energy_uj=A.energy_uj + energy_step,
-                n_steps=A.n_steps + 1.0,
-                forced_steps=A.forced_steps + forced.astype(jnp.float32),
-            )
-            if n_windows > 0:
-                # window edges are jump boundaries, so a macro-slot
-                # never spans windows (except forced jumps, attributed
-                # to the window containing their start)
-                wi = jnp.clip((now / window_us).astype(jnp.int32),
-                              0, n_windows - 1)
-                win_acc = win_acc.at[wi].add(jnp.stack([
-                    offered, served, lat_area, awake_step, energy_step]))
-            nxt = (sleep_rem, attached, backlog, vac_timer, arr_res,
-                   stall_end, next_stall, rem_t, A, win_acc)
-            # finished points hold their carry: every later step is a
-            # no-op, so one compiled budget serves the whole batch
-            gated = jax.tree_util.tree_map(
-                lambda new, old: jnp.where(live, new, old), nxt, prev)
+            with jax.named_scope("accumulate"):
+                rem_t = rem_t - dt
+                # energy: active power over this step's awake time plus the
+                # per-arm C-state charges (blocked wakes re-arm T_L)
+                awake_step = n_wake * wake_cost_us + served / mu
+                energy_step = (active_power_w * awake_step
+                               + ts_arm * e_arm_s + busy_tries * e_arm_l)
+                A = _AdaptiveStats(
+                    offered=A.offered + offered,
+                    dropped=A.dropped + dropped,
+                    serviced=A.serviced + served,
+                    wakeups=A.wakeups + n_wake,
+                    busy_tries=A.busy_tries + busy_tries,
+                    cycles=A.cycles + cycles,
+                    awake_us=A.awake_us + awake_step,
+                    lat_area=A.lat_area + lat_area,
+                    vac_sum=A.vac_sum + vac_sum,
+                    nv_sum=A.nv_sum + nv_sum,
+                    ts_arms=A.ts_arms + ts_arm,
+                    energy_uj=A.energy_uj + energy_step,
+                    n_steps=A.n_steps + 1.0,
+                    forced_steps=A.forced_steps + forced.astype(jnp.float32),
+                )
+                if n_windows > 0:
+                    # window edges are jump boundaries, so a macro-slot
+                    # never spans windows (except forced jumps, attributed
+                    # to the window containing their start)
+                    wi = jnp.clip((now / window_us).astype(jnp.int32),
+                                  0, n_windows - 1)
+                    win_acc = win_acc.at[wi].add(jnp.stack([
+                        offered, served, lat_area, awake_step, energy_step]))
+            with jax.named_scope("gate"):
+                nxt = (sleep_rem, attached, backlog, vac_timer, arr_res,
+                       stall_end, next_stall, rem_t, A, win_acc)
+                # finished points hold their carry: every later step is a
+                # no-op, so one compiled budget serves the whole batch
+                gated = jax.tree_util.tree_map(
+                    lambda new, old: jnp.where(live, new, old), nxt, prev)
             return gated, None
 
         z0 = jnp.float32(0.0)
@@ -500,7 +512,11 @@ def _build_adaptive_sweep(max_steps: int, slot_us: float, m_max: int,
         # completed point has rem_f == 0.0 exactly (not approximately)
         return A, win_acc, backlog_f.sum(), duration - rem_f
 
-    return jax.jit(jax.vmap(one_point))
+    def adaptive_sweep(*points):
+        return jax.vmap(one_point)(*points)
+
+    # the function's name is the compiled module's: jit_adaptive_sweep
+    return jax.jit(adaptive_sweep)
 
 
 # created on first use so importing this module has no registry side
@@ -509,18 +525,17 @@ def _build_adaptive_sweep(max_steps: int, slot_us: float, m_max: int,
 _compiled_adaptive_sweep = None
 
 
-def adaptive_sweep_arrays(grid, cfg: SimRunConfig, slot_us: float):
-    """Run ``grid`` through the event-jump kernel.
-
-    Returns ``(vals, win, final_backlog, sim_time_us, scan_len)`` in
-    the array conventions ``simulate_batch`` assembles into
-    ``BatchStats`` — this module owns the kernel; dispatch, validation
-    and result packaging stay in ``batched.simulate_batch``.
-    """
+def adaptive_kernel(grid, cfg: SimRunConfig, slot_us: float, n_seg: int,
+                    n_windows: int):
+    """The jitted event-jump kernel for ``grid``'s static shape, and its
+    scan length (the bucketed step budget).  It takes the fixed
+    kernel's inputs and returns ``(stats, windows, final_backlog,
+    sim_time_us)``; ``batched.simulate_batch`` stages, runs and packages
+    it like the fixed kernel."""
     # batched.py imports this module lazily inside simulate_batch and
     # this import runs only at call time, so the two modules stay
     # import-order independent
-    from .batched import CompileCache, _schedule_rows, bucket_steps
+    from .batched import CompileCache, bucket_steps
 
     global _compiled_adaptive_sweep
     if _compiled_adaptive_sweep is None:
@@ -528,16 +543,12 @@ def adaptive_sweep_arrays(grid, cfg: SimRunConfig, slot_us: float):
             _build_adaptive_sweep, maxsize=64,
             name="batched_adaptive._compiled_adaptive_sweep")
 
-    n_windows = (int(math.ceil(cfg.duration_us / cfg.window_us))
-                 if cfg.window_us > 0 else 0)
-    m_max = int(grid.m.max())
-    q_max = int(grid.n_queues.max())
-    n_seg, sched_edges, sched_scales = _schedule_rows(grid, cfg)
     max_steps = bucket_steps(
         estimate_adaptive_steps(grid, cfg, slot_us, n_windows))
     sm = cfg.sleep_model
     fn = _compiled_adaptive_sweep(
-        max_steps, float(slot_us), m_max, q_max,
+        max_steps, float(slot_us), int(grid.m.max()),
+        int(grid.n_queues.max()),
         float(cfg.service_rate_mpps), float(cfg.queue_capacity),
         float(cfg.wake_cost_us),
         (float(sm.base_us), float(sm.slope), float(sm.sigma_us),
@@ -546,22 +557,4 @@ def adaptive_sweep_arrays(grid, cfg: SimRunConfig, slot_us: float):
          float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
         cfg.energy_model.params(),
         n_seg, n_windows, float(cfg.window_us))
-    seed64 = np.asarray(grid.seed, dtype=np.uint64)
-    n = len(grid)
-    out, win, back_f, simt = fn(
-        jnp.asarray(grid.t_s_us, jnp.float32),
-        jnp.asarray(grid.t_l_us, jnp.float32),
-        jnp.asarray(grid.m, jnp.int32),
-        jnp.asarray(grid.n_queues, jnp.int32),
-        jnp.asarray(grid.rate_mpps, jnp.float32),
-        jnp.asarray((seed64 & np.uint64(0xFFFFFFFF)).astype(np.uint32)),
-        jnp.asarray((seed64 >> np.uint64(32)).astype(np.uint32)),
-        jnp.full((n,), float(cfg.duration_us), jnp.float32),
-        jnp.asarray(sched_edges, jnp.float32),
-        jnp.asarray(sched_scales, jnp.float32))
-    vals = {k: np.asarray(v, dtype=np.float64)
-            for k, v in out._asdict().items()}
-    win_np = (np.asarray(win, dtype=np.float64) if n_windows
-              else np.empty(0))
-    return (vals, win_np, np.asarray(back_f, dtype=np.float64),
-            np.asarray(simt, dtype=np.float64), max_steps)
+    return fn, max_steps

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.core import analytics
 
+from . import tracing
 from .batched import SweepGrid, simulate_batch, validate_batched_config
 from .simcore import SimRunConfig
 
@@ -352,131 +353,134 @@ def build_operating_table(
 
     The returned table records ``cfg`` as its ``environment``.
     """
-    cfg = cfg or SimRunConfig(duration_us=60_000.0)
-    validate_batched_config(cfg)
-    if objective not in ("cpu", "energy"):
-        raise ValueError(
-            f"objective must be 'cpu' or 'energy', got {objective!r}")
-    if cfg.schedule is not None:
-        raise ValueError(
-            "calibration sweeps must run on stationary loads: each table "
-            "rung is labeled with one rho, which a cfg.schedule would "
-            "modulate mid-measurement.  Pass the schedule as "
-            "schedule_check= to validate the finished table under "
-            "nonstationary load instead")
-    rhos = np.atleast_1d(np.asarray(rhos, dtype=np.float64))
-    mu = cfg.service_rate_mpps
-    if fleet is not None:
-        fleet.validate()
-        peak_fleet_mpps = float(np.max(rhos)) * mu * fleet.n_hosts
-        topo_us = fleet.mean_topo_delay_us(peak_fleet_mpps)
-        if topo_us >= target_mean_latency_us:
+    with tracing.span("calibrate.table"):
+        cfg = cfg or SimRunConfig(duration_us=60_000.0)
+        validate_batched_config(cfg)
+        if objective not in ("cpu", "energy"):
             raise ValueError(
-                f"fleet topology delay ({topo_us:.2f}us at peak "
-                f"{peak_fleet_mpps:.2f} Mpps) consumes the whole "
-                f"{target_mean_latency_us:g}us latency target — no host "
-                f"budget remains")
-        target_mean_latency_us = target_mean_latency_us - topo_us
-    grid = SweepGrid.product(t_s_us=t_s_grid, t_l_us=t_l_grid, m=m_grid,
-                             n_queues=(cfg.n_queues,),
-                             rate_mpps=rhos * mu, seeds=seeds)
-    if sweep is None:
-        bs = simulate_batch(grid, cfg, slot_us=slot_us,
-                            stepping=stepping)
-    else:
-        # the precomputed sweep must be THIS lattice simulated in THIS
-        # environment — matching shape alone would let metrics from one
-        # grid be labeled with another grid's parameters
-        same_axes = (sweep.grid.shape == grid.shape and all(
-            np.array_equal(getattr(sweep.grid, f), getattr(grid, f))
-            for f in ("t_s_us", "t_l_us", "m", "n_queues", "rate_mpps",
-                      "seed")))
-        if not (same_axes and sweep.cfg == cfg
-                and sweep.slot_us == float(slot_us)
-                and sweep.stepping == stepping):
+                f"objective must be 'cpu' or 'energy', got {objective!r}")
+        if cfg.schedule is not None:
             raise ValueError(
-                "precomputed sweep does not match the requested lattice/"
-                "environment (grid axes, SimRunConfig, slot_us and "
-                "stepping must all be identical)")
-        bs = sweep
-
-    # seed-averaged metrics on the (ts, tl, m, nq, rho, seed) lattice
-    lat = bs.reshaped("mean_latency_us").mean(axis=-1)
-    cpu = bs.reshaped("cpu_fraction").mean(axis=-1)
-    loss = bs.reshaped("loss_fraction").mean(axis=-1)
-    vac = bs.reshaped("mean_vacation_us").mean(axis=-1)
-    energy = bs.reshaped("energy_uj").mean(axis=-1)
-
-    ts_ax = np.atleast_1d(np.asarray(t_s_grid, dtype=np.float64))
-    tl_ax = np.atleast_1d(np.asarray(t_l_grid, dtype=np.float64))
-    m_ax = np.atleast_1d(np.asarray(m_grid))
-    # analytic guard: engine and closed form must roughly agree — at the
-    # per-queue load, with the noisy-host slack for this environment
-    valid = analytic_guard_mask(vac, ts_ax, tl_ax, m_ax, rhos,
-                                guard_rel=analytic_guard_rel,
-                                slot_us=slot_us,
-                                n_queues=(cfg.n_queues,),
-                                slack_us=cfg.interference_slack_us())
-    feasible = valid & (lat <= target_mean_latency_us) & (loss <= max_loss)
-
-    cost = cpu if objective == "cpu" else energy
-    points = []
-    big = np.inf
-    for k, rho in enumerate(rhos):
-        feas_k = feasible[..., k]
-        if feas_k.any():
-            cost_k = np.where(feas_k, cost[..., k], big)
-            i, j, l, _ = np.unravel_index(int(np.argmin(cost_k)),
-                                          cost_k.shape)
-            met = True
+                "calibration sweeps must run on stationary loads: each table "
+                "rung is labeled with one rho, which a cfg.schedule would "
+                "modulate mid-measurement.  Pass the schedule as "
+                "schedule_check= to validate the finished table under "
+                "nonstationary load instead")
+        rhos = np.atleast_1d(np.asarray(rhos, dtype=np.float64))
+        mu = cfg.service_rate_mpps
+        if fleet is not None:
+            fleet.validate()
+            peak_fleet_mpps = float(np.max(rhos)) * mu * fleet.n_hosts
+            topo_us = fleet.mean_topo_delay_us(peak_fleet_mpps)
+            if topo_us >= target_mean_latency_us:
+                raise ValueError(
+                    f"fleet topology delay ({topo_us:.2f}us at peak "
+                    f"{peak_fleet_mpps:.2f} Mpps) consumes the whole "
+                    f"{target_mean_latency_us:g}us latency target — no host "
+                    f"budget remains")
+            target_mean_latency_us = target_mean_latency_us - topo_us
+        grid = SweepGrid.product(t_s_us=t_s_grid, t_l_us=t_l_grid, m=m_grid,
+                                 n_queues=(cfg.n_queues,),
+                                 rate_mpps=rhos * mu, seeds=seeds)
+        if sweep is None:
+            bs = simulate_batch(grid, cfg, slot_us=slot_us,
+                                stepping=stepping)
         else:
-            lat_k = np.where(valid[..., k], lat[..., k], big)
-            if not np.isfinite(lat_k).any():
-                lat_k = lat[..., k]                 # last resort: raw
-            i, j, l, _ = np.unravel_index(int(np.argmin(lat_k)),
-                                          lat_k.shape)
-            met = False
-        points.append(OperatingPoint(
-            rho=float(rho), t_s_us=float(ts_ax[i]), t_l_us=float(tl_ax[j]),
-            m=int(m_ax[l]), mean_latency_us=float(lat[i, j, l, 0, k]),
-            cpu_fraction=float(cpu[i, j, l, 0, k]),
-            loss_fraction=float(loss[i, j, l, 0, k]), meets_target=met,
-            energy_uj=float(energy[i, j, l, 0, k])))
+            # the precomputed sweep must be THIS lattice simulated in THIS
+            # environment — matching shape alone would let metrics from one
+            # grid be labeled with another grid's parameters
+            same_axes = (sweep.grid.shape == grid.shape and all(
+                np.array_equal(getattr(sweep.grid, f), getattr(grid, f))
+                for f in ("t_s_us", "t_l_us", "m", "n_queues", "rate_mpps",
+                          "seed")))
+            if not (same_axes and sweep.cfg == cfg
+                    and sweep.slot_us == float(slot_us)
+                    and sweep.stepping == stepping):
+                raise ValueError(
+                    "precomputed sweep does not match the requested lattice/"
+                    "environment (grid axes, SimRunConfig, slot_us and "
+                    "stepping must all be identical)")
+            bs = sweep
 
-    env = asdict(cfg)
-    if fleet is not None:
-        env["fleet"] = asdict(fleet)
-    # JSON-canonical from the start (tuples -> lists), so the recorded
-    # environment survives a to_json/from_json round trip unchanged
-    env = json.loads(json.dumps(env))
-    table = OperatingTable(target_mean_latency_us=target_mean_latency_us,
-                           service_rate_mpps=mu, points=tuple(points),
-                           environment=env)
+        # seed-averaged metrics on the (ts, tl, m, nq, rho, seed) lattice
+        lat = bs.reshaped("mean_latency_us").mean(axis=-1)
+        cpu = bs.reshaped("cpu_fraction").mean(axis=-1)
+        loss = bs.reshaped("loss_fraction").mean(axis=-1)
+        vac = bs.reshaped("mean_vacation_us").mean(axis=-1)
+        energy = bs.reshaped("energy_uj").mean(axis=-1)
 
-    if spot_check:
-        # contention-honest: the exact engine re-examines selected points
-        # in the environment the table claims to be calibrated for —
-        # interference and stalls included.  (This used to quiet the
-        # config first, laundering noisy-host tables through quiet-host
-        # validation.)  Noisy environments get the batched engine's wider
-        # documented parity floors.
-        lat_floor, cpu_floor = (4.5, 0.04) if cfg.is_noisy else (2.0, 0.03)
-        idxs = np.linspace(0, len(points) - 1,
-                           min(spot_check, len(points))).astype(int)
-        for i in sorted(set(idxs.tolist())):
-            p = points[i]
-            rs = _event_sim_point(p, cfg, p.rho * mu)
-            lat_err = abs(rs.mean_sojourn_us - p.mean_latency_us)
-            cpu_err = abs(rs.cpu_fraction - p.cpu_fraction)
-            if (lat_err > spot_check_rel * p.mean_latency_us + lat_floor
-                    or cpu_err > spot_check_rel * p.cpu_fraction
-                    + cpu_floor):
-                raise CalibrationMismatch(
-                    f"operating point {p} failed its event-engine spot "
-                    f"check: event mean sojourn {rs.mean_sojourn_us:.2f}us "
-                    f"vs batched {p.mean_latency_us:.2f}us, event cpu "
-                    f"{rs.cpu_fraction:.3f} vs batched "
-                    f"{p.cpu_fraction:.3f}")
-    if schedule_check is not None:
-        schedule_spot_check(table, schedule_check, cfg=cfg)
-    return table
+        ts_ax = np.atleast_1d(np.asarray(t_s_grid, dtype=np.float64))
+        tl_ax = np.atleast_1d(np.asarray(t_l_grid, dtype=np.float64))
+        m_ax = np.atleast_1d(np.asarray(m_grid))
+        # analytic guard: engine and closed form must roughly agree — at the
+        # per-queue load, with the noisy-host slack for this environment
+        valid = analytic_guard_mask(vac, ts_ax, tl_ax, m_ax, rhos,
+                                    guard_rel=analytic_guard_rel,
+                                    slot_us=slot_us,
+                                    n_queues=(cfg.n_queues,),
+                                    slack_us=cfg.interference_slack_us())
+        feasible = valid & (lat <= target_mean_latency_us) & (loss <= max_loss)
+
+        cost = cpu if objective == "cpu" else energy
+        points = []
+        big = np.inf
+        for k, rho in enumerate(rhos):
+            feas_k = feasible[..., k]
+            if feas_k.any():
+                cost_k = np.where(feas_k, cost[..., k], big)
+                i, j, l, _ = np.unravel_index(int(np.argmin(cost_k)),
+                                              cost_k.shape)
+                met = True
+            else:
+                lat_k = np.where(valid[..., k], lat[..., k], big)
+                if not np.isfinite(lat_k).any():
+                    lat_k = lat[..., k]                 # last resort: raw
+                i, j, l, _ = np.unravel_index(int(np.argmin(lat_k)),
+                                              lat_k.shape)
+                met = False
+            points.append(OperatingPoint(
+                rho=float(rho), t_s_us=float(ts_ax[i]), t_l_us=float(tl_ax[j]),
+                m=int(m_ax[l]), mean_latency_us=float(lat[i, j, l, 0, k]),
+                cpu_fraction=float(cpu[i, j, l, 0, k]),
+                loss_fraction=float(loss[i, j, l, 0, k]), meets_target=met,
+                energy_uj=float(energy[i, j, l, 0, k])))
+
+        env = asdict(cfg)
+        if fleet is not None:
+            env["fleet"] = asdict(fleet)
+        # JSON-canonical from the start (tuples -> lists), so the recorded
+        # environment survives a to_json/from_json round trip unchanged
+        env = json.loads(json.dumps(env))
+        table = OperatingTable(target_mean_latency_us=target_mean_latency_us,
+                               service_rate_mpps=mu, points=tuple(points),
+                               environment=env)
+
+        if spot_check:
+            # contention-honest: the exact engine re-examines selected points
+            # in the environment the table claims to be calibrated for —
+            # interference and stalls included.  (This used to quiet the
+            # config first, laundering noisy-host tables through quiet-host
+            # validation.)  Noisy environments get the batched engine's wider
+            # documented parity floors.
+            lat_floor, cpu_floor = (4.5, 0.04) if cfg.is_noisy else (2.0, 0.03)
+            idxs = np.linspace(0, len(points) - 1,
+                               min(spot_check, len(points))).astype(int)
+            for i in sorted(set(idxs.tolist())):
+                p = points[i]
+                with tracing.span("calibrate.spot_check", rho=i):
+                    rs = _event_sim_point(p, cfg, p.rho * mu)
+                lat_err = abs(rs.mean_sojourn_us - p.mean_latency_us)
+                cpu_err = abs(rs.cpu_fraction - p.cpu_fraction)
+                if (lat_err > spot_check_rel * p.mean_latency_us + lat_floor
+                        or cpu_err > spot_check_rel * p.cpu_fraction
+                        + cpu_floor):
+                    raise CalibrationMismatch(
+                        f"operating point {p} failed its event-engine spot "
+                        f"check: event mean sojourn "
+                        f"{rs.mean_sojourn_us:.2f}us vs batched "
+                        f"{p.mean_latency_us:.2f}us, event cpu "
+                        f"{rs.cpu_fraction:.3f} vs batched "
+                        f"{p.cpu_fraction:.3f}")
+        if schedule_check is not None:
+            schedule_spot_check(table, schedule_check, cfg=cfg)
+        return table
