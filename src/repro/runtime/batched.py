@@ -674,7 +674,8 @@ def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
                     claim_hot = do_attach & (q_idx == qi)
                     claim_any = claim_hot | (empty_claim & (q_idx == eqi))
                     vac_sum = vac_sum + (vac_timer * claim_any).sum()
-                    nv_sum = nv_sum + jnp.where(do_attach, backlog[qi], 0.0)
+                    # one nonzero term at most, so the sum is exact
+                    nv_sum = nv_sum + jnp.where(claim_hot, backlog, 0.0).sum()
                     vac_timer = jnp.where(claim_any, 0.0, vac_timer)
                     cycles = cycles + (do_attach | empty_claim)
                     busy_tries = busy_tries + blocked
@@ -697,8 +698,10 @@ def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
             # 4. emptied queues release their thread (fresh T_S sleep)
             with jax.named_scope("release"):
                 q_done = occ & (backlog <= 1e-6)
-                att_q = jnp.clip(attached, 0, q_max - 1)
-                t_done = (attached >= 0) & q_done[att_q]
+                # one-hot over the queue axis: a per-point gather here
+                # costs most of a scan step on a TPU; a detached
+                # thread (-1) matches no queue
+                t_done = ((attached[:, None] == q_idx) & q_done).any(axis=1)
                 ts_arm = ts_arm + t_done.sum()
                 sleep_rem = jnp.where(t_done, slp_s, sleep_rem)
                 attached = jnp.where(t_done, -1, attached)

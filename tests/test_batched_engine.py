@@ -2,6 +2,9 @@
 cross-validation, and one-call sweep scale (the acceptance criteria of
 the batched-backend refactor)."""
 
+import json
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -284,3 +287,37 @@ def test_sweep_grid_product_shape_and_point():
     cfg = SimRunConfig(duration_us=2_000.0)
     bs = simulate_batch(grid, cfg, slot_us=1.0)
     assert bs.reshaped("cpu_fraction").shape == grid.shape
+
+
+@pytest.mark.parametrize("q_max", [1, 4])
+@pytest.mark.parametrize("kind", ["fixed", "adaptive", "fleet"])
+def test_kernels_take_the_queue_axis_without_gathers(scan_kernel, kind,
+                                                     q_max):
+    """A thread's queue (release) and the claimed queue's backlog
+    (wake_claim) are read by one-hot selects over the queue axis: the
+    compiled kernel holds no gather, at one queue or several."""
+    fn, args = scan_kernel(kind, q_max)
+    assert fn.lower(*args).compile().as_text().count("gather(") == 0
+
+
+# every per-point counter of the fixed kernel over a mixed grid (one to
+# four threads and queues, light to heavy load, a seed with high bits
+# set), as the kernel gave them on the CPU when it read both sites by
+# per-point gathers; the selects that replaced them must match bit for
+# bit
+GOLDEN = Path(__file__).parent / "golden" / "fixed_scan_counters.json"
+
+
+def test_fixed_kernel_counters_match_golden_bit_for_bit():
+    cfg = SimRunConfig(duration_us=4_000.0)
+    grid = SweepGrid.product(
+        t_s_us=[8.0, 20.0], t_l_us=[250.0], m=[1, 3, 4], n_queues=[1, 2, 4],
+        rate_mpps=np.array([0.1, 0.47, 0.87]) * cfg.service_rate_mpps,
+        seeds=(3, 2**33 + 5))
+    bs = simulate_batch(grid, cfg, slot_us=0.5)
+    golden = json.loads(GOLDEN.read_text())
+    assert len(golden) == 12
+    for name, want in golden.items():
+        assert len(want) == len(grid) == 108
+        np.testing.assert_array_equal(getattr(bs, name), want,
+                                      err_msg=name)

@@ -65,27 +65,6 @@ def _compile(fn, *args):
     return compiled
 
 
-class _Captured(Exception):
-    pass
-
-
-def _capture(monkeypatch, module, attr, build):
-    """Swap a module's compile cache for one that hands back the jitted
-    kernel and the arguments the entry point prepared, without running."""
-    seen = {}
-
-    def cache(*static):
-        fn = build(*static)
-
-        def call(*args):
-            seen["fn"], seen["args"] = fn, args
-            raise _Captured
-        return call
-
-    monkeypatch.setattr(module, attr, cache)
-    return seen
-
-
 def _lattice():
     from benchmarks.sweep_frontier import lattice
 
@@ -98,41 +77,46 @@ def _lattice():
     return grid, cfg, lat["slot_us"]
 
 
-def test_fixed_sweep_kernel_compiles(monkeypatch, on_chip):
+def _compile_gather_free(fn, args):
+    """The kernel compiles for the chip and takes the queue axis by
+    one-hot selects: its compiled text holds no gather."""
+    text = _compile(fn, *args).as_text()
+    assert text.count("gather(") == 0
+
+
+def test_fixed_sweep_kernel_compiles(capture_kernel, on_chip):
     from repro.runtime import batched
 
-    seen = _capture(monkeypatch, batched, "_compiled_sweep",
-                    batched._build_sweep)
     grid, cfg, slot_us = _lattice()
-    with pytest.raises(_Captured):
-        simulate_batch(grid, cfg, slot_us=slot_us)
-    _compile(seen["fn"], *on_chip(seen["args"]))
+    fn, args = capture_kernel(
+        batched, "_compiled_sweep", batched._build_sweep,
+        lambda: simulate_batch(grid, cfg, slot_us=slot_us))
+    _compile_gather_free(fn, on_chip(args))
 
 
-def test_adaptive_sweep_kernel_compiles(monkeypatch, on_chip):
+def test_adaptive_sweep_kernel_compiles(capture_kernel, on_chip):
     from repro.runtime import batched_adaptive
 
-    seen = _capture(monkeypatch, batched_adaptive,
-                    "_compiled_adaptive_sweep",
-                    batched_adaptive._build_adaptive_sweep)
     grid, cfg, slot_us = _lattice()
-    with pytest.raises(_Captured):
-        simulate_batch(grid, cfg, slot_us=slot_us, stepping="adaptive")
-    _compile(seen["fn"], *on_chip(seen["args"]))
+    fn, args = capture_kernel(
+        batched_adaptive, "_compiled_adaptive_sweep",
+        batched_adaptive._build_adaptive_sweep,
+        lambda: simulate_batch(grid, cfg, slot_us=slot_us,
+                               stepping="adaptive"))
+    _compile_gather_free(fn, on_chip(args))
 
 
-def test_fleet_kernel_compiles_1000_hosts(monkeypatch, on_chip):
+def test_fleet_kernel_compiles_1000_hosts(capture_kernel, on_chip):
     from benchmarks.fleet import scale_sweep
     from repro.runtime import fleet
 
-    seen = _capture(monkeypatch, fleet, "_compiled_fleet_sweep",
-                    fleet._build_fleet_sweep)
     fgrid, cfg, slot_us = scale_sweep(quick=False)
     assert isinstance(fgrid, FleetGrid)
     assert fgrid.fleet == FleetConfig(n_hosts=1000) and len(fgrid) == 8
-    with pytest.raises(_Captured):
-        simulate_fleet(fgrid, cfg, slot_us=slot_us, shard=False)
-    _compile(seen["fn"], *on_chip(seen["args"]))
+    fn, args = capture_kernel(
+        fleet, "_compiled_fleet_sweep", fleet._build_fleet_sweep,
+        lambda: simulate_fleet(fgrid, cfg, slot_us=slot_us, shard=False))
+    _compile_gather_free(fn, on_chip(args))
 
 
 @pytest.fixture(scope="module")

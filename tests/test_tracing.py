@@ -12,8 +12,6 @@ import pytest
 from repro.runtime import (
     SimRunConfig,
     SweepGrid,
-    batched,
-    batched_adaptive,
     build_operating_table,
     simulate_batch,
     tracing,
@@ -21,11 +19,6 @@ from repro.runtime import (
 
 STAGES = ("rng", "stall", "arrivals", "overshoot", "wake_claim", "drain",
           "release", "accumulate", "gate")
-# every stage of the fixed kernel has operations only when the sleep
-# model has a tail and the host has interference and stalls
-NOISY = SimRunConfig(duration_us=100.0, interference_prob=0.1,
-                     interference_mean_us=5.0, stall_rate_per_us=1e-3,
-                     stall_mean_us=50.0)
 
 
 def _children(rec):
@@ -154,53 +147,6 @@ def test_profiler_trace_holds_the_program_spans(tmp_path):
             "repro.batched.fetch"} <= names
 
 
-class _Captured(Exception):
-    pass
-
-
-def _captured_kernel(monkeypatch, module, attr, build, run):
-    """The jitted kernel and the inputs an entry point hands it."""
-    seen = {}
-
-    def cache(*static):
-        fn = build(*static)
-
-        def call(*args):
-            seen["fn"], seen["args"] = fn, args
-            raise _Captured
-        return call
-
-    monkeypatch.setattr(module, attr, cache)
-    with pytest.raises(_Captured):
-        run()
-    return seen["fn"], seen["args"]
-
-
-def _kernel(monkeypatch, kind):
-    """The jitted kernel of ``kind`` and its inputs, at a tiny shape."""
-    from repro.runtime import fleet
-    from repro.runtime.simcore import FleetConfig
-
-    grid = SweepGrid.product(t_s_us=[10.0], t_l_us=[100.0], m=[2],
-                             rate_mpps=[3.0])
-    if kind == "fleet":
-        fgrid = fleet.FleetGrid.product(
-            fleet=FleetConfig(n_hosts=2, near_cost_us=1.0), t_s_us=[10.0],
-            t_l_us=[100.0], rate_mpps=[3.0], hedge_deadline_us=[20.0])
-        return _captured_kernel(
-            monkeypatch, fleet, "_compiled_fleet_sweep",
-            fleet._build_fleet_sweep,
-            lambda: fleet.simulate_fleet(fgrid, NOISY, shard=False))
-    if kind == "adaptive":
-        return _captured_kernel(
-            monkeypatch, batched_adaptive, "_compiled_adaptive_sweep",
-            batched_adaptive._build_adaptive_sweep,
-            lambda: simulate_batch(grid, NOISY, stepping="adaptive"))
-    return _captured_kernel(
-        monkeypatch, batched, "_compiled_sweep", batched._build_sweep,
-        lambda: simulate_batch(grid, NOISY))
-
-
 def _program_text(compiled_text: str) -> str:
     """A compiled module's computations without their metadata: the
     stack-frame tables before the first computation and each
@@ -217,13 +163,13 @@ def _program_text(compiled_text: str) -> str:
                   body)
 
 
-def test_stage_names_and_module_names(monkeypatch):
+def test_stage_names_and_module_names(scan_kernel):
     stages = {"fixed": STAGES,
               "adaptive": STAGES + ("jump",),
               "fleet": STAGES + ("balancer", "topology", "hedge")}
     modules = set()
     for kind in stages:
-        fn, args = _kernel(monkeypatch, kind)
+        fn, args = scan_kernel(kind)
         text = fn.lower(*args).as_text(debug_info=True)
         modules.add(re.search(r"module @(\w+)", text).group(1))
         for stage in stages[kind]:
@@ -234,13 +180,13 @@ def test_stage_names_and_module_names(monkeypatch):
                        "jit_fleet_sweep"}
 
 
-def test_named_scopes_are_metadata_only(monkeypatch):
-    fn, args = _kernel(monkeypatch, "fixed")
+def test_named_scopes_are_metadata_only(monkeypatch, scan_kernel):
+    fn, args = scan_kernel("fixed")
     scoped = fn.lower(*args).compile().as_text()
     assert "/wake_claim/" in scoped
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    fn, args = _kernel(monkeypatch, "fixed")
+    fn, args = scan_kernel("fixed")
     plain = fn.lower(*args).compile().as_text()
     assert "/wake_claim/" not in plain
     assert _program_text(scoped) == _program_text(plain)
