@@ -16,10 +16,11 @@ The mechanism is the interesting part: a single Metronome host has a
 stalls are independent across replicas, so "duplicate after D; first
 completion wins" collapses the stall tail (both replicas must stall)
 while the busy-poll fleet pays H full cores and still eats the
-co-runner stalls.  The busy-poll comparator's p99.9 comes from the
-same two-component tail model (``hedged_latency_quantile`` at D=0)
-applied to its event-engine spin-model mean, so both sides' tails are
-scored by one formula.
+co-runner stalls.  The Metronome fleet's p99.9 is measured in the fleet
+kernel (``FleetStats.quantile``); the busy-poll comparator, which the
+kernel does not run, is scored by the two-component tail model
+(``hedged_latency_quantile`` at D=0) on its event-engine spin-model
+mean.
 
 Rows (suite convention: ``name,value,derived``):
   - ``fleet/H<H>/<lb>/D<D>``  one fleet operating point: value = total

@@ -21,20 +21,31 @@ runs the single-host slot dynamics under a second ``vmap``
      ``topo_area`` — it is real end-to-end latency but NOT host queue
      depth, so host-level parity vs the single-host engines is
      untouched.
-  3. **Hedged requests.**  A per-point hedge deadline D duplicates
-     requests that are predicted to miss it: each slot, the fraction
-     ``sigmoid((backlog/mu - D) / (D/4))`` of a host's admitted packets
-     is re-injected into the currently least-loaded *other* host — a
-     smooth fluid stand-in for "duplicate to a second replica after D;
-     first completion wins".  Duplicates burn real CPU on the partner
-     (cancellation is not modeled in-scan, so fleet CPU is a
-     conservative upper bound) and are counted in ``hedge_dup``, not in
-     ``offered``.  The *tail benefit* of hedging — both replicas must
-     stall for a request to stay slow — is evaluated post-scan by the
-     closed-form model ``repro.runtime.stats.hedged_latency_quantile``
-     on the per-host measured means, and pinned against the exact
-     first-completion-wins reference (``repro.runtime.sim.
-     fleet_tail_reference``) in tests.
+  3. **Hedged requests.**  A per-point hedge deadline D duplicates each
+     request still queued at age D, and only those, once: a duplicate
+     is never hedged again.  The partner is a random other replica, so
+     in the fluid kernel host ``h`` receives ``(sum(dup) - dup_h) /
+     (H - 1)`` each slot, spread over its queues.  A duplicate that
+     finds the partner's ring full is dropped and counted in
+     ``hedge_dup_dropped``; one admitted is served in full (there is no
+     cancellation, so fleet CPU counts the duplicates' work).  Issued
+     duplicates are ``hedge_dup``, not ``offered``.
+
+**Sojourn accounting.**  The fixed kernel measures each host's packet
+sojourns.  Per (host, queue) it keeps a ring of ``RING`` samples of the
+cumulative admissions (all of them, and the balancer's originals apart),
+one at the end of every cohort of ``cohort_slots`` slots (``COHORT_US``
+of simulated time, a horizon of ``RING * COHORT_US``).  At each cohort's
+end the ring gives the admission time of the rank just served, by a
+compare-and-count over the ring with linear interpolation inside a
+cohort (FIFO: a packet leaves at the rank it arrived at), and the
+cohort's served mass goes into a per-host log-spaced histogram of
+sojourns (``sojourn_edges``), whose last bin counts what the horizon
+cannot see.  The same ring gives, each slot, the mass of packets that
+turn D old while still queued: the hedge trigger.  ``FleetStats.
+quantile`` combines the measured per-host survivals by first completion
+wins; nothing in it is assumed.  With D <= 0 every other counter is
+bit-identical to a kernel without hedging.
 
 **Per-host parity contract.**  Host ``h`` of a fleet row with seed
 ``s`` draws exactly the PRNG stream of a single-host batched run seeded
@@ -58,6 +69,14 @@ through one ``CompileCache``d jit per static shape — a 1000-host x
 Cluster rollups go through the existing ``RunStats`` machinery:
 ``FleetStats.host_run_stats(i)`` yields one ``RunStats`` per host and
 ``to_run_stats(i)`` n-way-merges them (``RunStats.merge_all``).
+
+Spans (``repro.runtime.tracing``): ``fleet.sweep`` around
+``simulate_fleet`` (counts ``points``, ``hosts``, ``scan_steps``,
+``hedge_dup``, ``hedge_dup_dropped``, ``hist_overflow``, ``built``),
+with children ``fleet.scan`` (the jitted call until its outputs are
+ready) and ``fleet.fetch`` (copies and ``FleetStats``); ``fleet.tail``
+around ``FleetStats.quantiles`` and ``fleet.rollup`` around
+``FleetStats.rollups``.
 """
 
 from __future__ import annotations
@@ -85,12 +104,34 @@ from .batched_adaptive import (
     _WAKE_EPS_US,
     estimate_adaptive_steps,
 )
+from . import tracing
 from .simcore import _LINK_UTIL_CLAMP, FleetConfig, SimRunConfig
-from .stats import Reservoir, RunStats, hedged_latency_quantile
+from .stats import Reservoir, RunStats
 
-__all__ = ["FleetGrid", "FleetStats", "simulate_fleet"]
+__all__ = ["FleetGrid", "FleetStats", "simulate_fleet", "sojourn_edges"]
 
 _LB_CODE = {"uniform": 0, "weighted": 1, "least-loaded": 2}
+
+# sojourn accounting: cohorts of about COHORT_US, a ring of RING cohort
+# samples (the horizon), a histogram of HIST_BINS log-spaced bins from
+# HIST_LO_US to the horizon, with an underflow and an overflow bin
+COHORT_US = 4.0
+RING = 384
+HIST_BINS = 128
+HIST_LO_US = 0.5
+
+
+def cohort_slots(slot_us: float) -> int:
+    """Slots per cohort of the sojourn ring at this slot width."""
+    return max(int(round(COHORT_US / slot_us)), 1)
+
+
+def sojourn_edges(slot_us: float) -> np.ndarray:
+    """The ``HIST_BINS + 1`` inner edges (us) of the sojourn histogram:
+    log-spaced from ``HIST_LO_US`` to the ring's horizon.  Bin 0 is
+    [0, edges[0]) and the last bin [edges[-1], inf), the overflow."""
+    horizon = RING * cohort_slots(slot_us) * slot_us
+    return np.geomspace(HIST_LO_US, horizon, HIST_BINS + 1)
 
 
 @dataclass(frozen=True)
@@ -180,6 +221,21 @@ class _FleetSlotStats(NamedTuple):
     energy_uj: jnp.ndarray     # EnergyModel charge (active + arms)
     topo_area: jnp.ndarray     # network delay integral (packet*us)
     hedge_dup: jnp.ndarray     # duplicate requests issued by this host
+    hedge_dup_in: jnp.ndarray  # duplicates admitted into this host's ring
+    hedge_dup_dropped: jnp.ndarray  # duplicates its full ring dropped
+
+
+class _Cohort(NamedTuple):
+    """Per (host, queue) counts since the last cohort's close (admissions
+    relative to the ranks served by then) and the open cohort's served
+    mass and first/last serving slot ends (us)."""
+
+    cum_all: jnp.ndarray       # admissions, duplicates included
+    cum_orig: jnp.ndarray      # admissions from the balancer
+    cum_served: jnp.ndarray
+    served: jnp.ndarray
+    first_us: jnp.ndarray
+    last_us: jnp.ndarray
 
 
 def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
@@ -213,8 +269,13 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
     ``lb_stale_us`` grid) — and the per-host body applies the
     closed-form multi-slot aggregates of ``batched_adaptive``.  The
     cross-host stages (LB split, bottleneck-link M/M/1 wait at the
-    macro-slot's admission rate, fluid hedge duplication) consume the
-    same ``dt``.
+    macro-slot's admission rate) consume the same ``dt``.  The adaptive
+    build keeps no sojourn ring: it neither hedges nor measures a tail.
+
+    The fixed kernel scans cohorts of ``cohort_slots(slot_us)`` slots:
+    each scan step runs that many slots one after another, then closes
+    the cohort (ring sample, served-mass histogram); see the module
+    docstring.
     """
     base_us, slope, sigma_us, tail_prob, tail_mean_us = sleep_params
     intf_prob, intf_mean_us, stall_rate, stall_mean_us = interference_params
@@ -230,6 +291,10 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                or link_rate_mpps > 0.0)
     w_static = (jnp.asarray(lb_weights, jnp.float32) if lb_code == 1
                 else jnp.full((n_hosts,), 1.0 / n_hosts, jnp.float32))
+    g = cohort_slots(slot_us)
+    cohort_us = g * dt
+    k_idx = jnp.arange(RING)
+    edges = jnp.asarray(sojourn_edges(slot_us), jnp.float32)
 
     def one_fleet(t_s, t_l, m, nq, lam, seed_lo, seed_hi, hedge_d,
                   duration, sched_edges, sched_scales):
@@ -384,14 +449,18 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                                + ts_arm * e_arm_s + busy_tries * e_arm_l)
                 out = (offered, dropped, served, n_wake, busy_tries, cycles,
                        vac_sum, nv_sum, adm.sum(), lat_area, ts_arm,
-                       energy_step)
+                       energy_step, adm, serve)
             return (sleep_rem, attached, backlog, vac_timer, arr_res,
                     stall_end), out
 
-        def fleet_step(carry, t):
+        def fleet_step(carry, t, aged_prev, aged_now, orig_share):
+            """One slot of the fleet.  ``aged_prev``/``aged_now``: per
+            (host, queue), how many admissions are at least D old at the
+            end of the previous slot and of this one; ``orig_share``: the
+            balancer's share of those that turn D old in this slot."""
             prev = carry
             (f_sleep, f_att, f_back, f_vac, f_res, f_stall, stale_b,
-             S) = carry
+             S, C) = carry
             now = t.astype(jnp.float32) * dt
             live = now < duration
             with jax.named_scope("balancer"):
@@ -420,9 +489,8 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
               f_res, f_stall)
             (f_sleep, f_att, f_back, f_vac, f_res, f_stall) = new_carry
             (offered_h, dropped_h, served_h, n_wake_h, busy_h, cycles_h,
-             vac_h, nv_h, adm_h, lat_area_h, ts_arm_h,
-             energy_h) = outs
-            back_tot = f_back.sum(axis=1)              # (H,) packets
+             vac_h, nv_h, adm_h, lat_area_h, ts_arm_h, energy_h, adm_hq,
+             serve_hq) = outs
 
             # 2. topology: admitted packets pay rack cost; far packets
             # also queue on the shared bottleneck link (M/M/1-style
@@ -440,24 +508,38 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                 else:
                     topo_area_h = jnp.zeros((n_hosts,))
 
-            # 3. hedging (fluid): the share of this slot's admissions
-            # predicted to miss the deadline (drain-time proxy
-            # backlog/mu vs D, smooth sigmoid gate) is duplicated onto
-            # the least-loaded OTHER host.  hedge_d <= 0 disables and
-            # leaves the backlog bit-identical.
+            # 3. hedging: the balancer's packets that turn D old in this
+            # slot while still queued (FIFO: ranks above those served)
+            # are duplicated, each to a random other replica; in the
+            # fluid kernel every other host takes 1/(H-1) of each host's
+            # duplicates.  orig_share is 0 unless D > 0, so the backlog
+            # then stays bit-identical.
             with jax.named_scope("hedge"):
-                hedge_on = (hedge_d > 0.0).astype(jnp.float32)
-                drain_us = back_tot / mu
-                gate = jax.nn.sigmoid((drain_us - hedge_d)
-                                      / (0.25 * hedge_d + 1e-6))
-                dup_h = adm_h * gate * hedge_on            # (H,) duplicates
-                b1 = jnp.argmin(back_tot)
-                b2 = jnp.argmin(jnp.where(h_idx == b1, jnp.inf, back_tot))
-                partner = jnp.where(h_idx == b1, b2, b1)   # (H,)
-                dup_per_q = dup_h[:, None] * (qmask / nq)  # (H, q_max)
-                inject = jnp.zeros((n_hosts, q_max)).at[partner].add(dup_per_q)
+                cum_served = C.cum_served + serve_hq
+                late = jnp.maximum(
+                    aged_now - jnp.maximum(aged_prev, cum_served), 0.0)
+                dup_h = (late * orig_share).sum(axis=1)    # (H,) issued
+                if n_hosts > 1:
+                    inject = (dup_h.sum() - dup_h) / (n_hosts - 1)
+                else:
+                    inject = jnp.zeros((n_hosts,))
+                inject = inject[:, None] * (qmask / nq)    # (H, q_max)
                 inj_room = jnp.maximum(capacity - f_back, 0.0)
-                f_back = f_back + jnp.minimum(inject, inj_room)
+                dup_in = jnp.minimum(inject, inj_room)
+                f_back = f_back + dup_in
+
+            with jax.named_scope("sojourn"):
+                slot_end = (t + 1).astype(jnp.float32) * dt
+                served_q = serve_hq > 0.0
+                C = _Cohort(
+                    cum_all=C.cum_all + adm_hq + dup_in,
+                    cum_orig=C.cum_orig + adm_hq,
+                    cum_served=cum_served,
+                    served=C.served + serve_hq,
+                    first_us=jnp.where(served_q,
+                                       jnp.minimum(C.first_us, slot_end),
+                                       C.first_us),
+                    last_us=jnp.where(served_q, slot_end, C.last_us))
 
             with jax.named_scope("accumulate"):
                 S = _FleetSlotStats(
@@ -476,33 +558,123 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                     energy_uj=S.energy_uj + energy_h,
                     topo_area=S.topo_area + topo_area_h,
                     hedge_dup=S.hedge_dup + dup_h,
+                    hedge_dup_in=S.hedge_dup_in + dup_in.sum(axis=1),
+                    hedge_dup_dropped=S.hedge_dup_dropped
+                    + (inject - dup_in).sum(axis=1),
                 )
             with jax.named_scope("gate"):
                 nxt = (f_sleep, f_att, f_back, f_vac, f_res, f_stall,
-                       stale_b, S)
+                       stale_b, S, C)
                 # steps past this point's duration hold the carry — the
                 # bucketed scan length pads with no-ops, live steps stay
                 # bit-identical to the unpadded scan
                 gated = jax.tree_util.tree_map(
                     lambda new, old: jnp.where(live, new, old), nxt, prev)
-            return gated, None
+            return gated
+
+        def cohort_step(carry, b):
+            """Cohort ``b``: its ``g`` slots, then its close.  At the
+            start, the ring gives the admissions at least D old at the
+            cohort's two ends, interpolated linearly in between; at the
+            close, the ring takes the cohort's sample and gives the
+            admission times of the first and the last rank served, and
+            the cohort's served mass goes into the histogram, its
+            sojourns spread evenly between those two ranks'."""
+            slots, ring_all, ring_orig, hist = carry
+            with jax.named_scope("hedge"):
+                # age of ring entry k, in cohorts: 0 = newest (cohort b-1)
+                age = ((b - 1 - k_idx) % RING).astype(jnp.float32)
+                lag = jnp.maximum(hedge_d, cohort_us) / cohort_us
+                w_lo = jnp.maximum(1.0 - jnp.abs(age - lag), 0.0)
+                w_d = jnp.maximum(1.0 - jnp.abs(age - lag + 1.0), 0.0) - w_lo
+                aged_lo = (ring_all * w_lo).sum(axis=-1)   # (H, q_max)
+                d_all = (ring_all * w_d).sum(axis=-1)
+                d_orig = (ring_orig * w_d).sum(axis=-1)
+                share = jnp.where(d_all > 1e-6,
+                                  d_orig / jnp.maximum(d_all, 1e-6), 0.0)
+                share = share * (hedge_d > 0.0)
+
+            def slot(carry, j):
+                k = j.astype(jnp.float32)
+                return fleet_step(carry, b * g + j,
+                                  aged_lo + (k / g) * d_all,
+                                  aged_lo + ((k + 1.0) / g) * d_all,
+                                  share), None
+
+            slots, _ = jax.lax.scan(slot, slots,
+                                    jnp.arange(g, dtype=jnp.int32))
+            C = slots[-1]
+            with jax.named_scope("sojourn"):
+                hot = k_idx == b % RING
+                ring_all = jnp.where(hot, C.cum_all[..., None], ring_all)
+                ring_orig = jnp.where(hot, C.cum_orig[..., None], ring_orig)
+                # when each served rank was admitted: the ring's samples
+                # above it are the newest, the newest one not above it
+                # and the oldest above it bracket its admission (FIFO)
+                t_close = (b + 1).astype(jnp.float32) * cohort_us
+                rank = jnp.stack([jnp.zeros_like(C.cum_served),
+                                  C.cum_served], axis=-1)[..., None]
+                above = ring_all[..., None, :] > rank     # (H, q, 2, RING)
+                n_above = above.sum(axis=-1).astype(jnp.float32)
+                a_hi = jnp.where(above, ring_all[..., None, :],
+                                 jnp.inf).min(axis=-1)
+                seen = n_above < RING
+                a_lo = jnp.where(seen, jnp.where(
+                    above, -jnp.inf, ring_all[..., None, :]).max(axis=-1),
+                    0.0)
+                t_adm = jnp.where(
+                    seen,
+                    t_close - n_above * cohort_us
+                    + cohort_us * (rank[..., 0] - a_lo)
+                    / jnp.maximum(a_hi - a_lo, 1e-6),
+                    t_close - (RING + 1) * cohort_us)
+                s_first = C.first_us - t_adm[..., 0]
+                s_last = C.last_us - t_adm[..., 1]
+                has = C.served > 0.0
+                lo = jnp.where(has, jnp.maximum(
+                    jnp.minimum(s_first, s_last), 0.0), 0.0)
+                hi = jnp.where(has, jnp.maximum(s_first, s_last), 0.0)
+                cdf = jnp.clip((edges - lo[..., None])
+                               / jnp.maximum(hi - lo, 1e-6)[..., None],
+                               0.0, 1.0)
+                ones = jnp.ones(lo.shape + (1,))
+                frac = (jnp.concatenate([cdf, ones], axis=-1)
+                        - jnp.concatenate([0.0 * ones, cdf], axis=-1))
+                hist = hist + (jnp.where(has, C.served, 0.0)[..., None]
+                               * frac).sum(axis=1)
+                # rebase the counts on the ranks served: what the lookups
+                # compare stays near zero, where float32 is exact enough
+                ring_all = ring_all - C.cum_served[..., None]
+                ring_orig = ring_orig - C.cum_orig[..., None]
+                C = _Cohort(cum_all=C.cum_all - C.cum_served,
+                            cum_orig=jnp.zeros_like(C.cum_orig),
+                            cum_served=jnp.zeros_like(C.cum_served),
+                            served=jnp.zeros_like(C.served),
+                            first_us=jnp.full_like(C.first_us, jnp.inf),
+                            last_us=jnp.full_like(C.last_us, -jnp.inf))
+            return (slots[:-1] + (C,), ring_all, ring_orig, hist), None
 
         zh = jnp.zeros((n_hosts,), jnp.float32)
+        zq = jnp.zeros((n_hosts, q_max), jnp.float32)
         if stepping == "fixed":
             init = (sleep0_h,
                     jnp.full((n_hosts, m_max), -1, jnp.int32),
-                    jnp.zeros((n_hosts, q_max), jnp.float32),
-                    jnp.zeros((n_hosts, q_max), jnp.float32),
-                    jnp.zeros((n_hosts, q_max), jnp.float32),
+                    zq, zq, zq,
                     jnp.full((n_hosts,), -1.0, jnp.float32),
                     zh,                          # stale LB snapshot
-                    _FleetSlotStats(zh, zh, zh, zh, zh, zh, zh, zh, zh,
-                                    zh, zh, zh, zh, zh))
-            (*_, S), _ = jax.lax.scan(
-                fleet_step, init, jnp.arange(n_slots, dtype=jnp.int32))
+                    _FleetSlotStats(*([zh] * len(_FleetSlotStats._fields))),
+                    _Cohort(zq, zq, zq, zq, jnp.full_like(zq, jnp.inf),
+                            jnp.full_like(zq, -jnp.inf)))
+            ring0 = jnp.zeros((n_hosts, q_max, RING), jnp.float32)
+            hist0 = jnp.zeros((n_hosts, HIST_BINS + 2), jnp.float32)
+            n_cohorts = -(-n_slots // g)
+            (slots, _, _, hist), _ = jax.lax.scan(
+                cohort_step, (init, ring0, ring0, hist0),
+                jnp.arange(n_cohorts, dtype=jnp.int32))
             n_live = jnp.minimum(jnp.ceil(duration / dt),
-                                 jnp.float32(n_slots))
-            return S, n_live * dt, n_live, jnp.zeros_like(duration)
+                                 jnp.float32(n_cohorts * g))
+            return ((slots[-2], hist), n_live * dt, n_live,
+                    jnp.zeros_like(duration))
 
         # ---- adaptive (event-jump): one shared variable dt per point —
         # the per-host boundary structure reduced with a fleet-wide min,
@@ -750,7 +922,6 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
             (offered_h, dropped_h, served_h, n_wake_h, busy_h, cycles_h,
              vac_h, nv_h, adm_h, lat_area_h, ts_arm_h,
              energy_h) = outs
-            back_tot = a_back.sum(axis=1)
 
             # topology — the macro-slot's admissions pay rack + link
             # cost at the slot's average far-rack arrival rate
@@ -766,22 +937,6 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                     topo_area_h = adm_h * topo_delay_us
                 else:
                     topo_area_h = jnp.zeros((n_hosts,))
-
-            # hedging (fluid) — per macro-slot, same gate as fixed
-            with jax.named_scope("hedge"):
-                hedge_on = (hedge_d > 0.0).astype(jnp.float32)
-                drain_us = back_tot / mu
-                gate = jax.nn.sigmoid((drain_us - hedge_d)
-                                      / (0.25 * hedge_d + 1e-6))
-                dup_h = adm_h * gate * hedge_on
-                b1 = jnp.argmin(back_tot)
-                b2 = jnp.argmin(jnp.where(h_idx == b1, jnp.inf, back_tot))
-                partner = jnp.where(h_idx == b1, b2, b1)
-                dup_per_q = dup_h[:, None] * (qmask / nq)
-                inject = jnp.zeros((n_hosts, q_max)).at[partner].add(
-                    dup_per_q)
-                inj_room = jnp.maximum(capacity - a_back, 0.0)
-                a_back = a_back + jnp.minimum(inject, inj_room)
 
             with jax.named_scope("accumulate"):
                 SA = _FleetSlotStats(
@@ -799,7 +954,9 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                     ts_arms=SA.ts_arms + ts_arm_h,
                     energy_uj=SA.energy_uj + energy_h,
                     topo_area=SA.topo_area + topo_area_h,
-                    hedge_dup=SA.hedge_dup + dup_h,
+                    hedge_dup=SA.hedge_dup,
+                    hedge_dup_in=SA.hedge_dup_in,
+                    hedge_dup_dropped=SA.hedge_dup_dropped,
                 )
                 rem_t = rem_t - dtv
                 nst = nst + 1.0
@@ -823,11 +980,11 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                   z0,                        # next_ref: refresh at t=0
                   jnp.asarray(duration, jnp.float32),
                   z0, z0,                    # n_steps, forced_steps
-                  _FleetSlotStats(zh, zh, zh, zh, zh, zh, zh, zh, zh,
-                                  zh, zh, zh, zh, zh))
+                  _FleetSlotStats(*([zh] * len(_FleetSlotStats._fields))))
         (*_, rem_f, nst, fst, SA), _ = jax.lax.scan(
             fleet_step_a, init_a, jnp.arange(n_slots, dtype=jnp.int32))
-        return SA, duration - rem_f, nst, fst
+        return ((SA, jnp.zeros((n_hosts, 0), jnp.float32)),
+                duration - rem_f, nst, fst)
 
     inner = jax.vmap(one_fleet)
     if n_shards > 1:
@@ -849,15 +1006,59 @@ _compiled_fleet_sweep = CompileCache(_build_fleet_sweep, maxsize=64,
                                      name="fleet._compiled_fleet_sweep")
 
 
+class _Survival:
+    """Measured per-host survival P(sojourn > x) from the kernel's
+    histograms ``hist`` (H, HIST_BINS + 2) with inner ``edges``: linear
+    inside a bin (the kernel spreads each cohort's mass evenly in x),
+    flat in the overflow bin.  ``shift_us`` (H,) delays each host's
+    curve by its network delay."""
+
+    def __init__(self, hist: np.ndarray, edges: np.ndarray,
+                 shift_us: np.ndarray):
+        tot = np.maximum(hist.sum(axis=1, keepdims=True), 1e-30)
+        self.mass = hist / tot
+        # survival at each bin's lower bound: 0, edges[0], ..., edges[-1]
+        self.tail = np.cumsum(self.mass[:, ::-1], axis=1)[:, ::-1]
+        self.bound = np.concatenate([[0.0], edges])
+        self.shift = shift_us
+        self.rows = np.arange(hist.shape[0])
+
+    def __call__(self, x: float) -> np.ndarray:
+        """(H,) survival of every host at ``x`` us."""
+        xs = np.maximum(x - self.shift, 0.0)
+        b = np.searchsorted(self.bound, xs, side="right") - 1
+        inner = b < self.bound.size - 1
+        bb = np.minimum(b, self.bound.size - 2)
+        width = self.bound[bb + 1] - self.bound[bb]
+        frac = np.where(inner, np.clip((xs - self.bound[bb]) / width,
+                                       0.0, 1.0), 0.0)
+        return self.tail[self.rows, b] - frac * self.mass[self.rows, bb]
+
+    def host_quantiles(self, q: float) -> np.ndarray:
+        """(H,) each host's own ``q`` quantile (inf past the horizon)."""
+        p = 1.0 - q
+        b = (self.tail > p).sum(axis=1) - 1
+        inner = b < self.bound.size - 1
+        bb = np.minimum(b, self.bound.size - 2)
+        m = np.maximum(self.mass[self.rows, bb], 1e-30)
+        x = self.bound[bb] + np.clip((self.tail[self.rows, bb] - p) / m,
+                                     0.0, 1.0) * (self.bound[bb + 1]
+                                                  - self.bound[bb])
+        return np.where(inner, x + self.shift, np.inf)
+
+
 @dataclass
 class FleetStats:
     """Per-(point, host) results of one fleet sweep.
 
-    All arrays are float64 of shape ``(len(fgrid), n_hosts)``.  Fleet-
-    level metrics reduce over the host axis; tail quantiles come from
-    the hedged-tail closed form on the per-host measured means (the
-    slot engine keeps no samples).  ``reshaped(name)`` appends the host
-    axis to the grid's logical shape.
+    All counter arrays are float64 of shape ``(len(fgrid), n_hosts)``;
+    ``hist`` is ``(len(fgrid), n_hosts, HIST_BINS + 2)``: each host's
+    served packets by sojourn, bins ``sojourn_edges(slot_us)`` (empty in
+    the adaptive build, which measures no tail).  Fleet-level metrics
+    reduce over the host axis; tail quantiles combine the measured
+    per-host survivals by first completion wins (``quantile``).
+    ``reshaped(name)`` appends the host axis to the grid's logical
+    shape.
     """
 
     fgrid: FleetGrid
@@ -878,6 +1079,10 @@ class FleetStats:
     energy_uj: np.ndarray = field(default_factory=lambda: np.empty(0))
     topo_area: np.ndarray = field(default_factory=lambda: np.empty(0))
     hedge_dup: np.ndarray = field(default_factory=lambda: np.empty(0))
+    hedge_dup_in: np.ndarray = field(default_factory=lambda: np.empty(0))
+    hedge_dup_dropped: np.ndarray = field(
+        default_factory=lambda: np.empty(0))
+    hist: np.ndarray = field(default_factory=lambda: np.empty(0))
     # stepping diagnostics (see BatchStats): which kernel ran, its
     # compiled scan length, and per-POINT live/forced step counts and
     # exact simulated time (host axis shares one dt, so these are (P,))
@@ -901,9 +1106,10 @@ class FleetStats:
 
     @property
     def host_weight(self) -> np.ndarray:
-        """(P, H) served-traffic share per host (tail-mixture weights)."""
-        tot = np.maximum(self.serviced.sum(axis=1, keepdims=True), 1.0)
-        return self.serviced / tot
+        """(P, H) share of the balancer's requests each host admitted
+        (duplicates excluded): the weights of the fleet's mixture."""
+        adm = np.maximum(self.offered - self.dropped, 0.0)
+        return adm / np.maximum(adm.sum(axis=1, keepdims=True), 1.0)
 
     @property
     def host_cpu_fraction(self) -> np.ndarray:
@@ -948,10 +1154,14 @@ class FleetStats:
 
     @property
     def offered_with_hedges(self) -> np.ndarray:
-        """(P,) offered load including hedge duplicates — strictly
-        increasing as the hedge deadline tightens (the cost side of the
-        hedging sanity test)."""
+        """(P,) offered load including the duplicates issued."""
         return (self.offered + self.hedge_dup).sum(axis=1)
+
+    @property
+    def hist_overflow(self) -> np.ndarray:
+        """(P,) served packets whose sojourn the ring's horizon could not
+        see (the histogram's last bin)."""
+        return self.hist[..., -1:].sum(axis=(1, 2))
 
     @property
     def rho(self) -> np.ndarray:
@@ -959,22 +1169,66 @@ class FleetStats:
         return (self.fgrid.grid.rate_mpps
                 / (self.cfg.service_rate_mpps * self.n_hosts))
 
+    def survival(self, i: int) -> _Survival:
+        """Measured per-host survival of point ``i``, network delay
+        included."""
+        if self.hist.size == 0:
+            raise ValueError(
+                "this sweep measured no sojourns (the adaptive fleet "
+                "build keeps no ring); run stepping='fixed' for tails")
+        delay = self.topo_area[i] / np.maximum(self.serviced[i], 1.0)
+        return _Survival(self.hist[i], sojourn_edges(self.slot_us), delay)
+
     def quantile(self, i: int, q: float = 0.999) -> float:
-        """Fleet latency quantile of point ``i`` from the hedged-tail
-        closed form on the measured per-host means, with the config's
-        correlated-stall environment as the tail component."""
-        tail_prob = min(self.cfg.stall_rate_per_us
-                        * self.cfg.stall_mean_us, 0.5)
-        return hedged_latency_quantile(
-            q, self.host_mean_latency_us[i], self.host_weight[i],
-            hedge_deadline_us=float(self.fgrid.hedge_deadline_us[i]),
-            tail_prob=tail_prob,
-            tail_scale_us=self.cfg.stall_mean_us)
+        """Fleet latency quantile of point ``i`` from the measured
+        per-host survivals S_h, first completion wins.  A request lands
+        on host h with the share of the balancer's traffic h admitted
+        (w_h); one still queued at D is duplicated to a random other
+        replica j, which loses it with j's duplicate-drop share l_j:
+
+            S(x) = sum_h w_h S_h(x) P_-h(x - D)   for x > D,
+            P_j(y) = l_j + (1 - l_j) S_j(y),  P_-h = mean of P over j != h
+
+        and S(x) = sum_h w_h S_h(x) up to D (or with D <= 0).  Solved by
+        bisection; inf when the quantile lies past the ring's horizon."""
+        if not 0.0 < q < 1.0:
+            raise ValueError("q must be in (0, 1)")
+        surv = self.survival(i)
+        w = self.host_weight[i]
+        d = float(self.fgrid.hedge_deadline_us[i])
+        hedged = d > 0.0 and self.n_hosts > 1
+        got = self.hedge_dup_in[i] + self.hedge_dup_dropped[i]
+        lost = self.hedge_dup_dropped[i] / np.maximum(got, 1e-30)
+
+        def fleet_surv(x: float) -> float:
+            s = surv(x)
+            if hedged and x > d:
+                p = lost + (1.0 - lost) * surv(x - d)
+                s = s * (p.sum() - p) / (self.n_hosts - 1)
+            return float(w @ s)
+
+        target = 1.0 - q
+        hi = float(surv.bound[-1] + surv.shift.max()) + max(d, 0.0)
+        if fleet_surv(hi) > target:
+            return math.inf
+        lo = 0.0
+        for _ in range(60):
+            mid = 0.5 * (lo + hi)
+            if fleet_surv(mid) > target:
+                lo = mid
+            else:
+                hi = mid
+        return 0.5 * (lo + hi)
+
+    def quantiles(self, q: float = 0.999) -> np.ndarray:
+        """(P,) ``quantile(i, q)`` of every point (span ``fleet.tail``)."""
+        with tracing.span("fleet.tail", points=len(self)):
+            return np.asarray([self.quantile(i, q)
+                               for i in range(len(self))])
 
     @property
     def p999_latency_us(self) -> np.ndarray:
-        return np.asarray([self.quantile(i, 0.999)
-                           for i in range(len(self))])
+        return self.quantiles(0.999)
 
     def reshaped(self, name: str) -> np.ndarray:
         val = np.asarray(getattr(self, name))
@@ -984,51 +1238,77 @@ class FleetStats:
         return val.reshape(shape)
 
     # -- RunStats rollups ------------------------------------------------------
+    def _labels(self, i: int) -> tuple[dict, float, dict]:
+        """What every host's ``RunStats`` of point ``i`` shares: labels,
+        the worst-case latency, and the point."""
+        p = self.fgrid.point(i)
+        cap = self.cfg.queue_capacity * max(int(p["n_queues"]), 1)
+        return dict(
+            backend="fleet",
+            policy=(f"sleepwake(t_s={p['t_s_us']:g},"
+                    f"t_l={p['t_l_us']:g},m={p['m']})"),
+            workload=f"fleet-share({p['rate_mpps']:g}mpps/{self.n_hosts})",
+            started_ns=0, stopped_ns=round(self.cfg.duration_us * 1e3),
+        ), float(cap / self.cfg.service_rate_mpps + p["t_l_us"]), p
+
     def host_run_stats(self, i: int) -> list[RunStats]:
         """One ``RunStats`` per host for point ``i`` (host-level view;
-        latency override mean includes the host's network share)."""
-        p = self.fgrid.point(i)
-        out = []
-        for h in range(self.n_hosts):
-            mean = float(self.host_mean_latency_us[i, h])
-            cap = self.cfg.queue_capacity * max(int(p["n_queues"]), 1)
-            out.append(RunStats(
-                backend="fleet",
-                policy=(f"sleepwake(t_s={p['t_s_us']:g},"
-                        f"t_l={p['t_l_us']:g},m={p['m']})"),
-                workload=(f"fleet-share({p['rate_mpps']:g}mpps"
-                          f"/{self.n_hosts})"),
-                wakeups=int(self.wakeups[i, h]),
-                cycles=int(self.cycles[i, h]),
-                busy_tries=int(self.busy_tries[i, h]),
-                items=int(self.serviced[i, h]),
-                offered=int(self.offered[i, h]),
-                dropped=int(self.dropped[i, h]),
-                awake_ns=round(self.awake_us[i, h] * 1e3),
-                started_ns=0,
-                stopped_ns=round(self.cfg.duration_us * 1e3),
-                latency_us=Reservoir(4, seed=int(p["seed"]) + h),
-                latency_area_us=float(self.lat_area[i, h]
-                                      + self.topo_area[i, h]),
-                energy_uj=float(self.energy_uj[i, h]),
-                latency_override={
-                    "mean": mean,
-                    "p99": mean * 3.0,
-                    "worst": float(cap / self.cfg.service_rate_mpps
-                                   + p["t_l_us"]),
-                },
-            ))
-        return out
+        the mean includes the host's network share, the p99 is the
+        host's own measured one: NaN where the sweep measured none)."""
+        labels, worst, p = self._labels(i)
+        p99 = (self.survival(i).host_quantiles(0.99) if self.hist.size
+               else np.full(self.n_hosts, np.nan))
+        means = self.host_mean_latency_us[i]
+        return [RunStats(
+            **labels,
+            wakeups=int(self.wakeups[i, h]),
+            cycles=int(self.cycles[i, h]),
+            busy_tries=int(self.busy_tries[i, h]),
+            items=int(self.serviced[i, h]),
+            offered=int(self.offered[i, h]),
+            dropped=int(self.dropped[i, h]),
+            awake_ns=round(self.awake_us[i, h] * 1e3),
+            latency_us=Reservoir(4, seed=int(p["seed"]) + h),
+            latency_area_us=float(self.lat_area[i, h]
+                                  + self.topo_area[i, h]),
+            energy_uj=float(self.energy_uj[i, h]),
+            latency_override={"mean": float(means[h]), "p99": float(p99[h]),
+                              "worst": worst},
+        ) for h in range(self.n_hosts)]
 
     def to_run_stats(self, i: int) -> RunStats:
-        """Cluster rollup of point ``i``: n-way ``RunStats.merge_all``
-        over the per-host stats, with the fleet-level hedged-tail p99
-        replacing the per-host heuristic."""
-        hosts = self.host_run_stats(i)
-        head = hosts[0]
-        head.merge_all(hosts[1:])
-        head.latency_override["p99"] = self.quantile(i, 0.99)
-        return head
+        """Cluster rollup of point ``i``: what ``RunStats.merge_all`` over
+        ``host_run_stats(i)`` gives, summed from the arrays, with the
+        fleet's measured p99 (first completion wins) as its p99."""
+        labels, worst, p = self._labels(i)
+
+        def total(a):          # the hosts' int() counters, summed
+            return int(np.floor(a[i]).astype(np.int64).sum())
+
+        items = np.floor(self.serviced[i])
+        return RunStats(
+            **labels,
+            wakeups=total(self.wakeups), cycles=total(self.cycles),
+            busy_tries=total(self.busy_tries), items=total(self.serviced),
+            offered=total(self.offered), dropped=total(self.dropped),
+            awake_ns=int(np.round(self.awake_us[i] * 1e3)
+                         .astype(np.int64).sum()),
+            latency_us=Reservoir(4, seed=int(p["seed"])),
+            latency_area_us=float((self.lat_area[i]
+                                   + self.topo_area[i]).sum()),
+            energy_uj=float(self.energy_uj[i].sum()),
+            latency_override={
+                "mean": float(self.host_mean_latency_us[i] @ items
+                              / max(items.sum(), 1.0)),
+                "p99": self.quantile(i, 0.99),
+                "worst": worst,
+            },
+        )
+
+    def rollups(self) -> list[RunStats]:
+        """``to_run_stats`` of every point (span ``fleet.rollup``)."""
+        with tracing.span("fleet.rollup", points=len(self)):
+            return [self.to_run_stats(i) for i in range(len(self))]
 
     def __len__(self) -> int:
         return len(self.fgrid)
@@ -1060,6 +1340,14 @@ def simulate_fleet(fgrid: FleetGrid, cfg: SimRunConfig | None = None, *,
     cfg = cfg or SimRunConfig()
     validate_batched_config(cfg)
     fleet = fgrid.fleet.validate()
+    hedge = np.asarray(fgrid.hedge_deadline_us, dtype=np.float64)
+    if stepping == "adaptive" and np.any(hedge > 0.0):
+        raise ValueError("the adaptive fleet build does not hedge: it keeps "
+                         "no sojourn ring (use stepping='fixed')")
+    horizon = float(sojourn_edges(slot_us)[-1])
+    if np.any(hedge >= horizon):
+        raise ValueError(f"hedge deadlines must lie below the sojourn "
+                         f"ring's horizon of {horizon:g} us")
     n_pts = len(fgrid)
     m_max = int(fgrid.grid.m.max())
     q_max = int(fgrid.grid.n_queues.max())
@@ -1075,57 +1363,71 @@ def simulate_fleet(fgrid: FleetGrid, cfg: SimRunConfig | None = None, *,
         n_slots = bucket_steps(
             min(fleet.n_hosts * est + 64, n_slots_true))
     else:
-        n_slots = bucket_steps(n_slots_true)
+        g = cohort_slots(slot_us)
+        n_slots = -(-bucket_steps(n_slots_true) // g) * g
 
     n_dev = len(jax.devices())
     use_shard = (n_dev > 1) if shard is None else bool(shard)
     n_shards = max(min(n_dev, n_pts), 1) if use_shard else 1
 
-    sm = cfg.sleep_model
-    lb_weights = (tuple(float(w) for w in fleet.shares())
-                  if fleet.lb == "weighted" else ())
-    fn = _compiled_fleet_sweep(
-        n_slots, float(slot_us), m_max, q_max, int(fleet.n_hosts),
-        float(cfg.service_rate_mpps), float(cfg.queue_capacity),
-        float(cfg.wake_cost_us),
-        (float(sm.base_us), float(sm.slope), float(sm.sigma_us),
-         float(sm.tail_prob), float(sm.tail_mean_us)),
-        (float(cfg.interference_prob), float(cfg.interference_mean_us),
-         float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
-        cfg.energy_model.params(),
-        n_seg, _LB_CODE[fleet.lb], lb_weights,
-        float(fleet.lb_softness_pkts), stale_every_slots,
-        fleet.far_hosts(), float(fleet.near_cost_us),
-        float(fleet.far_cost_us), float(fleet.link_rate_mpps),
-        n_shards, stepping)
+    with tracing.span("fleet.sweep", points=n_pts, hosts=fleet.n_hosts,
+                      scan_steps=n_slots, built=0) as sp:
+        sm = cfg.sleep_model
+        lb_weights = (tuple(float(w) for w in fleet.shares())
+                      if fleet.lb == "weighted" else ())
+        fn = _compiled_fleet_sweep(
+            n_slots, float(slot_us), m_max, q_max, int(fleet.n_hosts),
+            float(cfg.service_rate_mpps), float(cfg.queue_capacity),
+            float(cfg.wake_cost_us),
+            (float(sm.base_us), float(sm.slope), float(sm.sigma_us),
+             float(sm.tail_prob), float(sm.tail_mean_us)),
+            (float(cfg.interference_prob), float(cfg.interference_mean_us),
+             float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
+            cfg.energy_model.params(),
+            n_seg, _LB_CODE[fleet.lb], lb_weights,
+            float(fleet.lb_softness_pkts), stale_every_slots,
+            fleet.far_hosts(), float(fleet.near_cost_us),
+            float(fleet.far_cost_us), float(fleet.link_rate_mpps),
+            n_shards, stepping)
 
-    pad = (-n_pts) % n_shards
-    def row(a, dtype):
-        arr = np.asarray(a)
-        if pad:
-            arr = np.concatenate([arr, np.repeat(arr[:1], pad, axis=0)])
-        return jnp.asarray(arr, dtype)
+        pad = (-n_pts) % n_shards
 
-    g = fgrid.grid
-    seed64 = np.asarray(g.seed, dtype=np.uint64)
-    out, simt, nst, fst = fn(
-        row(g.t_s_us, jnp.float32), row(g.t_l_us, jnp.float32),
-        row(g.m, jnp.int32), row(g.n_queues, jnp.int32),
-        row(g.rate_mpps, jnp.float32),
-        row((seed64 & np.uint64(0xFFFFFFFF)).astype(np.uint32),
-            jnp.uint32),
-        row((seed64 >> np.uint64(32)).astype(np.uint32), jnp.uint32),
-        row(fgrid.hedge_deadline_us, jnp.float32),
-        row(np.full(n_pts, cfg.duration_us), jnp.float32),
-        row(sched_edges, jnp.float32),
-        row(sched_scales, jnp.float32))
-    vals = {k: np.asarray(v, dtype=np.float64)[:n_pts]
-            for k, v in out._asdict().items()}
-    return FleetStats(
-        fgrid=fgrid, cfg=cfg, slot_us=float(slot_us),
-        backend=(f"shard_map({n_shards})" if n_shards > 1 else "vmap"),
-        stepping=stepping, scan_len=n_slots,
-        n_steps=np.asarray(nst, dtype=np.float64)[:n_pts],
-        forced_steps=np.asarray(fst, dtype=np.float64)[:n_pts],
-        sim_time_us=np.asarray(simt, dtype=np.float64)[:n_pts],
-        **vals)
+        def row(a, dtype):
+            arr = np.asarray(a)
+            if pad:
+                arr = np.concatenate([arr, np.repeat(arr[:1], pad, axis=0)])
+            return jnp.asarray(arr, dtype)
+
+        g = fgrid.grid
+        seed64 = np.asarray(g.seed, dtype=np.uint64)
+        args = (row(g.t_s_us, jnp.float32), row(g.t_l_us, jnp.float32),
+                row(g.m, jnp.int32), row(g.n_queues, jnp.int32),
+                row(g.rate_mpps, jnp.float32),
+                row((seed64 & np.uint64(0xFFFFFFFF)).astype(np.uint32),
+                    jnp.uint32),
+                row((seed64 >> np.uint64(32)).astype(np.uint32),
+                    jnp.uint32),
+                row(hedge, jnp.float32),
+                row(np.full(n_pts, cfg.duration_us), jnp.float32),
+                row(sched_edges, jnp.float32),
+                row(sched_scales, jnp.float32))
+        with tracing.span("fleet.scan"):
+            (out, hist), simt, nst, fst = jax.block_until_ready(fn(*args))
+        with tracing.span("fleet.fetch"):
+            vals = {k: np.asarray(v, dtype=np.float64)[:n_pts]
+                    for k, v in out._asdict().items()}
+            fs = FleetStats(
+                fgrid=fgrid, cfg=cfg, slot_us=float(slot_us),
+                backend=(f"shard_map({n_shards})" if n_shards > 1
+                         else "vmap"),
+                stepping=stepping, scan_len=n_slots,
+                hist=np.asarray(hist, dtype=np.float64)[:n_pts],
+                n_steps=np.asarray(nst, dtype=np.float64)[:n_pts],
+                forced_steps=np.asarray(fst, dtype=np.float64)[:n_pts],
+                sim_time_us=np.asarray(simt, dtype=np.float64)[:n_pts],
+                **vals)
+        sp.counts.update(
+            hedge_dup=round(float(fs.hedge_dup.sum())),
+            hedge_dup_dropped=round(float(fs.hedge_dup_dropped.sum())),
+            hist_overflow=round(float(fs.hist[..., -1:].sum())))
+    return fs

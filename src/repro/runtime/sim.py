@@ -42,6 +42,7 @@ from .simcore import (
     SimRunConfig,
     SleepModel,
     WindowAccum,
+    fifo_sojourns,
     prepare_run,
     queue_reservoirs,
     scheduled_workload,
@@ -111,6 +112,10 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
     vac, bus, nvs = [], [], []
     # one latency reservoir per queue, decorrelated seeds (simcore)
     lat_q = queue_reservoirs(cfg, nq)
+    # each ring's FIFO curves: count, start, end of every admission and
+    # departure run, in order, flat
+    admitted = [[] for _ in range(nq)]
+    departed = [[] for _ in range(nq)]
     awake_us = 0.0
     lat_area = 0.0           # queue-depth integral (packet*us), Little's law
     # EnergyModel accounting: active power on awake time, sleep +
@@ -126,9 +131,10 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
     b_cnt = np.zeros(max(nbins, 1))
     wa = WindowAccum(cfg)        # no-op when cfg.window_us == 0
 
-    def admit(q: int, n: int, at_t: float) -> None:
-        """Room-clipped enqueue of ``n`` arrivals into queue ``q``; drops
-        beyond queue capacity are counted (Rx-ring semantics)."""
+    def admit(q: int, n: int, at_t: float, to_t: float) -> None:
+        """Room-clipped enqueue of ``n`` arrivals spread over [at_t, to_t]
+        into queue ``q``: the earliest are admitted, drops beyond queue
+        capacity are counted (Rx-ring semantics)."""
         nonlocal offered, dropped
         offered += n
         offered_q[q] += n
@@ -138,7 +144,12 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
             d = int(n - max(room, 0))
             dropped += d
             dropped_q[q] += d
-            n = int(max(room, 0))
+            n_in = int(max(room, 0))
+        else:
+            n_in = n
+        if n_in > 0:
+            admitted[q].extend((n_in, at_t, at_t + (to_t - at_t) * n_in / n))
+        n = n_in
         backlog[q] += n
         if nbins:
             b = min(int(at_t / cfg.timeseries_bin_us), nbins - 1)
@@ -152,12 +163,12 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
             return
         n = workload.counts_in(last_advanced, to_t)
         if nq == 1:
-            admit(0, n, last_advanced)
+            admit(0, n, last_advanced, to_t)
         elif n > 0:
             parts = dispatcher.split(int(n), backlog)
             for q in range(nq):
                 if parts[q]:
-                    admit(q, int(parts[q]), last_advanced)
+                    admit(q, int(parts[q]), last_advanced, to_t)
         last_advanced = to_t
 
     def drain(q: int, t_start: float) -> tuple[float, int]:
@@ -176,6 +187,7 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
             b_r = float(backlog[q])
             served += float(backlog[q])
             total_t += dt
+            departed[q].extend((b_r, cursor, cursor + dt))
             n = workload.counts_in(cursor, cursor + dt)
             cursor += dt
             if nq == 1:
@@ -187,14 +199,18 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
                     own = int(parts[q])
                     for j in range(nq):
                         if j != q and parts[j]:
-                            admit(j, int(parts[j]), cursor)
+                            admit(j, int(parts[j]), cursor - dt, cursor)
             offered += own
             offered_q[q] += own
+            n_own = own
             if own > cfg.queue_capacity:
                 d = own - cfg.queue_capacity
                 dropped += d
                 dropped_q[q] += d
                 own = cfg.queue_capacity
+            if own > 0:
+                admitted[q].extend((own, cursor - dt,
+                                    cursor - dt + dt * own / n_own))
             backlog[q] = float(own)
             # Little integral, drain round r: the b_r being served decline
             # linearly to 0 over dt while the next round's own arrivals
@@ -275,16 +291,11 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
                 wa.add(t_cursor, awake=b_time, energy_uj=e_busy)
 
                 vac.append(v); bus.append(b_time); nvs.append(n_v)
-                # Latency: packets found at busy start waited (uniform
-                # arrival in V) V/2 on average + their drain position.
-                # Sample a handful per cycle for percentiles.
                 if n_v >= 1:
-                    k = min(int(n_v), 8)
-                    arr = rng.uniform(0.0, max(v, 1e-9), size=k)      # age
-                    pos = np.sort(rng.uniform(0.0, n_v, size=k)) / mu
-                    samp = (max(v, 1e-9) - arr + pos).tolist()
-                    lat_q[q].extend(samp)
-                    wa.latency_samples(t_cursor, samp)
+                    # consumed so that a seed's event sequence stays the
+                    # one the tests pin (latencies come from the FIFO
+                    # curves after the loop)
+                    rng.random(2 * min(int(n_v), 8))
 
                 pol.on_cycle_end(b_time, max(v, 1e-9))
                 t_cursor = float(busy_until[q])
@@ -328,6 +339,16 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
             delay += rng.exponential(cfg.interference_mean_us)
         wake_at[i] = t_cursor + delay
 
+    # per-packet sojourns, FIFO on each ring: departure minus admission
+    # time at the same rank, for ranks drawn stratified over every packet
+    # served (Little's law gives the mean; these give the percentiles).
+    # A 64th of the reservoir, so that merging up to 64 runs pools every
+    # sample.
+    for q in range(nq):
+        t_dep, samp = fifo_sojourns(admitted[q], departed[q],
+                                    cfg.latency_reservoir // 64, rng)
+        lat_q[q].extend(samp)
+        wa.latency_samples(t_dep, samp)
     cnt = np.maximum(b_cnt, 1)
     nbins_eff = max(nbins, 1)
     # run-level latency = weighted union of the per-queue reservoirs
@@ -514,19 +535,19 @@ def fleet_tail_reference(host_stats, fleet, hedge_deadline_us: float, *,
                          n_samples: int = 200_000,
                          seed: int = 0) -> np.ndarray:
     """Exact first-completion-wins hedging over measured per-host
-    latency samples — the reference the fluid/closed-form hedged-tail
-    model is parity-pinned against.
+    latency samples — the reference the fleet kernel's measured tail
+    (``FleetStats.quantile``) is pinned against.
 
     Per simulated request: pick a host by served share, draw a base
     latency from that host's empirical reservoir
     (``host_stats[h].latency_us``), add its topology delay (rack cost
     plus, for far hosts, an Exp-distributed share of the bottleneck-link
     M/M/1 wait at the measured far-rack offered rate).  If the total
-    exceeds the hedge deadline D, duplicate to a second host drawn from
-    the other replicas and finish at ``min(original, D + partner's full
-    latency)`` — first completion wins, exactly.  ``D <= 0`` disables
-    hedging.  Returns the ``n_samples`` end-to-end latencies; quantile
-    them directly.
+    exceeds the hedge deadline D, duplicate to a random other replica
+    (uniform over the others), which loses it with its loss share, and
+    finish at ``min(original, D + partner's full latency)`` — first
+    completion wins, exactly.  ``D <= 0`` disables hedging.  Returns the
+    ``n_samples`` end-to-end latencies; quantile them directly.
     """
     fleet.validate()
     if len(host_stats) != fleet.n_hosts:
@@ -540,6 +561,8 @@ def fleet_tail_reference(host_stats, fleet, hedge_deadline_us: float, *,
     served = np.asarray([max(rs.items, 1) for rs in host_stats],
                         dtype=np.float64)
     weight = served / served.sum()
+    loss = np.asarray([rs.dropped / max(rs.offered, 1)
+                       for rs in host_stats], dtype=np.float64)
     far = fleet.far_mask()
     duration_us = host_stats[0].duration_ns / 1e3
     far_rate = float(sum(rs.offered for rs, f in zip(host_stats, far)
@@ -567,13 +590,11 @@ def fleet_tail_reference(host_stats, fleet, hedge_deadline_us: float, *,
         slow = lat > d
         n_slow = int(slow.sum())
         if n_slow:
-            # partner: an independent draw from the OTHER replicas,
-            # renormalized served-share weights
-            pw = np.tile(weight, (n_slow, 1))
-            pw[np.arange(n_slow), hosts[slow]] = 0.0
-            pw /= pw.sum(axis=1, keepdims=True)
-            cum = np.cumsum(pw, axis=1)
-            u = rng.random(n_slow)
-            partners = (u[:, None] > cum).sum(axis=1).astype(np.int64)
-            lat[slow] = np.minimum(lat[slow], d + draw(partners))
+            # partner: a random other replica (uniform over the others),
+            # which loses the duplicate with its loss share
+            partners = rng.integers(fleet.n_hosts - 1, size=n_slow)
+            partners += partners >= hosts[slow]
+            y = d + draw(partners)
+            lost = rng.random(n_slow) < loss[partners]
+            lat[slow] = np.where(lost, lat[slow], np.minimum(lat[slow], y))
     return lat

@@ -514,10 +514,19 @@ class WindowAccum:
         self.rho_cnt[i] += 1
         self.ts_sum[i] += ts_us
 
-    def latency_samples(self, t_us: float, values) -> None:
-        if not self.n or t_us >= self.duration_us:
+    def latency_samples(self, t_us, values) -> None:
+        """Latency samples taken at ``t_us``: one time for all of them or
+        one per sample; each goes to its time's window."""
+        if not self.n:
             return
-        self.samples[self._idx(t_us)].extend(values)
+        v = np.asarray(values, dtype=np.float64).ravel()
+        t = np.broadcast_to(np.asarray(t_us, dtype=np.float64), v.shape)
+        keep = t < self.duration_us
+        idx = np.clip((t[keep] / self.window_us).astype(np.int64), 0,
+                      self.n - 1)
+        v = v[keep]
+        for i in np.unique(idx):
+            self.samples[int(i)].extend(v[idx == i].tolist())
 
     def series(self, cfg: SimRunConfig) -> WindowedSeries | None:
         if not self.n:
@@ -539,6 +548,35 @@ class WindowAccum:
             spill_lat_area_us=self.spill_lat_area,
             spill_awake_us=self.spill_awake,
             spill_energy_uj=self.spill_energy)
+
+
+def fifo_sojourns(admitted: list, departed: list, k: int,
+                  rng) -> tuple[np.ndarray, np.ndarray]:
+    """Per-packet sojourns on one FIFO ring from its admission and
+    departure runs, flat lists of ``count, start, end`` triples, the
+    packets of a run spread evenly over [start, end]: a packet leaves at
+    the rank it was admitted at, so its sojourn is the departure curve's
+    time at that rank minus the admission curve's.  Returns (departure
+    times, sojourns) of ``k`` ranks drawn stratified over every packet
+    served (all of them when fewer)."""
+    if not admitted or not departed:
+        return np.empty(0), np.empty(0)
+    adm = np.asarray(admitted, dtype=np.float64).reshape(-1, 3)
+    dep = np.asarray(departed, dtype=np.float64).reshape(-1, 3)
+    n_dep = float(dep[:, 0].sum())
+    k = min(int(k), int(n_dep))
+    if k < 1:
+        return np.empty(0), np.empty(0)
+    ranks = (np.arange(k) + rng.random(k)) / k * n_dep
+
+    def time_at(runs):
+        top = np.cumsum(runs[:, 0])
+        i = np.minimum(np.searchsorted(top, ranks), len(runs) - 1)
+        frac = np.clip(1.0 - (top[i] - ranks) / runs[i, 0], 0.0, 1.0)
+        return runs[i, 1] + frac * (runs[i, 2] - runs[i, 1])
+
+    t_dep = time_at(dep)
+    return t_dep, np.maximum(t_dep - time_at(adm), 0.0)
 
 
 def queue_reservoirs(cfg: SimRunConfig, n_queues: int) -> list[Reservoir]:

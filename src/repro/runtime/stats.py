@@ -34,7 +34,7 @@ class Reservoir:
     truthiness guards — keep working unchanged.
     """
 
-    __slots__ = ("capacity", "count", "_buf", "_rng", "_np_rng")
+    __slots__ = ("capacity", "count", "_buf", "_seed", "_py", "_np")
 
     def __init__(self, capacity: int = 65_536, seed: int = 0):
         if capacity < 1:
@@ -42,8 +42,23 @@ class Reservoir:
         self.capacity = capacity
         self.count = 0              # total values ever offered
         self._buf: list[float] = []
-        self._rng = random.Random(seed)
-        self._np_rng = np.random.default_rng(seed)
+        # generators seeded on first use: a fleet rollup makes thousands
+        # of reservoirs that never sample
+        self._seed = seed
+        self._py: random.Random | None = None
+        self._np: np.random.Generator | None = None
+
+    @property
+    def _rng(self) -> random.Random:
+        if self._py is None:
+            self._py = random.Random(self._seed)
+        return self._py
+
+    @property
+    def _np_rng(self) -> np.random.Generator:
+        if self._np is None:
+            self._np = np.random.default_rng(self._seed)
+        return self._np
 
     def append(self, value: float) -> None:
         self.count += 1
@@ -891,7 +906,10 @@ def hedged_latency_quantile(q: float, mean_us, weight=None, *,
                             hedge_deadline_us: float = 0.0,
                             tail_prob: float = 0.0,
                             tail_scale_us: float = 0.0) -> float:
-    """Latency quantile of a replicated fleet under hedged requests.
+    """Latency quantile of a replicated fleet under hedged requests, from
+    an assumed tail model.  It scores fleets that no kernel measures
+    (the busy-poll comparator of ``benchmarks/fleet.py``); the fleet
+    kernel measures its own tail (``FleetStats.quantile``).
 
     Per-host latency follows a two-component survival
 

@@ -79,9 +79,11 @@ def _lattice():
 
 def _compile_gather_free(fn, args):
     """The kernel compiles for the chip and takes the queue axis by
-    one-hot selects: its compiled text holds no gather."""
+    one-hot selects: its compiled text holds no gather.  Returns the
+    text."""
     text = _compile(fn, *args).as_text()
     assert text.count("gather(") == 0
+    return text
 
 
 def test_fixed_sweep_kernel_compiles(capture_kernel, on_chip):
@@ -116,7 +118,10 @@ def test_fleet_kernel_compiles_1000_hosts(capture_kernel, on_chip):
     fn, args = capture_kernel(
         fleet, "_compiled_fleet_sweep", fleet._build_fleet_sweep,
         lambda: simulate_fleet(fgrid, cfg, slot_us=slot_us, shard=False))
-    _compile_gather_free(fn, on_chip(args))
+    text = _compile_gather_free(fn, on_chip(args))
+    # the hedge exchange is a mean over the other hosts and the sojourn
+    # ring is read by compare-and-count: no scatter either
+    assert text.count("scatter(") == 0
 
 
 @pytest.fixture(scope="module")

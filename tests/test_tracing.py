@@ -108,6 +108,37 @@ def test_one_sweep_one_scan_one_fetch_and_built(stepping):
     assert built == [1, 0]
 
 
+def test_fleet_sweep_tail_and_rollup_spans():
+    from repro.runtime import FleetConfig, FleetGrid, simulate_fleet
+
+    # a shape no other test builds (three hosts, 2,345 us), so the first
+    # call misses the kernel cache
+    cfg = SimRunConfig(duration_us=2345.0, stall_rate_per_us=1e-3,
+                       stall_mean_us=50.0)
+    fg = FleetGrid.product(fleet=FleetConfig(n_hosts=3), t_s_us=[10.0],
+                           t_l_us=[300.0], m=[2], rate_mpps=[30.0],
+                           hedge_deadline_us=[0.0, 20.0])
+    built = []
+    for _ in range(2):
+        fs = simulate_fleet(fg, cfg, slot_us=0.5, shard=False)
+        sweep = _last("fleet.sweep")
+        kids = _children(sweep)
+        assert [k.name for k in kids] == ["fleet.scan", "fleet.fetch"]
+        assert sweep.counts["points"] == 2 and sweep.counts["hosts"] == 3
+        assert sweep.counts["scan_steps"] == fs.scan_len
+        assert sweep.counts["hedge_dup"] == round(fs.hedge_dup.sum())
+        assert sweep.counts["hedge_dup_dropped"] == round(
+            fs.hedge_dup_dropped.sum())
+        assert sweep.counts["hist_overflow"] == round(fs.hist_overflow.sum())
+        built.append(sweep.counts["built"])
+    assert built == [1, 0]
+    fs.quantiles(0.999)
+    assert _last("fleet.tail").counts == {"points": 2}
+    rolled = fs.rollups()
+    assert len(rolled) == 2
+    assert _last("fleet.rollup").counts == {"points": 2}
+
+
 def test_table_spans_spot_checks_under_one_table():
     cfg = SimRunConfig(duration_us=30_000.0)
     lattice = dict(t_s_grid=np.linspace(4.0, 48.0, 6), t_l_grid=[150.0, 500.0],
