@@ -3,10 +3,10 @@
 The event engine (``repro.runtime.sim``) is exact but serial — one
 (policy, workload, environment) point per call, at interpreter speed.
 This engine trades per-event exactness for *throughput*: it discretizes
-time into fixed slots (``lax.scan``), advances every poller thread and
-Rx queue one slot at a time with pure array ops, and ``vmap``s the whole
-run over a ``SweepGrid`` of operating points — (T_S, T_L, M, n_queues,
-offered load, seed) — so a dense parameter sweep is one JIT-compiled
+time into fixed slots, and advances every poller thread and Rx queue
+one slot at a time with pure array ops over a whole ``SweepGrid`` of
+operating points — (T_S, T_L, M, n_queues, offered load, seed), the
+lanes of every array — so a dense parameter sweep is one JIT-compiled
 call instead of thousands of Python simulations.
 
 Model (per grid point, per slot of ``slot_us``):
@@ -105,6 +105,13 @@ this docstring is a statement about its *per-slot* update at
   - wake-timer / busy-period / stall-window quantization is always
     ``slot_us`` regardless of the padded scan length.
 
+Its slot body is written once, lanes-minor (the points are the lanes),
+and has two drivers chosen from the backend and the static build: on a
+TPU a stationary build without windows runs its slots K at a time in
+one Pallas kernel (``repro.kernels.fixed_scan``), with the state kept
+on the core; otherwise a ``lax.scan`` runs them one at a time.  Both
+draw from the same ``jax.random`` calls and give the same bits.
+
 ``stepping="adaptive"`` dispatches to the event-jump kernel in
 ``batched_adaptive.py``: variable ``dt`` per scan step (next wake /
 drain-out / fill / schedule-segment / window / stall-start boundary),
@@ -116,8 +123,10 @@ is documented in that module; both modes hold the parity bands above.
 
 from __future__ import annotations
 
+import functools
 import logging
 import math
+import operator
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import NamedTuple
@@ -516,13 +525,68 @@ class BatchStats:
         return len(self.grid)
 
 
+class _Point(NamedTuple):
+    """A point's constants, one row each (an array over the points)."""
+    m: jnp.ndarray             # threads
+    nq: jnp.ndarray            # queues
+    mu_a: tuple                # per queue: mean arrivals a slot (0 past nq)
+    sd_a: tuple                # per queue: sqrt(mu_a), their deviation
+    slp_s: jnp.ndarray         # T_S * (1 + slope)
+    slp_l: jnp.ndarray         # T_L * (1 + slope)
+    e_arm_s: jnp.ndarray       # energy of a T_S arm
+    e_arm_l: jnp.ndarray       # energy of a T_L arm
+    duration: jnp.ndarray
+
+
+class _Lanes(NamedTuple):
+    """The scan's state, lanes-minor: one row per thread, queue or
+    counter, each row an array over the points."""
+    sleep_rem: tuple           # per thread: time left on its timer
+    attached: tuple            # per thread: the queue it drains, -1 none
+    backlog: tuple             # per queue
+    vac_timer: tuple           # per queue: time since it was last claimed
+    arr_res: tuple             # per queue: arrival deficit carried forward
+    stall_end: jnp.ndarray     # end of the open stall window, -1 none
+    S: _SlotStats
+
+
+class _Draws(NamedTuple):
+    """One slot's random inputs, one row each."""
+    z: tuple                   # per queue: N(0, 1) for the arrivals
+    over: tuple                # per thread: sleep overshoot
+    stall_len: jnp.ndarray | None  # a window opening now: its length; -inf
+    jitter: tuple              # per thread: U(0, 1) re-arm after a stall
+
+
+def _fold(xs):
+    """Left fold of ``+`` in index order: a sum over threads or queues."""
+    return functools.reduce(operator.add, xs)
+
+
+def _any(xs):
+    return functools.reduce(operator.or_, xs)
+
+
+def _fused(n_seg: int, n_windows: int) -> bool:
+    """Whether the Pallas kernel (``repro.kernels.fixed_scan``) runs the
+    slots: on a TPU, for a stationary build without windows.  Elsewhere
+    a ``lax.scan`` runs the same slot body."""
+    return jax.default_backend() == "tpu" and not n_seg and not n_windows
+
+
+# the chunk's draws, in the kernel's two pipeline buffers, fit this much
+# VMEM; a chunk is at most _MAX_CHUNK slots
+_DRAW_VMEM = 16 << 20
+_MAX_CHUNK = 1024
+
+
 def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
                  mu: float, capacity: float, wake_cost_us: float,
                  sleep_params: tuple, interference_params: tuple,
                  energy_params: tuple,
                  n_seg: int = 0, n_windows: int = 0,
-                 window_us: float = 0.0):
-    """Build + jit the vmapped fixed-slot kernel for one static shape.
+                 window_us: float = 0.0, fused: bool = False):
+    """Build + jit the fixed-slot kernel for one static shape.
 
     ``n_seg > 0`` compiles the nonstationary variant: each point carries
     a piecewise-constant load schedule as ``(edges, scales)`` rows of
@@ -534,7 +598,15 @@ def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
     ``EnergyModel.params()`` tuple; per-arm C-state charges are
     closed-form per point (the targets T_S/T_L are per-point traced
     scalars), so the energy column costs one fused multiply-add per
-    slot."""
+    slot.
+
+    The slot body is written once, lanes-minor: every per-thread and
+    per-queue quantity is its own row over the points, and the threads
+    and queues are short static loops.  ``fused`` picks its driver (see
+    ``_fused``): the Pallas kernel, K slots a call inside a ``lax.scan``
+    over chunks, with each chunk's draws made first by the same
+    ``jax.random`` calls; or a ``lax.scan`` over slots, which also
+    serves schedules and windows.  Both give the same bits."""
     base_us, slope, sigma_us, tail_prob, tail_mean_us = sleep_params
     intf_prob, intf_mean_us, stall_rate, stall_mean_us = interference_params
     active_power_w, _dvfs_scale, e_states = energy_params
@@ -544,17 +616,15 @@ def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
     t_idx = jnp.arange(m_max)
     q_idx = jnp.arange(q_max)
 
-    def one_point(t_s, t_l, m, nq, lam, seed_lo, seed_hi, duration,
-                  sched_edges, sched_scales):
+    def point(t_s, t_l, m, nq, lam, seed_lo, seed_hi):
+        """One point's key, first timers, arrival rates and arm costs."""
         tmask = t_idx < m
-        qmask = q_idx < nq
-        lam_q = jnp.where(qmask, lam / nq, 0.0)
+        lam_q = jnp.where(q_idx < nq, lam / nq, 0.0)
         # per-arm energy of the point's two sleep classes — the C-state
         # follows the programmed target (event-engine convention), so
         # both charges are point constants hoisted out of the scan
         e_arm_s = energy_arm_cost(t_s, e_states)
         e_arm_l = energy_arm_cost(t_l, e_states)
-
         # both 32-bit halves of the 64-bit seed are folded in, so seeds
         # differing only in their high bits stay independent
         key = jax.random.fold_in(
@@ -564,203 +634,327 @@ def _build_sweep(n_slots: int, slot_us: float, m_max: int, q_max: int,
         # uniformly inside one primary timeout, not spread over T_L
         sleep0 = jax.random.uniform(k0, (m_max,)) * t_s
         sleep0 = jnp.where(tmask, jnp.maximum(sleep0, dt), jnp.inf)
+        return key, sleep0, lam_q, e_arm_s, e_arm_l
+
+    def draw(key, t):
+        """One point's draws for slot ``t``: arrival normals (one per
+        queue), overshoots (one per thread), the stall window and the
+        re-arm jitter."""
+        with jax.named_scope("rng"):
+            kt_step = jax.random.fold_in(key, t)
+            if tail_prob > 0.0:
+                kt_step, kp, ku = jax.random.split(kt_step, 3)
+            if intf_prob > 0.0:
+                kt_step, kip, kie = jax.random.split(kt_step, 3)
+            if stall_p > 0.0:
+                kt_step, ksp, kse, ksu = jax.random.split(kt_step, 4)
+            # one fused normal draw covers arrivals + sleep noise
+            zs = jax.random.normal(kt_step, (q_max + m_max,))
+
+        # correlated stall windows: Bernoulli(1-exp(-rate*dt)) opens
+        # an Exp(stall_mean)-long system-wide freeze
+        stall_len, jitter = None, ()
+        with jax.named_scope("stall"):
+            if stall_p > 0.0:
+                stall_len = jnp.where(
+                    jax.random.uniform(ksp, ()) < stall_p,
+                    stall_mean_us * jax.random.exponential(kse, ()),
+                    -jnp.inf)
+                jitter = jax.random.uniform(ksu, (m_max,))
+
+        # sleep overshoot draws for this slot (one per thread;
+        # static zeros when the model is exact)
+        with jax.named_scope("overshoot"):
+            over = jnp.full((m_max,), base_us)
+            if sigma_us > 0.0:
+                over = over + sigma_us * jnp.abs(zs[q_max:])
+            if tail_prob > 0.0:
+                hit = jax.random.uniform(kp, (m_max,)) < tail_prob
+                over = over + hit * tail_mean_us * jax.random.exponential(
+                    ku, (m_max,))
+            # per-wake OS interference (paper Sec 5.6): each re-sleep is
+            # lengthened by Exp(mean) w.p. q — one independent draw per
+            # thread per slot, charged only on the slots where a thread
+            # actually re-arms (the same per-sleep draw the event engine
+            # makes after sampling the sleep model)
+            if intf_prob > 0.0:
+                ihit = jax.random.uniform(kip, (m_max,)) < intf_prob
+                over = over + ihit * intf_mean_us * jax.random.exponential(
+                    kie, (m_max,))
+        return zs[:q_max], over, stall_len, jitter
+
+    def slot(c, s, d, t):
+        """Slot ``t`` of every point: the new state, the slot's
+        [offered, served, lat_area, awake, energy] and whether it was
+        live."""
+        now = t.astype(jnp.float32) * dt
+        # slots at/past the traced duration are carry-preserving
+        # no-ops: the scan length is bucketed (bucket_steps), so one
+        # compiled kernel serves every nearby duration
+        live = (now < c.duration) & (t < n_slots)
+        tmask = [c.m > i for i in range(m_max)]
+        qmask = [c.nq > q for q in range(q_max)]
+
+        # overlapping stall windows extend (max), like the event
+        # engine's lazy merge
+        stall_end = s.stall_end
+        with jax.named_scope("stall"):
+            if stall_p > 0.0:
+                stall_end = jnp.maximum(stall_end, now + d.stall_len)
+
+        # 1. arrivals: residual-carried Gaussian fluid ~ Poisson
+        with jax.named_scope("arrivals"):
+            raw = [r + mu_a + sd * z for r, mu_a, sd, z
+                   in zip(s.arr_res, c.mu_a, c.sd_a, d.z)]
+            a = [jnp.maximum(x, 0.0) for x in raw]
+            arr_res = tuple(jnp.minimum(x, 0.0) for x in raw)  # deficit
+            adm = [jnp.minimum(x, jnp.maximum(capacity - b, 0.0))
+                   for x, b in zip(a, s.backlog)]
+            backlog = [b + x for b, x in zip(s.backlog, adm)]
+            offered = _fold(a)
+            dropped = _fold([x - y for x, y in zip(a, adm)])
+
+        with jax.named_scope("overshoot"):
+            slp_s = [c.slp_s + o for o in d.over]
+            slp_l = [c.slp_l + o for o in d.over]
+
+        # 2. countdown + wake + claim (threads in index order)
+        with jax.named_scope("wake_claim"):
+            sleep_rem, woken = [], []
+            for i in range(m_max):
+                sleeping = tmask[i] & (s.attached[i] < 0)
+                r = jnp.where(sleeping, s.sleep_rem[i] - dt, s.sleep_rem[i])
+                w = sleeping & (r <= 0.0)
+                if stall_p > 0.0:
+                    # timers expiring inside an open stall window defer to
+                    # its end (+U(0,1)us re-arm jitter) and are NOT counted
+                    # as wakes — the event engine's deferred-wake semantics
+                    push = w & (now < stall_end)
+                    w = w & ~push
+                    r = jnp.where(push, stall_end - now + d.jitter[i], r)
+                sleep_rem.append(r)
+                woken.append(w)
+            n_wake = _fold([jnp.where(w, 1.0, 0.0) for w in woken])
+
+            attached = list(s.attached)
+            vac = list(s.vac_timer)
+            occ = [_any([a == q for a in attached]) for q in range(q_max)]
+            busy_tries = cycles = vac_sum = nv_sum = ts_arm = 0.0
+            for i in range(m_max):
+                w = woken[i]
+                free = [qm & ~o for qm, o in zip(qmask, occ)]
+                claimable = [f & (b >= 1.0) for f, b in zip(free, backlog)]
+                # the claimable queue with the longest backlog, the
+                # first of equals
+                best = jnp.where(claimable[0], backlog[0], -1.0)
+                qi = jnp.zeros_like(attached[i])
+                for q in range(1, q_max):
+                    v = jnp.where(claimable[q], backlog[q], -1.0)
+                    longer = v > best
+                    qi = jnp.where(longer, q, qi)
+                    best = jnp.where(longer, v, best)
+                # the first free (empty) queue
+                first_free = [free[0]]
+                for q in range(1, q_max):
+                    first_free.append(free[q] & ~_any(free[:q]))
+                any_claimable, any_free = _any(claimable), _any(free)
+                do_attach = w & any_claimable
+                empty_claim = w & ~any_claimable & any_free
+                blocked = w & ~any_free
+
+                claim_hot = [do_attach & (qi == q) for q in range(q_max)]
+                claim_any = [h | (empty_claim & f)
+                             for h, f in zip(claim_hot, first_free)]
+                # one nonzero term at most, so each sum is exact
+                vac_sum = vac_sum + _fold(
+                    [jnp.where(h, v, 0.0) for h, v in zip(claim_any, vac)])
+                nv_sum = nv_sum + _fold(
+                    [jnp.where(h, b, 0.0) for h, b in zip(claim_hot, backlog)])
+                vac = [jnp.where(h, 0.0, v) for h, v in zip(claim_any, vac)]
+                cycles = cycles + jnp.where(do_attach | empty_claim, 1.0, 0.0)
+                busy_tries = busy_tries + jnp.where(blocked, 1.0, 0.0)
+                ts_arm = ts_arm + jnp.where(empty_claim, 1.0, 0.0)
+                attached[i] = jnp.where(do_attach, qi, attached[i])
+                occ = [o | h for o, h in zip(occ, claim_hot)]
+                # re-sleep adds onto the (negative) expired-timer
+                # residual: removes the slot-quantization wake-rate bias
+                sleep_rem[i] = sleep_rem[i] + jnp.where(
+                    empty_claim, slp_s[i], jnp.where(blocked, slp_l[i], 0.0))
+
+        # 3. locked queues drain at mu for the slot
+        with jax.named_scope("drain"):
+            serve = [jnp.where(o, jnp.minimum(b, mu * dt), 0.0)
+                     for o, b in zip(occ, backlog)]
+            backlog = [b - x for b, x in zip(backlog, serve)]
+            served = _fold(serve)
+
+        # 4. emptied queues release their thread (fresh T_S sleep)
+        with jax.named_scope("release"):
+            q_done = [o & (b <= 1e-6) for o, b in zip(occ, backlog)]
+            t_done = [_any([(a == q) & qd for q, qd in enumerate(q_done)])
+                      for a in attached]
+            ts_arm = ts_arm + _fold([jnp.where(x, 1.0, 0.0) for x in t_done])
+            sleep_rem = [jnp.where(x, ss, r)
+                         for x, ss, r in zip(t_done, slp_s, sleep_rem)]
+            attached = [jnp.where(x, -1, a) for x, a in zip(t_done, attached)]
+            occ = [o & ~qd for o, qd in zip(occ, q_done)]
+
+        # 5. vacations tick on unlocked queues; 6. Little integral
+        with jax.named_scope("accumulate"):
+            vac = [v + jnp.where(qm & ~o, dt, 0.0)
+                   for v, qm, o in zip(vac, qmask, occ)]
+            lat_area = _fold(backlog) * dt
+
+            # energy: active power over the slot's awake time plus the
+            # per-arm C-state charges (blocked wakes re-arm T_L)
+            awake_step = n_wake * wake_cost_us + served / mu
+            energy_step = (active_power_w * awake_step
+                           + ts_arm * c.e_arm_s + busy_tries * c.e_arm_l)
+
+            S = s.S
+            S = _SlotStats(
+                offered=S.offered + offered,
+                dropped=S.dropped + dropped,
+                serviced=S.serviced + served,
+                wakeups=S.wakeups + n_wake,
+                busy_tries=S.busy_tries + busy_tries,
+                cycles=S.cycles + cycles,
+                awake_us=S.awake_us + awake_step,
+                lat_area=S.lat_area + lat_area,
+                vac_sum=S.vac_sum + vac_sum,
+                nv_sum=S.nv_sum + nv_sum,
+                ts_arms=S.ts_arms + ts_arm,
+                energy_uj=S.energy_uj + energy_step,
+            )
+        with jax.named_scope("gate"):
+            nxt = _Lanes(tuple(sleep_rem), tuple(attached), tuple(backlog),
+                         tuple(vac), arr_res, stall_end, S)
+            gated = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(live, new, old), nxt, s)
+        return gated, (offered, served, lat_area, awake_step,
+                       energy_step), live
+
+    def slot_draws(z, over, stall_len, jitter):
+        """``_Draws`` from one slot's draws, ``(..., points, ...)``."""
+        return _Draws(
+            z=tuple(z[..., q] for q in range(q_max)),
+            over=tuple(over[..., i] for i in range(m_max)),
+            stall_len=stall_len,
+            jitter=tuple(jitter[..., i] for i in range(m_max))
+            if stall_p > 0.0 else ())
+
+    def scan_slots(c, s0, keys, lam_q, sched_edges, sched_scales):
+        n = keys.shape[0]
 
         def step(carry, t):
-            prev = carry
-            (sleep_rem, attached, backlog, vac_timer, arr_res, stall_end,
-             S, win_acc) = carry
-            now = t.astype(jnp.float32) * dt
-            # slots at/past the traced duration are carry-preserving
-            # no-ops: the scan length is bucketed (bucket_steps), so one
-            # compiled kernel serves every nearby duration
-            live = now < duration
-            with jax.named_scope("rng"):
-                kt_step = jax.random.fold_in(key, t)
-                if tail_prob > 0.0:
-                    kt_step, kp, ku = jax.random.split(kt_step, 3)
-                if intf_prob > 0.0:
-                    kt_step, kip, kie = jax.random.split(kt_step, 3)
-                if stall_p > 0.0:
-                    kt_step, ksp, kse, ksu = jax.random.split(kt_step, 4)
-                # one fused normal draw covers arrivals + sleep noise
-                zs = jax.random.normal(kt_step, (q_max + m_max,))
-
-            # correlated stall windows: Bernoulli(1-exp(-rate*dt)) opens
-            # an Exp(stall_mean)-long system-wide freeze; overlapping
-            # windows extend (max), like the event engine's lazy merge
-            with jax.named_scope("stall"):
-                if stall_p > 0.0:
-                    hit_s = jax.random.uniform(ksp, ()) < stall_p
-                    win = now + stall_mean_us * jax.random.exponential(
-                        kse, ())
-                    stall_end = jnp.where(
-                        hit_s, jnp.maximum(stall_end, win), stall_end)
-
-            # 1. arrivals: residual-carried Gaussian fluid ~ Poisson,
-            # rate modulated by the point's load schedule when one is
-            # compiled in (piecewise-constant scale looked up per slot)
+            s, win_acc = carry
+            draws = jax.vmap(draw, in_axes=(0, None))(keys, t)
+            # the load schedule, when one is compiled in, scales each
+            # point's arrival rate by its piecewise-constant segment
+            cs = c
             with jax.named_scope("arrivals"):
                 if n_seg > 0:
-                    si = jnp.clip(
-                        jnp.searchsorted(sched_edges, now, side="right") - 1,
-                        0, n_seg - 1)
-                    mu_a = lam_q * sched_scales[si] * dt
-                else:
-                    mu_a = lam_q * dt
-                raw = arr_res + mu_a + jnp.sqrt(mu_a) * zs[:q_max]
-                a = jnp.maximum(raw, 0.0)
-                arr_res = jnp.minimum(raw, 0.0)      # deficit carried forward
-                room = jnp.maximum(capacity - backlog, 0.0)
-                adm = jnp.minimum(a, room)
-                backlog = backlog + adm
-                offered = a.sum()
-                dropped = (a - adm).sum()
+                    now = t.astype(jnp.float32) * dt
+                    scale = jax.vmap(lambda e, sc: sc[jnp.clip(
+                        jnp.searchsorted(e, now, side="right") - 1,
+                        0, n_seg - 1)])(sched_edges, sched_scales)
+                    mu_a = tuple(lam_q[:, q] * scale * dt
+                                 for q in range(q_max))
+                    cs = c._replace(mu_a=mu_a,
+                                    sd_a=tuple(jnp.sqrt(x) for x in mu_a))
+            s, vals, live = slot(cs, s, slot_draws(*draws), t)
+            if n_windows > 0:
+                # the event engine's WindowAccum convention: raw
+                # [offered, served, lat_area, awake, energy] per window,
+                # one array each: stacked inside the loop, they slowed
+                # its steps on XLA's CPU backend some two hundredfold
+                now = t.astype(jnp.float32) * dt
+                w = jnp.minimum((now / window_us).astype(jnp.int32),
+                                n_windows - 1)
+                win_acc = tuple(
+                    jnp.where(live[:, None], a.at[:, w].add(v), a)
+                    for a, v in zip(win_acc, vals))
+            return (s, win_acc), None
 
-            # sleep overshoot draws for this slot (one per thread;
-            # static zeros when the model is exact)
-            with jax.named_scope("overshoot"):
-                over = jnp.full((m_max,), base_us)
-                if sigma_us > 0.0:
-                    over = over + sigma_us * jnp.abs(zs[q_max:])
-                if tail_prob > 0.0:
-                    hit = jax.random.uniform(kp, (m_max,)) < tail_prob
-                    over = over + hit * tail_mean_us * jax.random.exponential(
-                        ku, (m_max,))
-                # per-wake OS interference (paper Sec 5.6): each re-sleep is
-                # lengthened by Exp(mean) w.p. q — one independent draw per
-                # thread per slot, charged only on the slots where a thread
-                # actually re-arms (the same per-sleep draw the event engine
-                # makes after sampling the sleep model)
-                if intf_prob > 0.0:
-                    ihit = jax.random.uniform(kip, (m_max,)) < intf_prob
-                    over = over + ihit * intf_mean_us * jax.random.exponential(
-                        kie, (m_max,))
-                slp_s = t_s * (1.0 + slope) + over
-                slp_l = t_l * (1.0 + slope) + over
+        win0 = (jnp.zeros((n, max(n_windows, 1)), jnp.float32),) * 5
+        (s, win_acc), _ = jax.lax.scan(
+            step, (s0, win0), jnp.arange(n_slots, dtype=jnp.int32))
+        return s, jnp.stack(win_acc, axis=-1)
 
-            # 2. countdown + wake + claim (threads in index order)
-            with jax.named_scope("wake_claim"):
-                sleeping = tmask & (attached < 0)
-                sleep_rem = jnp.where(sleeping, sleep_rem - dt, sleep_rem)
-                woken = sleeping & (sleep_rem <= 0.0)
-                if stall_p > 0.0:
-                    # timers expiring inside an open stall window defer to its
-                    # end (+U(0,1)us re-arm jitter) and are NOT counted as
-                    # wakes — the event engine's deferred-wake semantics
-                    push = woken & (now < stall_end)
-                    woken = woken & ~push
-                    sleep_rem = jnp.where(
-                        push,
-                        stall_end - now + jax.random.uniform(ksu, (m_max,)),
-                        sleep_rem)
-                n_wake = woken.sum().astype(jnp.float32)
+    def kernel_slots(c, s0, keys):
+        from ..kernels.fixed_scan import LANES, ROWS, advance_slots
 
-                occ = (jax.nn.one_hot(attached, q_max).sum(axis=0) > 0)
-                busy_tries = jnp.float32(0.0)
-                cycles = jnp.float32(0.0)
-                vac_sum = jnp.float32(0.0)
-                nv_sum = jnp.float32(0.0)
-                ts_arm = jnp.float32(0.0)
-                for i in range(m_max):            # static unroll, m_max small
-                    w = woken[i]
-                    free_q = qmask & ~occ
-                    claimable = free_q & (backlog >= 1.0)
-                    qi = jnp.argmax(jnp.where(claimable, backlog, -1.0))
-                    do_attach = w & claimable.any()
-                    empty_claim = w & ~claimable.any() & free_q.any()
-                    eqi = jnp.argmax(free_q)      # first free (empty) queue
-                    blocked = w & ~free_q.any()
+        tree = jax.tree_util
+        n = keys.shape[0]
+        lanes = -(-n // (ROWS * LANES)) * ROWS * LANES
 
-                    claim_hot = do_attach & (q_idx == qi)
-                    claim_any = claim_hot | (empty_claim & (q_idx == eqi))
-                    vac_sum = vac_sum + (vac_timer * claim_any).sum()
-                    # one nonzero term at most, so the sum is exact
-                    nv_sum = nv_sum + jnp.where(claim_hot, backlog, 0.0).sum()
-                    vac_timer = jnp.where(claim_any, 0.0, vac_timer)
-                    cycles = cycles + (do_attach | empty_claim)
-                    busy_tries = busy_tries + blocked
-                    ts_arm = ts_arm + empty_claim
-                    attached = attached.at[i].set(
-                        jnp.where(do_attach, qi, attached[i]))
-                    occ = occ | claim_hot
-                    # re-sleep adds onto the (negative) expired-timer
-                    # residual: removes the slot-quantization wake-rate bias
-                    sleep_rem = sleep_rem.at[i].add(
-                        jnp.where(empty_claim, slp_s[i],
-                                  jnp.where(blocked, slp_l[i], 0.0)))
+        def tiles(rows):
+            """Rows over the points as ``(rows, R, 128)`` tiles."""
+            x = jnp.pad(jnp.stack(rows), ((0, 0), (0, lanes - n)))
+            return x.reshape(len(rows), lanes // LANES, LANES)
 
-            # 3. locked queues drain at mu for the slot
-            with jax.named_scope("drain"):
-                serve = jnp.where(occ, jnp.minimum(backlog, mu * dt), 0.0)
-                backlog = backlog - serve
-                served = serve.sum()
+        def untile(x):
+            return list(x.reshape(x.shape[0], lanes)[:, :n])
 
-            # 4. emptied queues release their thread (fresh T_S sleep)
-            with jax.named_scope("release"):
-                q_done = occ & (backlog <= 1e-6)
-                # one-hot over the queue axis: a per-point gather here
-                # costs most of a scan step on a TPU; a detached
-                # thread (-1) matches no queue
-                t_done = ((attached[:, None] == q_idx) & q_done).any(axis=1)
-                ts_arm = ts_arm + t_done.sum()
-                sleep_rem = jnp.where(t_done, slp_s, sleep_rem)
-                attached = jnp.where(t_done, -1, attached)
-                occ = occ & ~q_done
+        def draw_rows(key, t):
+            return jnp.stack(tree.tree_leaves(slot_draws(*draw(key, t))))
 
-            # 5. vacations tick on unlocked queues; 6. Little integral
-            with jax.named_scope("accumulate"):
-                vac_timer = vac_timer + jnp.where(qmask & ~occ, dt, 0.0)
-                lat_area = backlog.sum() * dt
+        c_rows, c_def = tree.tree_flatten(c)
+        f_rows, f_def = tree.tree_flatten(s0._replace(attached=()))
+        d_def = tree.tree_structure(jax.eval_shape(
+            lambda k: slot_draws(*draw(k, 0)), keys[0]))
+        chunk = min(n_slots, _MAX_CHUNK,
+                    _DRAW_VMEM // (2 * d_def.num_leaves * ROWS * LANES * 4))
+        # padded points draw from a zero key and are never live
+        keys = jnp.pad(keys, ((0, lanes - n), (0, 0)))
 
-                # energy: active power over the slot's awake time plus the
-                # per-arm C-state charges (blocked wakes re-arm T_L)
-                awake_step = n_wake * wake_cost_us + served / mu
-                energy_step = (active_power_w * awake_step
-                               + ts_arm * e_arm_s + busy_tries * e_arm_l)
+        def slot_tiles(c_t, f_t, i_t, d_t, t):
+            s = tree.tree_unflatten(f_def, f_t)._replace(attached=tuple(i_t))
+            s, _, _ = slot(tree.tree_unflatten(c_def, c_t), s,
+                           tree.tree_unflatten(d_def, d_t), t)
+            return tree.tree_leaves(s._replace(attached=())), list(s.attached)
 
-                S = _SlotStats(
-                    offered=S.offered + offered,
-                    dropped=S.dropped + dropped,
-                    serviced=S.serviced + served,
-                    wakeups=S.wakeups + n_wake,
-                    busy_tries=S.busy_tries + busy_tries,
-                    cycles=S.cycles + cycles,
-                    awake_us=S.awake_us + awake_step,
-                    lat_area=S.lat_area + lat_area,
-                    vac_sum=S.vac_sum + vac_sum,
-                    nv_sum=S.nv_sum + nv_sum,
-                    ts_arms=S.ts_arms + ts_arm,
-                    energy_uj=S.energy_uj + energy_step,
-                )
-                if n_windows > 0:
-                    # the event engine's WindowAccum convention: raw
-                    # [offered, served, lat_area, awake, energy] per window
-                    w = jnp.minimum((now / window_us).astype(jnp.int32),
-                                    n_windows - 1)
-                    win_acc = win_acc.at[w].add(jnp.stack([
-                        offered, served, lat_area, awake_step, energy_step]))
-            with jax.named_scope("gate"):
-                nxt = (sleep_rem, attached, backlog, vac_timer, arr_res,
-                       stall_end, S, win_acc)
-                gated = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(live, new, old), nxt, prev)
-            return gated, None
+        def run_chunk(state, j):
+            # the chunk's draws, (chunk, rows, points), by the same
+            # jax.random calls as the scan's
+            t0 = j * chunk
+            d = jax.vmap(jax.vmap(draw_rows, in_axes=(0, None), out_axes=1),
+                         in_axes=(None, 0))(
+                keys, t0 + jnp.arange(chunk, dtype=jnp.int32))
+            d = d.reshape(chunk, d_def.num_leaves, lanes // LANES, LANES)
+            return advance_slots(slot_tiles, c_tiles, *state, d, t0), None
 
-        z0 = jnp.float32(0.0)
-        init = (sleep0,
-                jnp.full((m_max,), -1, jnp.int32),
-                jnp.zeros(q_max, jnp.float32),
-                jnp.zeros(q_max, jnp.float32),
-                jnp.zeros(q_max, jnp.float32),
-                jnp.float32(-1.0),          # stall_end: no window open
-                _SlotStats(z0, z0, z0, z0, z0, z0, z0, z0, z0, z0, z0, z0),
-                jnp.zeros((max(n_windows, 1), 5), jnp.float32))
-        (_, _, backlog_f, _, _, _, S, win_acc), _ = jax.lax.scan(
-            step, init, jnp.arange(n_slots, dtype=jnp.int32))
-        return S, win_acc, backlog_f.sum()
+        c_tiles = tiles(c_rows)
+        (f, i), _ = jax.lax.scan(
+            run_chunk, (tiles(f_rows), tiles(s0.attached)),
+            jnp.arange(-(-n_slots // chunk), dtype=jnp.int32))
+        return tree.tree_unflatten(f_def, untile(f))._replace(
+            attached=tuple(untile(i)))
 
-    def fixed_sweep(*points):
-        return jax.vmap(one_point)(*points)
+    def fixed_sweep(t_s, t_l, m, nq, lam, seed_lo, seed_hi, duration,
+                    sched_edges, sched_scales):
+        keys, sleep0, lam_q, e_arm_s, e_arm_l = jax.vmap(point)(
+            t_s, t_l, m, nq, lam, seed_lo, seed_hi)
+        mu_a = tuple(lam_q[:, q] * dt for q in range(q_max))
+        c = _Point(m=m.astype(jnp.float32), nq=nq.astype(jnp.float32),
+                   mu_a=mu_a, sd_a=tuple(jnp.sqrt(x) for x in mu_a),
+                   slp_s=t_s * (1.0 + slope), slp_l=t_l * (1.0 + slope),
+                   e_arm_s=e_arm_s, e_arm_l=e_arm_l, duration=duration)
+        zero = jnp.zeros_like(t_s)
+        s0 = _Lanes(
+            sleep_rem=tuple(sleep0[:, i] for i in range(m_max)),
+            attached=(jnp.full(t_s.shape, -1, jnp.int32),) * m_max,
+            backlog=(zero,) * q_max, vac_timer=(zero,) * q_max,
+            arr_res=(zero,) * q_max,
+            stall_end=jnp.full(t_s.shape, -1.0, jnp.float32),
+            S=_SlotStats(*(zero,) * len(_SlotStats._fields)))
+        if fused:
+            s = kernel_slots(c, s0, keys)
+            win_acc = jnp.zeros((t_s.shape[0], 1, 5), jnp.float32)
+        else:
+            s, win_acc = scan_slots(c, s0, keys, lam_q, sched_edges,
+                                    sched_scales)
+        return s.S, win_acc, _fold(s.backlog)
 
     # the function's name is the compiled module's: jit_fixed_sweep
     return jax.jit(fixed_sweep)
@@ -836,8 +1030,8 @@ def _schedule_rows(grid: SweepGrid, cfg: SimRunConfig
 def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
                    slot_us: float = 0.5,
                    stepping: str = "fixed") -> BatchStats:
-    """Simulate every operating point in ``grid`` — one JIT-compiled,
-    vmapped call over the whole batch.
+    """Simulate every operating point in ``grid`` — one JIT-compiled
+    call over the whole batch.
 
     ``cfg`` supplies the environment (duration, mu, per-queue capacity,
     sleep model, wake cost, OS interference / correlated stalls, load
@@ -857,8 +1051,9 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
     call, with counts ``points``, ``scan_steps`` and ``built`` (1 when
     the kernel for this shape was built, and so compiled, by this
     call); inside it ``batched.scan`` (the device scan, until its
-    outputs are ready) and ``batched.fetch`` (device-to-host copies and
-    ``BatchStats``).  Input staging is the sweep's self time.
+    outputs are ready; count ``fused``, 1 when the Pallas kernel ran the
+    slots, 0 for a ``lax.scan``) and ``batched.fetch`` (device-to-host
+    copies and ``BatchStats``).  Input staging is the sweep's self time.
     """
     cfg = cfg or SimRunConfig()
     validate_batched_config(cfg)
@@ -871,12 +1066,15 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
     n_slots_true = max(int(math.ceil(cfg.duration_us / slot_us)), 1)
     with tracing.span("batched.sweep", points=n, built=0) as sp:
         n_seg, sched_edges, sched_scales = _schedule_rows(grid, cfg)
+        fused = False
         if stepping == "adaptive":
             from .batched_adaptive import adaptive_kernel
             fn, scan_len = adaptive_kernel(grid, cfg, float(slot_us), n_seg,
                                            n_windows)
         else:
             scan_len = bucket_steps(n_slots_true)
+            n_win = bucket_steps(n_windows, base=8) if n_windows else 0
+            fused = _fused(n_seg, n_win)
             sm = cfg.sleep_model
             fn = _compiled_sweep(
                 scan_len, float(slot_us), int(grid.m.max()),
@@ -889,8 +1087,7 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
                  float(cfg.interference_mean_us),
                  float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
                 cfg.energy_model.params(),
-                n_seg, bucket_steps(n_windows, base=8) if n_windows else 0,
-                float(cfg.window_us))
+                n_seg, n_win, float(cfg.window_us), fused)
         sp.counts["scan_steps"] = scan_len
         seed64 = np.asarray(grid.seed, dtype=np.uint64)
         args = (jnp.asarray(grid.t_s_us, jnp.float32),
@@ -908,7 +1105,7 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
                 jnp.asarray(np.full(n, cfg.duration_us, np.float32)),
                 jnp.asarray(sched_edges, jnp.float32),
                 jnp.asarray(sched_scales, jnp.float32))
-        with tracing.span("batched.scan"):
+        with tracing.span("batched.scan", fused=int(fused)):
             out, win, back_f, *simt = jax.block_until_ready(fn(*args))
         with tracing.span("batched.fetch"):
             vals = {k: np.asarray(v, dtype=np.float64)

@@ -2,6 +2,7 @@
 cross-validation, and one-call sweep scale (the acceptance criteria of
 the batched-backend refactor)."""
 
+import dataclasses
 import json
 from pathlib import Path
 
@@ -321,3 +322,42 @@ def test_fixed_kernel_counters_match_golden_bit_for_bit():
         assert len(want) == len(grid) == 108
         np.testing.assert_array_equal(getattr(bs, name), want,
                                       err_msg=name)
+
+
+# builds the Pallas kernel takes, each against the lax.scan driver: the
+# thread and queue loops at one, some and four, the sleep tail,
+# interference and stalls, and a duration ending inside the bucketed
+# padding of a scan longer than one chunk
+KERNEL_CASES = {
+    "m1_q1": (dict(m=[1], n_queues=[1]), {}, 200.0),
+    "m3_q2": (dict(m=[1, 3], n_queues=[1, 2]), {}, 200.0),
+    "m4_q4": (dict(m=[1, 3, 4], n_queues=[1, 2, 4]), {}, 200.0),
+    "noisy": (dict(m=[2, 3], n_queues=[1, 2]),
+              dict(INTERFERENCE_ENV, stall_rate_per_us=2e-3,
+                   sleep_model=dataclasses.replace(
+                       HR_SLEEP_MODEL, tail_prob=0.05, tail_mean_us=30.0)),
+              200.0),
+    "padded_end": (dict(m=[4], n_queues=[4]), {}, 153.3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernel_counters_equal_scan_driver_bit_for_bit(monkeypatch, case):
+    from repro.runtime import batched
+
+    axes, env, duration = KERNEL_CASES[case]
+    cfg = SimRunConfig(duration_us=duration, queue_capacity=48, **env)
+    grid = SweepGrid.product(
+        t_s_us=[4.0, 20.0], t_l_us=[150.0],
+        rate_mpps=np.array([0.1, 0.6, 0.95]) * cfg.service_rate_mpps,
+        seeds=(7, 2**33 + 1), **axes)
+    scan = simulate_batch(grid, cfg, slot_us=0.5)
+    monkeypatch.setattr(batched, "_fused",
+                        lambda n_seg, n_windows: not n_seg and not n_windows)
+    kernel = simulate_batch(grid, cfg, slot_us=0.5)
+    if case == "padded_end":
+        assert scan.scan_len > duration / 0.5
+    for name in batched._SlotStats._fields + ("final_backlog",):
+        np.testing.assert_array_equal(getattr(kernel, name),
+                                      getattr(scan, name), err_msg=name)
+    assert scan.wakeups.min() > 0 and scan.dropped.max() > 0

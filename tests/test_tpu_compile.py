@@ -9,9 +9,11 @@ the worker given this file loads the TPU library.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -94,6 +96,43 @@ def test_fixed_sweep_kernel_compiles(capture_kernel, on_chip):
         batched, "_compiled_sweep", batched._build_sweep,
         lambda: simulate_batch(grid, cfg, slot_us=slot_us))
     _compile_gather_free(fn, on_chip(args))
+
+
+def _lowload():
+    cfg = SimRunConfig(duration_us=50_000.0)
+    grid = SweepGrid.product(
+        t_s_us=np.linspace(20.0, 80.0, 7), t_l_us=[250.0, 500.0, 900.0],
+        m=[2, 3], rate_mpps=np.array([0.05, 0.1, 0.15, 0.2, 0.25])
+        * cfg.service_rate_mpps, seeds=(0, 1))
+    assert len(grid) == 420
+    return grid, cfg, 0.5
+
+
+def _computation(text, name):
+    start = text.index(f"\n{name} ")
+    return text[start:text.index("\n}", start)]
+
+
+@pytest.mark.parametrize("cell", ["lattice", "lowload"])
+def test_fixed_scan_compiles_calibration_sweeps(capture_kernel, on_chip,
+                                                monkeypatch, cell):
+    """The calibration cells' sweeps (2,016 points up to four threads,
+    420 points up to three) with their slots in the Pallas kernel, as a
+    TPU runs them: they fit the chip's VMEM and HBM, hold no gather, and
+    call the kernel inside the loop over chunks."""
+    from repro.kernels.fixed_scan import kernel
+    from repro.runtime import batched
+
+    monkeypatch.setattr(batched, "_fused", lambda n_seg, n_windows: True)
+    monkeypatch.setattr(kernel, "resolve_interpret", lambda interpret: False)
+    grid, cfg, slot_us = _lattice() if cell == "lattice" else _lowload()
+    fn, args = capture_kernel(
+        batched, "_compiled_sweep", batched._build_sweep,
+        lambda: simulate_batch(grid, cfg, slot_us=slot_us))
+    text = _compile_gather_free(fn, on_chip(args))
+    loops = re.findall(r"body=(%[\w.\-]+)", text)
+    assert [b for b in loops
+            if "tpu_custom_call" in _computation(text, b)], loops
 
 
 def test_adaptive_sweep_kernel_compiles(capture_kernel, on_chip):
@@ -204,6 +243,6 @@ def test_every_kernel_is_compiled_here():
     kernels = [d for d in os.listdir(root)
                if os.path.isfile(os.path.join(root, d, "kernel.py"))]
     tests = [n for n in globals() if n.startswith("test_")]
-    assert len(kernels) == 3
+    assert len(kernels) == 4
     for name in kernels:
         assert any(t.startswith(f"test_{name}_compiles") for t in tests), name

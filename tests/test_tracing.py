@@ -108,6 +108,23 @@ def test_one_sweep_one_scan_one_fetch_and_built(stepping):
     assert built == [1, 0]
 
 
+@pytest.mark.parametrize("fused", [False, True])
+def test_scan_span_says_which_driver_ran_the_slots(monkeypatch, fused):
+    """``fused`` on ``batched.scan`` reads 0 when a ``lax.scan`` ran the
+    slots (the CPU's driver) and 1 when the Pallas kernel did (forced
+    here, so it runs in interpret mode)."""
+    from repro.runtime import batched
+
+    if fused:
+        monkeypatch.setattr(batched, "_fused",
+                            lambda n_seg, n_windows: True)
+    cfg = SimRunConfig(duration_us=100.0)
+    grid = SweepGrid.product(t_s_us=[10.0], t_l_us=[200.0], m=[2],
+                             rate_mpps=[3.0], seeds=(0, 1))
+    simulate_batch(grid, cfg, slot_us=0.5)
+    assert _last("batched.scan").counts["fused"] == int(fused)
+
+
 def test_fleet_sweep_tail_and_rollup_spans():
     from repro.runtime import FleetConfig, FleetGrid, simulate_fleet
 
