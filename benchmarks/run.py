@@ -59,7 +59,6 @@ def main() -> None:
     from benchmarks.policy_matrix import matrix_policies_workloads
     from benchmarks.power import power
     from benchmarks.rss_skew import matrix_rss_skew
-    from benchmarks.stepping import stepping_compare
     from benchmarks.sweep_frontier import sweep_frontier
     from benchmarks.paper_tables import (
         fig2_sleep_cpu,
@@ -93,7 +92,7 @@ def main() -> None:
         table3_nanosleep_loss, fig11_adaptation, fig12_dpdk_compare,
         matrix_policies_workloads, matrix_rss_skew, sweep_frontier,
         cpu_sharing, adaptation, fig15_applications, fleet_bench,
-        stepping_compare, power, compile_caches,
+        power, compile_caches,
     ]
     print("name,us_per_call,derived")
     failures = 0

@@ -11,9 +11,8 @@ CPU fallback: without a TPU the script prints no result and exits 2.
   calibration  ``build_operating_table`` on the paper's l3fwd deployment
                (29.76 Mpps service rate, 1,024-descriptor ring) over the
                full ``sweep_frontier`` lattice, 2,016 points of 50 ms at
-               0.5 us slots, once per scan kernel (fixed, adaptive); the
-               event engine spot-checks three table points, and a few
-               lattice points of both kernels are held to the quiet
+               0.5 us slots; the event engine spot-checks three table
+               points, and a few lattice points are held to the quiet
                event-engine parity bands of ``tests/test_batched_engine``.
   fleet        ``simulate_fleet`` on the H=16 noisy cluster over the hedge
                ladder, then the 1000-host x 8-point sweep in one call.
@@ -142,7 +141,7 @@ def _event_reference(point: dict, cfg):
 
 
 def check_parity(bs, cfg, points) -> int:
-    """Hold lattice points of one kernel's sweep to the event engine."""
+    """Hold lattice points of the sweep to the event engine."""
     g = bs.grid
     for p in points:
         idx = np.flatnonzero(
@@ -154,7 +153,7 @@ def check_parity(bs, cfg, points) -> int:
         rs = _event_reference(p, cfg)
         lat_b, lat_e = float(bs.mean_latency_us[i]), rs.mean_sojourn_us
         cpu_b, cpu_e = float(bs.cpu_fraction[i]), rs.cpu_fraction
-        tag = f"{bs.stepping} kernel at {p}"
+        tag = f"sweep at {p}"
         _require(abs(lat_b - lat_e) <= max(LAT_ABS_US, LAT_REL * lat_e),
                  f"{tag}: mean latency {lat_b:.3f}us vs event {lat_e:.3f}us")
         _require(abs(cpu_b - cpu_e) <= CPU_ABS + CPU_REL * cpu_e,
@@ -175,11 +174,17 @@ def parity_points(lat: dict, picks=PARITY_POINTS) -> list[dict]:
             for i, tl, m, rho, seed in picks]
 
 
-def calibration(lat: dict, stepping: str, points: list[dict],
-                target_us: float = 15.0, spot_check: int = 3) -> dict:
-    """One lattice sweep with one scan kernel, distilled into an
-    operating table (event-engine spot checks included), then parity."""
-    from repro.runtime import SweepGrid, build_operating_table, simulate_batch
+def calibration(lat: dict, points: list[dict], target_us: float = 15.0,
+                spot_check: int = 3) -> dict:
+    """One lattice sweep, distilled into an operating table (event-engine
+    spot checks included), then parity.  ``fused`` says which driver ran
+    the slots: 1 the Pallas kernel, 0 the ``lax.scan``."""
+    from repro.runtime import (
+        SweepGrid,
+        build_operating_table,
+        simulate_batch,
+        tracing,
+    )
 
     cfg = l3fwd_env(lat["duration_us"])
     grid = SweepGrid.product(
@@ -187,22 +192,23 @@ def calibration(lat: dict, stepping: str, points: list[dict],
         n_queues=(cfg.n_queues,),
         rate_mpps=np.asarray(lat["rhos"]) * cfg.service_rate_mpps,
         seeds=lat["seeds"])
-    bs = simulate_batch(grid, cfg, slot_us=lat["slot_us"], stepping=stepping)
+    bs = simulate_batch(grid, cfg, slot_us=lat["slot_us"])
+    fused = [r for r in tracing.records()
+             if r.name == "batched.scan"][-1].counts["fused"]
     table = build_operating_table(
         rhos=lat["rhos"], target_mean_latency_us=target_us,
         t_s_grid=lat["t_s_grid"], t_l_grid=lat["t_l_grid"],
         m_grid=lat["m_grid"], cfg=cfg, seeds=lat["seeds"],
-        slot_us=lat["slot_us"], spot_check=spot_check, sweep=bs,
-        stepping=stepping)
+        slot_us=lat["slot_us"], spot_check=spot_check, sweep=bs)
     for f in ("mean_latency_us", "cpu_fraction", "loss_fraction"):
         _require(bool(np.all(np.isfinite(getattr(bs, f)))),
-                 f"{stepping} sweep: non-finite {f}")
+                 f"sweep: non-finite {f}")
     _require(len(table.points) == len(lat["rhos"]), "table has a missing rung")
     missed = [p.rho for p in table.points if not p.meets_target]
-    _require(not missed, f"{stepping} table misses the {target_us:g}us "
-                         f"target at rho {missed}")
+    _require(not missed, f"table misses the {target_us:g}us target at rho "
+                         f"{missed}")
     n_parity = check_parity(bs, cfg, points)
-    return {"stepping": stepping, "points": len(grid),
+    return {"fused": fused, "points": len(grid),
             "slots_per_point": int(round(lat["duration_us"] / lat["slot_us"])),
             "scan_len": int(bs.scan_len), "parity_points": n_parity,
             "table": ";".join(f"{p.rho:g}:ts{p.t_s_us:.1f}/tl{p.t_l_us:g}"
@@ -385,9 +391,7 @@ def main(argv=None) -> int:
 
         lat = lattice(quick=False)
         points = parity_points(lat)
-        for stepping in ("fixed", "adaptive"):
-            run_phase(f"calibration_{stepping}", calibration, clock, lat,
-                      stepping, points)
+        run_phase("calibration", calibration, clock, lat, points)
         run_phase("fleet", fleet, clock, 16, 60_000.0, 0.5, False)
         run_phase("serving", serving, clock, SERVE_ARGV)
     print(json.dumps({"ok": True, "device": {

@@ -17,11 +17,9 @@ PR 3/4 kept them in sync with a hand-maintained drift guard
     code says otherwise).
 
 File discovery is structural, not hard-wired: any scanned file defining
-``class SimRunConfig`` is paired with every sibling engine module
-(``batched.py`` and, when present, the event-jump kernel
-``batched_adaptive.py``) in the same directory, so fixture mini-repos
-exercise the pass the same way ``src/repro/runtime`` does — each engine
-file must independently read-or-declare every config field.
+``class SimRunConfig`` is paired with the sibling engine module
+``batched.py`` in the same directory, so fixture mini-repos exercise the
+pass the same way ``src/repro/runtime`` does.
 """
 
 from __future__ import annotations
@@ -33,7 +31,7 @@ from .core import ERROR, AnalysisPass, Finding, SourceFile, register
 __all__ = ["EngineParityPass"]
 
 CONFIG_CLASS = "SimRunConfig"
-ENGINE_BASENAMES = ("batched.py", "batched_adaptive.py")
+ENGINE_BASENAMES = ("batched.py",)
 # attribute bases that denote "the config object" in the engine module
 CONFIG_BASES = ("cfg", "config")
 

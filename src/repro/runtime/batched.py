@@ -92,10 +92,9 @@ mean sojourn within max(4.5us, 22%), CPU within 0.025 + 6%, and loss
 fraction within 0.03 absolute — pinned for 16 random noisy-host
 configurations in the same test module.
 
-Stepping modes.  ``simulate_batch(..., stepping="fixed")`` (the
-default) is the kernel described above; every quantization caveat in
-this docstring is a statement about its *per-slot* update at
-``slot_us`` resolution, with two scan-shape refinements:
+Scan shape.  Every quantization caveat in this docstring is a
+statement about the per-slot update at ``slot_us`` resolution, with two
+refinements of the scan's shape:
 
   - the scan length is the slot count rounded *up* a geometric ladder
     (``bucket_steps``) and the run duration is a traced input, so
@@ -105,20 +104,12 @@ this docstring is a statement about its *per-slot* update at
   - wake-timer / busy-period / stall-window quantization is always
     ``slot_us`` regardless of the padded scan length.
 
-Its slot body is written once, lanes-minor (the points are the lanes),
+The slot body is written once, lanes-minor (the points are the lanes),
 and has two drivers chosen from the backend and the static build: on a
 TPU a stationary build without windows runs its slots K at a time in
 one Pallas kernel (``repro.kernels.fixed_scan``), with the state kept
 on the core; otherwise a ``lax.scan`` runs them one at a time.  Both
 draw from the same ``jax.random`` calls and give the same bits.
-
-``stepping="adaptive"`` dispatches to the event-jump kernel in
-``batched_adaptive.py``: variable ``dt`` per scan step (next wake /
-drain-out / fill / schedule-segment / window / stall-start boundary),
-closed-form multi-slot aggregates, scan length O(#events) instead of
-O(duration/slot_us) — load-proportional simulation, ~10x+ fewer steps
-at low load.  Its approximation surface (what stays exact, what moves)
-is documented in that module; both modes hold the parity bands above.
 """
 
 from __future__ import annotations
@@ -394,14 +385,11 @@ class BatchStats:
     # (len(grid), n_windows, 5) — [offered, served, lat_area, awake,
     # energy] — the same raw sums the event engine's WindowAccum keeps
     win: np.ndarray = field(default_factory=lambda: np.empty(0))
-    # stepping diagnostics: which kernel produced this batch, its
-    # compiled scan length, and per-point live-step / forced-step
-    # counts, exact simulated time, and end-of-run total backlog (the
-    # missing term of the offered = served + dropped + backlog law)
-    stepping: str = "fixed"
+    # the compiled scan length, and per-point live slots, simulated
+    # time, and end-of-run total backlog (the missing term of the
+    # offered = served + dropped + backlog law)
     scan_len: int = 0
     n_steps: np.ndarray = field(default_factory=lambda: np.empty(0))
-    forced_steps: np.ndarray = field(default_factory=lambda: np.empty(0))
     sim_time_us: np.ndarray = field(default_factory=lambda: np.empty(0))
     final_backlog: np.ndarray = field(default_factory=lambda: np.empty(0))
 
@@ -1028,8 +1016,7 @@ def _schedule_rows(grid: SweepGrid, cfg: SimRunConfig
 
 
 def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
-                   slot_us: float = 0.5,
-                   stepping: str = "fixed") -> BatchStats:
+                   slot_us: float = 0.5) -> BatchStats:
     """Simulate every operating point in ``grid`` — one JIT-compiled
     call over the whole batch.
 
@@ -1041,12 +1028,6 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
     adaptation series (``BatchStats.windows(i)``).  Binned time series
     remain event-engine-only and raise (``validate_batched_config``).
 
-    ``stepping`` selects the kernel: ``"fixed"`` (default) scans
-    uniform ``slot_us`` slots; ``"adaptive"`` scans event-jump
-    macro-slots (see ``batched_adaptive``) — same statistics, same
-    parity bands, scan length proportional to event count instead of
-    simulated time.
-
     Spans (``repro.runtime.tracing``): ``batched.sweep`` around the
     call, with counts ``points``, ``scan_steps`` and ``built`` (1 when
     the kernel for this shape was built, and so compiled, by this
@@ -1057,37 +1038,28 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
     """
     cfg = cfg or SimRunConfig()
     validate_batched_config(cfg)
-    if stepping not in ("fixed", "adaptive"):
-        raise ValueError(
-            f"stepping must be 'fixed' or 'adaptive', got {stepping!r}")
     n = len(grid)
     n_windows = (int(math.ceil(cfg.duration_us / cfg.window_us))
                  if cfg.window_us > 0 else 0)
     n_slots_true = max(int(math.ceil(cfg.duration_us / slot_us)), 1)
     with tracing.span("batched.sweep", points=n, built=0) as sp:
         n_seg, sched_edges, sched_scales = _schedule_rows(grid, cfg)
-        fused = False
-        if stepping == "adaptive":
-            from .batched_adaptive import adaptive_kernel
-            fn, scan_len = adaptive_kernel(grid, cfg, float(slot_us), n_seg,
-                                           n_windows)
-        else:
-            scan_len = bucket_steps(n_slots_true)
-            n_win = bucket_steps(n_windows, base=8) if n_windows else 0
-            fused = _fused(n_seg, n_win)
-            sm = cfg.sleep_model
-            fn = _compiled_sweep(
-                scan_len, float(slot_us), int(grid.m.max()),
-                int(grid.n_queues.max()),
-                float(cfg.service_rate_mpps), float(cfg.queue_capacity),
-                float(cfg.wake_cost_us),
-                (float(sm.base_us), float(sm.slope), float(sm.sigma_us),
-                 float(sm.tail_prob), float(sm.tail_mean_us)),
-                (float(cfg.interference_prob),
-                 float(cfg.interference_mean_us),
-                 float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
-                cfg.energy_model.params(),
-                n_seg, n_win, float(cfg.window_us), fused)
+        scan_len = bucket_steps(n_slots_true)
+        n_win = bucket_steps(n_windows, base=8) if n_windows else 0
+        fused = _fused(n_seg, n_win)
+        sm = cfg.sleep_model
+        fn = _compiled_sweep(
+            scan_len, float(slot_us), int(grid.m.max()),
+            int(grid.n_queues.max()),
+            float(cfg.service_rate_mpps), float(cfg.queue_capacity),
+            float(cfg.wake_cost_us),
+            (float(sm.base_us), float(sm.slope), float(sm.sigma_us),
+             float(sm.tail_prob), float(sm.tail_mean_us)),
+            (float(cfg.interference_prob),
+             float(cfg.interference_mean_us),
+             float(cfg.stall_rate_per_us), float(cfg.stall_mean_us)),
+            cfg.energy_model.params(),
+            n_seg, n_win, float(cfg.window_us), fused)
         sp.counts["scan_steps"] = scan_len
         seed64 = np.asarray(grid.seed, dtype=np.uint64)
         args = (jnp.asarray(grid.t_s_us, jnp.float32),
@@ -1106,23 +1078,15 @@ def simulate_batch(grid: SweepGrid, cfg: SimRunConfig | None = None, *,
                 jnp.asarray(sched_edges, jnp.float32),
                 jnp.asarray(sched_scales, jnp.float32))
         with tracing.span("batched.scan", fused=int(fused)):
-            out, win, back_f, *simt = jax.block_until_ready(fn(*args))
+            out, win, back_f = jax.block_until_ready(fn(*args))
         with tracing.span("batched.fetch"):
             vals = {k: np.asarray(v, dtype=np.float64)
                     for k, v in out._asdict().items()}
-            if stepping == "adaptive":
-                n_steps = vals.pop("n_steps")
-                forced_steps = vals.pop("forced_steps")
-                sim_time_us = np.asarray(simt[0], dtype=np.float64)
-            else:
-                n_steps = np.full(n, float(n_slots_true))
-                forced_steps = np.zeros(n)
-                # fixed slots overshoot duration by the ceil slot
-                sim_time_us = np.full(n, n_slots_true * slot_us)
             return BatchStats(
                 grid=grid, cfg=cfg, slot_us=float(slot_us),
                 win=(np.asarray(win, dtype=np.float64)[:, :n_windows]
                      if n_windows else np.empty(0)),
-                stepping=stepping, scan_len=scan_len, n_steps=n_steps,
-                forced_steps=forced_steps, sim_time_us=sim_time_us,
+                scan_len=scan_len, n_steps=np.full(n, float(n_slots_true)),
+                # the slots overshoot the duration by the ceil slot
+                sim_time_us=np.full(n, n_slots_true * slot_us),
                 final_backlog=np.asarray(back_f, dtype=np.float64), **vals)

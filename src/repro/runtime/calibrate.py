@@ -283,7 +283,6 @@ def build_operating_table(
     sweep=None,
     schedule_check=None,
     fleet=None,
-    stepping: str = "fixed",
     objective: str = "cpu",
 ) -> OperatingTable:
     """Sweep (t_s x t_l x m x rho x seed) through the batched engine and
@@ -342,15 +341,6 @@ def build_operating_table(
     ``cfg.schedule`` is rejected (a moving rate would mislabel every
     rho rung of the table).
 
-    ``stepping`` selects the batched engine's scan kernel for the
-    lattice sweep (``"adaptive"`` = event-jump macro-slots — the fast
-    path for calibration lattices, whose rungs live at low-to-moderate
-    rho where the speedup is largest).  The event-engine spot-checks
-    are untouched either way: they remain the exact validator, so a
-    stepping-mode regression fails calibration instead of silently
-    shifting the table.  A precomputed ``sweep`` must have been run
-    with the same ``stepping``.
-
     The returned table records ``cfg`` as its ``environment``.
     """
     with tracing.span("calibrate.table"):
@@ -383,8 +373,7 @@ def build_operating_table(
                                  n_queues=(cfg.n_queues,),
                                  rate_mpps=rhos * mu, seeds=seeds)
         if sweep is None:
-            bs = simulate_batch(grid, cfg, slot_us=slot_us,
-                                stepping=stepping)
+            bs = simulate_batch(grid, cfg, slot_us=slot_us)
         else:
             # the precomputed sweep must be THIS lattice simulated in THIS
             # environment — matching shape alone would let metrics from one
@@ -394,12 +383,11 @@ def build_operating_table(
                 for f in ("t_s_us", "t_l_us", "m", "n_queues", "rate_mpps",
                           "seed")))
             if not (same_axes and sweep.cfg == cfg
-                    and sweep.slot_us == float(slot_us)
-                    and sweep.stepping == stepping):
+                    and sweep.slot_us == float(slot_us)):
                 raise ValueError(
                     "precomputed sweep does not match the requested lattice/"
-                    "environment (grid axes, SimRunConfig, slot_us and "
-                    "stepping must all be identical)")
+                    "environment (grid axes, SimRunConfig and slot_us must "
+                    "all be identical)")
             bs = sweep
 
         # seed-averaged metrics on the (ts, tl, m, nq, rho, seed) lattice

@@ -31,7 +31,7 @@ runs the single-host slot dynamics under a second ``vmap``
      cancellation, so fleet CPU counts the duplicates' work).  Issued
      duplicates are ``hedge_dup``, not ``offered``.
 
-**Sojourn accounting.**  The fixed kernel measures each host's packet
+**Sojourn accounting.**  The kernel measures each host's packet
 sojourns.  Per (host, queue) it keeps a ring of ``RING`` samples of the
 cumulative admissions (all of them, and the balancer's originals apart),
 one at the end of every cohort of ``cohort_slots`` slots (``COHORT_US``
@@ -97,12 +97,6 @@ from .batched import (
     bucket_steps,
     energy_arm_cost,
     validate_batched_config,
-)
-from .batched_adaptive import (
-    _FILL_SLACK_PKTS,
-    _RATE_EPS,
-    _WAKE_EPS_US,
-    estimate_adaptive_steps,
 )
 from . import tracing
 from .simcore import _LINK_UTIL_CLAMP, FleetConfig, SimRunConfig
@@ -247,7 +241,7 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                        lb_softness_pkts: float, stale_every_slots: int,
                        far_count: int, near_cost_us: float,
                        far_cost_us: float, link_rate_mpps: float,
-                       n_shards: int, stepping: str = "fixed"):
+                       n_shards: int):
     """Build + jit the (point x host) fleet kernel for one static shape.
 
     The per-host slot body is the single-host kernel's, line for line
@@ -257,25 +251,12 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
     ``n_shards > 1`` wraps the point-axis vmap in ``shard_map`` over the
     first ``n_shards`` local devices.
 
-    ``stepping="fixed"`` scans ``n_slots`` constant ``slot_us`` slots
+    The kernel scans ``n_slots`` constant ``slot_us`` slots
     (``duration`` is traced and steps past it are carry-held no-ops, so
-    one bucketed scan length serves nearby durations bit-identically);
-    ``stepping="adaptive"`` treats ``n_slots`` as the event-jump step
-    *budget*: every scan step advances one shared variable ``dt`` per
-    point — the min over all hosts' wake / drain-out / fill boundaries,
-    the schedule segment end, each host's next correlated-stall start,
-    and the LB stale-snapshot refresh lattice (the refresh is a jump
-    boundary, so the stale signal updates exactly on its
-    ``lb_stale_us`` grid) — and the per-host body applies the
-    closed-form multi-slot aggregates of ``batched_adaptive``.  The
-    cross-host stages (LB split, bottleneck-link M/M/1 wait at the
-    macro-slot's admission rate) consume the same ``dt``.  The adaptive
-    build keeps no sojourn ring: it neither hedges nor measures a tail.
-
-    The fixed kernel scans cohorts of ``cohort_slots(slot_us)`` slots:
-    each scan step runs that many slots one after another, then closes
-    the cohort (ring sample, served-mass histogram); see the module
-    docstring.
+    one bucketed scan length serves nearby durations bit-identically),
+    in cohorts of ``cohort_slots(slot_us)`` slots: each scan step runs
+    that many slots one after another, then closes the cohort (ring
+    sample, served-mass histogram); see the module docstring.
     """
     base_us, slope, sigma_us, tail_prob, tail_mean_us = sleep_params
     intf_prob, intf_mean_us, stall_rate, stall_mean_us = interference_params
@@ -314,16 +295,9 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
                 jax.random.fold_in(jax.random.PRNGKey(0), lo), seed_hi)
             k, k0 = jax.random.split(k)
             s0 = jax.random.uniform(k0, (m_max,)) * t_s
-            # the extra split exists only in adaptive builds, so the
-            # fixed kernel's per-host streams stay bit-identical
-            if stepping == "adaptive" and stall_rate > 0.0:
-                k, kst = jax.random.split(k)
-                ns0 = jax.random.exponential(kst, ()) / stall_rate
-            else:
-                ns0 = jnp.float32(jnp.inf)
-            return k, s0, ns0
+            return k, s0
 
-        keys, sleep0_h, next0_h = jax.vmap(init_host)(host_lo)
+        keys, sleep0_h = jax.vmap(init_host)(host_lo)
         sleep0_h = jnp.where(tmask[None, :],
                              jnp.maximum(sleep0_h, dt), jnp.inf)
 
@@ -656,335 +630,24 @@ def _build_fleet_sweep(n_slots: int, slot_us: float, m_max: int,
 
         zh = jnp.zeros((n_hosts,), jnp.float32)
         zq = jnp.zeros((n_hosts, q_max), jnp.float32)
-        if stepping == "fixed":
-            init = (sleep0_h,
-                    jnp.full((n_hosts, m_max), -1, jnp.int32),
-                    zq, zq, zq,
-                    jnp.full((n_hosts,), -1.0, jnp.float32),
-                    zh,                          # stale LB snapshot
-                    _FleetSlotStats(*([zh] * len(_FleetSlotStats._fields))),
-                    _Cohort(zq, zq, zq, zq, jnp.full_like(zq, jnp.inf),
-                            jnp.full_like(zq, -jnp.inf)))
-            ring0 = jnp.zeros((n_hosts, q_max, RING), jnp.float32)
-            hist0 = jnp.zeros((n_hosts, HIST_BINS + 2), jnp.float32)
-            n_cohorts = -(-n_slots // g)
-            (slots, _, _, hist), _ = jax.lax.scan(
-                cohort_step, (init, ring0, ring0, hist0),
-                jnp.arange(n_cohorts, dtype=jnp.int32))
-            n_live = jnp.minimum(jnp.ceil(duration / dt),
-                                 jnp.float32(n_cohorts * g))
-            return ((slots[-2], hist), n_live * dt, n_live,
-                    jnp.zeros_like(duration))
-
-        # ---- adaptive (event-jump): one shared variable dt per point —
-        # the per-host boundary structure reduced with a fleet-wide min,
-        # so all hosts advance in lock-step through the LB coupling
-        floor_us = slot_us
-        stale_us = float(stale_every_slots) * slot_us
-
-        def fleet_step_a(carry, t):
-            prev = carry
-            (a_sleep, a_att, a_back, a_vac, a_res, a_stall, a_next,
-             lb_snap, next_ref, rem_t, nst, fst, SA) = carry
-            now = duration - rem_t
-            live = rem_t > 0.0
-
-            with jax.named_scope("balancer"):
-                if n_seg > 0:
-                    si = jnp.clip(
-                        jnp.searchsorted(sched_edges, now, side="right") - 1,
-                        0, n_seg - 1)
-                    scale_t = sched_scales[si]
-                    nxt_si = jnp.clip(si + 1, 0, n_seg - 1)
-                    seg_dt = jnp.where(si + 1 < n_seg,
-                                       sched_edges[nxt_si] - now, jnp.inf)
-                else:
-                    scale_t = jnp.float32(1.0)
-                    seg_dt = jnp.float32(jnp.inf)
-
-                # LB stale refresh is a jump boundary: the snapshot updates
-                # exactly on its lb_stale_us lattice (missed lattice points
-                # after a forced jump are skipped, matching the fixed
-                # kernel's modulo refresh)
-                if lb_code == 2:
-                    fire_ref = now + _WAKE_EPS_US >= next_ref
-                    lb_snap = jnp.where(fire_ref, a_back.sum(axis=1), lb_snap)
-                    next_ref = jnp.where(
-                        fire_ref,
-                        (jnp.floor(now / stale_us + _WAKE_EPS_US) + 1.0)
-                        * stale_us,
-                        next_ref)
-                    shares = jax.nn.softmax(-lb_snap / lb_softness_pkts)
-                    ref_dt = next_ref - now
-                else:
-                    shares = w_static
-                    ref_dt = jnp.float32(jnp.inf)
-                lam_h = lam * shares                       # (H,) mpps
-                lam_hq = (lam_h * scale_t)[:, None] \
-                    * jnp.where(qmask, 1.0 / nq, 0.0)[None, :]
-
-            # ---- the jump: nearest boundary across the whole fleet
-            with jax.named_scope("jump"):
-                sleeping_h = tmask[None, :] & (a_att < 0)
-                occ_h = (jax.nn.one_hot(a_att, q_max).sum(axis=1) > 0)
-                wake_dt = jnp.min(jnp.where(
-                    sleeping_h, jnp.maximum(a_sleep, 0.0), jnp.inf))
-                net_out = jnp.where(occ_h, mu - lam_hq, 0.0)
-                drain_hq = jnp.where(
-                    occ_h & (net_out > _RATE_EPS),
-                    jnp.maximum(a_back, 0.0)
-                    / jnp.maximum(net_out, _RATE_EPS), jnp.inf)
-                drain_dt = jnp.min(drain_hq)
-                net_in = lam_hq - jnp.where(occ_h, mu, 0.0)
-                fill_dt = jnp.min(jnp.where(
-                    qmask[None, :] & (net_in > _RATE_EPS)
-                    & (a_back < capacity - _FILL_SLACK_PKTS),
-                    (capacity - a_back) / jnp.maximum(net_in, _RATE_EPS),
-                    jnp.inf))
-                stall_dt = jnp.min(a_next) - now
-                dt_b = jnp.minimum(
-                    jnp.minimum(jnp.minimum(wake_dt, drain_dt),
-                                jnp.minimum(fill_dt, seg_dt)),
-                    jnp.minimum(jnp.minimum(ref_dt, stall_dt), rem_t))
-                # completion guard — tail-reserve pacing only, see
-                # batched_adaptive (same scheme, n_slots is the budget here)
-                steps_left = jnp.float32(n_slots) - t.astype(jnp.float32)
-                in_tail = steps_left <= jnp.float32(max(n_slots // 8, 2))
-                pace = jnp.where(in_tail, rem_t / steps_left, 0.0)
-                # floor respects wakes and drain-outs fleet-wide (see
-                # batched_adaptive: stepping past either stretches busy
-                # periods / coalesces claims and biases the wake rate down
-                # through the T_L parking feedback)
-                floor_eff = jnp.minimum(
-                    floor_us,
-                    jnp.maximum(jnp.minimum(wake_dt, drain_dt),
-                                _WAKE_EPS_US))
-                dtv = jnp.minimum(
-                    jnp.maximum(dt_b, jnp.maximum(floor_eff, pace)), rem_t)
-                forced = (dtv > jnp.maximum(dt_b, floor_us) + _WAKE_EPS_US) \
-                    & live
-                t_new = now + dtv
-
-            def host_step_a(key_h, lam_q, sleep_rem, attached, backlog,
-                            vac_timer, arr_res, stall_end, next_stall):
-                """One host, one macro-slot — the closed-form aggregates
-                of ``batched_adaptive`` at the shared fleet ``dtv``."""
-                with jax.named_scope("rng"):
-                    kt_step = jax.random.fold_in(key_h, t)
-                    if tail_prob > 0.0:
-                        kt_step, kp, ku = jax.random.split(kt_step, 3)
-                    if intf_prob > 0.0:
-                        kt_step, kip, kie = jax.random.split(kt_step, 3)
-                    if stall_rate > 0.0:
-                        kt_step, kse, ksg, ksu = jax.random.split(kt_step, 4)
-                    zs = jax.random.normal(kt_step, (q_max + m_max,))
-
-                with jax.named_scope("arrivals"):
-                    sleeping = tmask & (attached < 0)
-                    occ = (jax.nn.one_hot(attached, q_max).sum(axis=0) > 0)
-
-                    # drain-boundary steps are deterministic per queue: a
-                    # noisy draw there is one-sided (positive residual
-                    # extends the busy period, negative cannot shorten it)
-                    # — see batched_adaptive for the full argument
-                    net_out_l = jnp.where(occ, mu - lam_q, 0.0)
-                    drain_ql = jnp.where(
-                        occ & (net_out_l > _RATE_EPS),
-                        jnp.maximum(backlog, 0.0)
-                        / jnp.maximum(net_out_l, _RATE_EPS), jnp.inf)
-                    drain_now = occ & (drain_ql <= dtv + _WAKE_EPS_US)
-                    mu_a = lam_q * dtv
-                    z_q = jnp.where(drain_now, 0.0, zs[:q_max])
-                    raw = arr_res + mu_a + jnp.sqrt(mu_a) * z_q
-                    a = jnp.maximum(raw, 0.0)
-                    arr_res = jnp.minimum(raw, 0.0)
-                    room = jnp.maximum(capacity - backlog, 0.0) \
-                        + jnp.where(occ, mu * dtv, 0.0)
-                    adm = jnp.minimum(a, room)
-                    offered = a.sum()
-                    dropped = (a - adm).sum()
-
-                with jax.named_scope("drain"):
-                    serve = jnp.where(
-                        occ, jnp.minimum(backlog + adm, mu * dtv), 0.0)
-                    b_new = jnp.minimum(
-                        jnp.maximum(backlog + adm - serve, 0.0), capacity)
-                    served = serve.sum()
-
-                with jax.named_scope("accumulate"):
-                    lat_area = 0.5 * (backlog.sum() + b_new.sum()) * dtv
-                    vac_timer = vac_timer + jnp.where(qmask & ~occ, dtv, 0.0)
-                    backlog = b_new
-
-                with jax.named_scope("stall"):
-                    if stall_rate > 0.0:
-                        fire = (next_stall <= t_new) & live
-                        w_end = next_stall + stall_mean_us \
-                            * jax.random.exponential(kse, ())
-                        stall_end = jnp.where(
-                            fire, jnp.maximum(stall_end, w_end), stall_end)
-                        gap = jax.random.exponential(ksg, ()) / stall_rate
-                        next_stall = jnp.where(fire, next_stall + gap,
-                                               next_stall)
-
-                with jax.named_scope("overshoot"):
-                    over = jnp.full((m_max,), base_us)
-                    if sigma_us > 0.0:
-                        over = over + sigma_us * jnp.abs(zs[q_max:])
-                    if tail_prob > 0.0:
-                        hit = jax.random.uniform(kp, (m_max,)) < tail_prob
-                        over = over + hit * tail_mean_us \
-                            * jax.random.exponential(ku, (m_max,))
-                    if intf_prob > 0.0:
-                        ihit = jax.random.uniform(kip, (m_max,)) < intf_prob
-                        over = over + ihit * intf_mean_us \
-                            * jax.random.exponential(kie, (m_max,))
-                    slp_s = t_s * (1.0 + slope) + over
-                    slp_l = t_l * (1.0 + slope) + over
-
-                with jax.named_scope("wake_claim"):
-                    sleep_rem = jnp.where(sleeping, sleep_rem - dtv,
-                                          sleep_rem)
-                    woken = sleeping & (sleep_rem <= _WAKE_EPS_US) & live
-                    if stall_rate > 0.0:
-                        push = woken & (t_new < stall_end)
-                        woken = woken & ~push
-                        sleep_rem = jnp.where(
-                            push,
-                            stall_end - t_new
-                            + jax.random.uniform(ksu, (m_max,)),
-                            sleep_rem)
-                    n_wake = woken.sum().astype(jnp.float32)
-
-                # queues drained out by the boundary release their
-                # thread BEFORE boundary wakes classify — drain-out
-                # precedes the boundary in true time, so a thread
-                # waking at the boundary must see the queue free
-                # (release-after-claim would park it on T_L)
-                with jax.named_scope("release"):
-                    q_done = occ & (backlog <= 1e-6)
-                    # one-hot over the queue axis: a per-point gather here
-                    # costs most of a scan step on a TPU; a detached
-                    # thread (-1) matches no queue
-                    t_done = ((attached[:, None] == q_idx) & q_done).any(axis=1)
-                    sleep_rem = jnp.where(t_done, slp_s, sleep_rem)
-                    attached = jnp.where(t_done, -1, attached)
-                    occ = occ & ~q_done
-
-                with jax.named_scope("wake_claim"):
-                    busy_tries = jnp.float32(0.0)
-                    cycles = jnp.float32(0.0)
-                    vac_sum = jnp.float32(0.0)
-                    nv_sum = jnp.float32(0.0)
-                    ts_arm = t_done.sum().astype(jnp.float32)
-                    for i in range(m_max):      # static unroll, m_max small
-                        w = woken[i]
-                        free_q = qmask & ~occ
-                        claimable = free_q & (backlog >= 1.0)
-                        qi = jnp.argmax(jnp.where(claimable, backlog, -1.0))
-                        do_attach = w & claimable.any()
-                        empty_claim = w & ~claimable.any() & free_q.any()
-                        eqi = jnp.argmax(free_q)
-                        blocked = w & ~free_q.any()
-
-                        claim_hot = do_attach & (q_idx == qi)
-                        claim_any = claim_hot | (empty_claim & (q_idx == eqi))
-                        vac_sum = vac_sum + (vac_timer * claim_any).sum()
-                        # one nonzero term at most, so the sum is exact
-                        nv_sum = nv_sum + jnp.where(claim_hot, backlog, 0.0).sum()
-                        vac_timer = jnp.where(claim_any, 0.0, vac_timer)
-                        cycles = cycles + (do_attach | empty_claim)
-                        busy_tries = busy_tries + blocked
-                        ts_arm = ts_arm + empty_claim
-                        attached = attached.at[i].set(
-                            jnp.where(do_attach, qi, attached[i]))
-                        occ = occ | claim_hot
-                        sleep_rem = sleep_rem.at[i].add(
-                            jnp.where(empty_claim, slp_s[i],
-                                      jnp.where(blocked, slp_l[i], 0.0)))
-
-                with jax.named_scope("accumulate"):
-                    awake_step = n_wake * wake_cost_us + served / mu
-                    energy_step = (active_power_w * awake_step
-                                   + ts_arm * e_arm_s
-                                   + busy_tries * e_arm_l)
-                    out = (offered, dropped, served, n_wake, busy_tries,
-                           cycles, vac_sum, nv_sum, adm.sum(), lat_area,
-                           ts_arm, energy_step)
-                return (sleep_rem, attached, backlog, vac_timer, arr_res,
-                        stall_end, next_stall), out
-
-            new_carry, outs = jax.vmap(host_step_a)(
-                keys, lam_hq, a_sleep, a_att, a_back, a_vac, a_res,
-                a_stall, a_next)
-            (a_sleep, a_att, a_back, a_vac, a_res, a_stall,
-             a_next) = new_carry
-            (offered_h, dropped_h, served_h, n_wake_h, busy_h, cycles_h,
-             vac_h, nv_h, adm_h, lat_area_h, ts_arm_h,
-             energy_h) = outs
-
-            # topology — the macro-slot's admissions pay rack + link
-            # cost at the slot's average far-rack arrival rate
-            with jax.named_scope("topology"):
-                if topo_on:
-                    topo_delay_us = rack_cost_us
-                    if link_rate_mpps > 0.0 and far_count > 0:
-                        far_rate = jnp.where(far_mask, adm_h, 0.0).sum() / dtv
-                        gap = jnp.maximum(
-                            link_rate_mpps - far_rate,
-                            (1.0 - _LINK_UTIL_CLAMP) * link_rate_mpps)
-                        topo_delay_us = topo_delay_us + far_mask / gap
-                    topo_area_h = adm_h * topo_delay_us
-                else:
-                    topo_area_h = jnp.zeros((n_hosts,))
-
-            with jax.named_scope("accumulate"):
-                SA = _FleetSlotStats(
-                    offered=SA.offered + offered_h,
-                    dropped=SA.dropped + dropped_h,
-                    serviced=SA.serviced + served_h,
-                    wakeups=SA.wakeups + n_wake_h,
-                    busy_tries=SA.busy_tries + busy_h,
-                    cycles=SA.cycles + cycles_h,
-                    awake_us=SA.awake_us + n_wake_h * wake_cost_us
-                             + served_h / mu,
-                    lat_area=SA.lat_area + lat_area_h,
-                    vac_sum=SA.vac_sum + vac_h,
-                    nv_sum=SA.nv_sum + nv_h,
-                    ts_arms=SA.ts_arms + ts_arm_h,
-                    energy_uj=SA.energy_uj + energy_h,
-                    topo_area=SA.topo_area + topo_area_h,
-                    hedge_dup=SA.hedge_dup,
-                    hedge_dup_in=SA.hedge_dup_in,
-                    hedge_dup_dropped=SA.hedge_dup_dropped,
-                )
-                rem_t = rem_t - dtv
-                nst = nst + 1.0
-                fst = fst + forced.astype(jnp.float32)
-            with jax.named_scope("gate"):
-                nxt = (a_sleep, a_att, a_back, a_vac, a_res, a_stall, a_next,
-                       lb_snap, next_ref, rem_t, nst, fst, SA)
-                gated = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(live, new, old), nxt, prev)
-            return gated, None
-
-        z0 = jnp.float32(0.0)
-        init_a = (sleep0_h,
-                  jnp.full((n_hosts, m_max), -1, jnp.int32),
-                  jnp.zeros((n_hosts, q_max), jnp.float32),
-                  jnp.zeros((n_hosts, q_max), jnp.float32),
-                  jnp.zeros((n_hosts, q_max), jnp.float32),
-                  jnp.full((n_hosts,), -1.0, jnp.float32),
-                  next0_h,
-                  zh,                        # stale LB snapshot
-                  z0,                        # next_ref: refresh at t=0
-                  jnp.asarray(duration, jnp.float32),
-                  z0, z0,                    # n_steps, forced_steps
-                  _FleetSlotStats(*([zh] * len(_FleetSlotStats._fields))))
-        (*_, rem_f, nst, fst, SA), _ = jax.lax.scan(
-            fleet_step_a, init_a, jnp.arange(n_slots, dtype=jnp.int32))
-        return ((SA, jnp.zeros((n_hosts, 0), jnp.float32)),
-                duration - rem_f, nst, fst)
+        init = (sleep0_h,
+                jnp.full((n_hosts, m_max), -1, jnp.int32),
+                zq, zq, zq,
+                jnp.full((n_hosts,), -1.0, jnp.float32),
+                zh,                          # stale LB snapshot
+                _FleetSlotStats(*([zh] * len(_FleetSlotStats._fields))),
+                _Cohort(zq, zq, zq, zq, jnp.full_like(zq, jnp.inf),
+                        jnp.full_like(zq, -jnp.inf)))
+        ring0 = jnp.zeros((n_hosts, q_max, RING), jnp.float32)
+        hist0 = jnp.zeros((n_hosts, HIST_BINS + 2), jnp.float32)
+        n_cohorts = -(-n_slots // g)
+        (slots, _, _, hist), _ = jax.lax.scan(
+            cohort_step, (init, ring0, ring0, hist0),
+            jnp.arange(n_cohorts, dtype=jnp.int32))
+        n_live = jnp.minimum(jnp.ceil(duration / dt),
+                             jnp.float32(n_cohorts * g))
+        return ((slots[-2], hist), n_live * dt, n_live,
+                jnp.zeros_like(duration))
 
     inner = jax.vmap(one_fleet)
     if n_shards > 1:
@@ -1053,8 +716,8 @@ class FleetStats:
 
     All counter arrays are float64 of shape ``(len(fgrid), n_hosts)``;
     ``hist`` is ``(len(fgrid), n_hosts, HIST_BINS + 2)``: each host's
-    served packets by sojourn, bins ``sojourn_edges(slot_us)`` (empty in
-    the adaptive build, which measures no tail).  Fleet-level metrics
+    served packets by sojourn, bins ``sojourn_edges(slot_us)``.
+    Fleet-level metrics
     reduce over the host axis; tail quantiles combine the measured
     per-host survivals by first completion wins (``quantile``).
     ``reshaped(name)`` appends the host axis to the grid's logical
@@ -1083,10 +746,8 @@ class FleetStats:
     hedge_dup_dropped: np.ndarray = field(
         default_factory=lambda: np.empty(0))
     hist: np.ndarray = field(default_factory=lambda: np.empty(0))
-    # stepping diagnostics (see BatchStats): which kernel ran, its
-    # compiled scan length, and per-POINT live/forced step counts and
-    # exact simulated time (host axis shares one dt, so these are (P,))
-    stepping: str = "fixed"
+    # the compiled scan length, and per-POINT live slots, simulated time
+    # and forced steps (always 0: the kernel takes no step but a slot)
     scan_len: int = 0
     n_steps: np.ndarray = field(default_factory=lambda: np.empty(0))
     forced_steps: np.ndarray = field(default_factory=lambda: np.empty(0))
@@ -1172,10 +833,6 @@ class FleetStats:
     def survival(self, i: int) -> _Survival:
         """Measured per-host survival of point ``i``, network delay
         included."""
-        if self.hist.size == 0:
-            raise ValueError(
-                "this sweep measured no sojourns (the adaptive fleet "
-                "build keeps no ring); run stepping='fixed' for tails")
         delay = self.topo_area[i] / np.maximum(self.serviced[i], 1.0)
         return _Survival(self.hist[i], sojourn_edges(self.slot_us), delay)
 
@@ -1315,8 +972,8 @@ class FleetStats:
 
 
 def simulate_fleet(fgrid: FleetGrid, cfg: SimRunConfig | None = None, *,
-                   slot_us: float = 0.5, shard: bool | None = None,
-                   stepping: str = "fixed") -> FleetStats:
+                   slot_us: float = 0.5, shard: bool | None = None
+                   ) -> FleetStats:
     """Simulate every fleet operating point — ONE jit-compiled call over
     the whole (point x host) batch; no Python loop over hosts.
 
@@ -1325,25 +982,11 @@ def simulate_fleet(fgrid: FleetGrid, cfg: SimRunConfig | None = None, *,
     back to pure vmap on one device; ``True``/``False`` force the
     respective path.  Points are padded to a multiple of the device
     count and the padding is sliced off the results.
-
-    ``stepping="adaptive"`` switches to the event-jump kernel: hosts
-    advance in lock-step by a shared variable ``dt`` (nearest boundary
-    across the fleet, incl. the LB stale-refresh lattice).  The step
-    budget sums per-host boundary estimates — load-proportionality
-    shrinks as ``n_hosts`` grows (a 1000-host fleet has a wake
-    somewhere almost every slot), so the budget is clamped at the
-    fixed scan length and adaptive never scans more than fixed.
     """
-    if stepping not in ("fixed", "adaptive"):
-        raise ValueError(
-            f"stepping must be 'fixed' or 'adaptive', got {stepping!r}")
     cfg = cfg or SimRunConfig()
     validate_batched_config(cfg)
     fleet = fgrid.fleet.validate()
     hedge = np.asarray(fgrid.hedge_deadline_us, dtype=np.float64)
-    if stepping == "adaptive" and np.any(hedge > 0.0):
-        raise ValueError("the adaptive fleet build does not hedge: it keeps "
-                         "no sojourn ring (use stepping='fixed')")
     horizon = float(sojourn_edges(slot_us)[-1])
     if np.any(hedge >= horizon):
         raise ValueError(f"hedge deadlines must lie below the sojourn "
@@ -1355,16 +998,8 @@ def simulate_fleet(fgrid: FleetGrid, cfg: SimRunConfig | None = None, *,
     stale_every_slots = max(int(round(fleet.lb_stale_us / slot_us)), 1)
 
     n_slots_true = max(int(math.ceil(cfg.duration_us / slot_us)), 1)
-    if stepping == "adaptive":
-        est = estimate_adaptive_steps(fgrid.grid, cfg, slot_us, 0)
-        if fleet.lb == "least-loaded":
-            est += int(math.ceil(
-                cfg.duration_us / (stale_every_slots * slot_us)))
-        n_slots = bucket_steps(
-            min(fleet.n_hosts * est + 64, n_slots_true))
-    else:
-        g = cohort_slots(slot_us)
-        n_slots = -(-bucket_steps(n_slots_true) // g) * g
+    g = cohort_slots(slot_us)
+    n_slots = -(-bucket_steps(n_slots_true) // g) * g
 
     n_dev = len(jax.devices())
     use_shard = (n_dev > 1) if shard is None else bool(shard)
@@ -1388,7 +1023,7 @@ def simulate_fleet(fgrid: FleetGrid, cfg: SimRunConfig | None = None, *,
             float(fleet.lb_softness_pkts), stale_every_slots,
             fleet.far_hosts(), float(fleet.near_cost_us),
             float(fleet.far_cost_us), float(fleet.link_rate_mpps),
-            n_shards, stepping)
+            n_shards)
 
         pad = (-n_pts) % n_shards
 
@@ -1420,7 +1055,7 @@ def simulate_fleet(fgrid: FleetGrid, cfg: SimRunConfig | None = None, *,
                 fgrid=fgrid, cfg=cfg, slot_us=float(slot_us),
                 backend=(f"shard_map({n_shards})" if n_shards > 1
                          else "vmap"),
-                stepping=stepping, scan_len=n_slots,
+                scan_len=n_slots,
                 hist=np.asarray(hist, dtype=np.float64)[:n_pts],
                 n_steps=np.asarray(nst, dtype=np.float64)[:n_pts],
                 forced_steps=np.asarray(fst, dtype=np.float64)[:n_pts],

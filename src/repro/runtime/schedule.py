@@ -171,17 +171,6 @@ class LoadSchedule:
                if a != b]
         return tuple(out)
 
-    def jump_boundaries(self, duration_us: float) -> np.ndarray:
-        """Interior segment edges in ``(0, duration_us)`` — the times an
-        event-jump (adaptive macro-slot) kernel must not step across,
-        because the arrival rate is only piecewise-constant between
-        them.  Used by ``batched_adaptive.estimate_adaptive_steps`` to
-        budget the scan length; the kernel itself stops at these edges
-        via the compiled ``(edges, scales)`` rows."""
-        edges, _ = self.segments(duration_us)
-        inner = edges[(edges > 0.0) & (edges < duration_us)]
-        return np.asarray(inner, dtype=np.float64)
-
     def descriptor(self) -> str:
         return self.name
 

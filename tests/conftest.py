@@ -1,5 +1,8 @@
 """Fixtures shared by the kernel tests."""
 
+import contextlib
+import time
+
 import pytest
 
 
@@ -31,10 +34,35 @@ def capture_kernel(monkeypatch):
     return capture
 
 
+@contextlib.contextmanager
+def _driven_by(driver):
+    from repro.runtime import batched, tracing
+
+    kernel = driver == "kernel"
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(batched, "_fused", lambda n_seg, n_windows:
+                   kernel and not n_seg and not n_windows)
+        start = time.perf_counter_ns()
+        yield
+    fused = {r.counts["fused"] for r in tracing.records()
+             if r.name == "batched.scan" and r.start_ns >= start}
+    assert fused <= {int(kernel)}, (driver, fused)
+
+
+@pytest.fixture(scope="session")
+def driven_by():
+    """``with driven_by(driver):`` runs the fixed scan's slots by
+    ``driver`` inside the block, whatever the backend: "scan", the
+    ``lax.scan`` over slots, or "kernel", the Pallas kernel that a TPU
+    runs for a stationary build without windows (in interpret mode off
+    a TPU).  It checks that every sweep in the block ran that driver."""
+    return _driven_by
+
+
 @pytest.fixture
 def scan_kernel(capture_kernel):
-    """``kernel(kind, q_max=1)``: the jitted ``kind`` kernel ("fixed",
-    "adaptive" or "fleet") and its inputs, at a tiny shape with
+    """``kernel(kind, q_max=1)``: the jitted ``kind`` kernel ("fixed" or
+    "fleet") and its inputs, at a tiny shape with
     ``q_max`` queues per point (per host, in the fleet).  Every named
     stage has operations: the sleep model has a tail, the host has
     interference and stalls, the fleet a topology and a hedge."""
@@ -44,7 +72,6 @@ def scan_kernel(capture_kernel):
         SimRunConfig,
         SweepGrid,
         batched,
-        batched_adaptive,
         fleet,
         simulate_batch,
     )
@@ -64,11 +91,6 @@ def scan_kernel(capture_kernel):
                 fleet, "_compiled_fleet_sweep", fleet._build_fleet_sweep,
                 lambda: fleet.simulate_fleet(fgrid, noisy, shard=False))
         grid = SweepGrid.product(**axes)
-        if kind == "adaptive":
-            return capture_kernel(
-                batched_adaptive, "_compiled_adaptive_sweep",
-                batched_adaptive._build_adaptive_sweep,
-                lambda: simulate_batch(grid, noisy, stepping="adaptive"))
         return capture_kernel(
             batched, "_compiled_sweep", batched._build_sweep,
             lambda: simulate_batch(grid, noisy))

@@ -37,7 +37,7 @@ CASES = [
     ("lock_block_bad.py", {"LOCK002": 1}),
     ("lock_stats_bad.py", {"LOCK003": 1}),
     ("lock_clean.py", {}),
-    ("parity_bad", {"PARITY001": 2, "PARITY002": 3}),
+    ("parity_bad", {"PARITY001": 1, "PARITY002": 2}),
     ("parity_clean", {}),
     ("race_write_bad.py", {"RACE001": 1}),
     ("race_write_clean.py", {}),

@@ -45,6 +45,15 @@ def test_build_meets_target_and_scales_cpu_with_load(table):
     assert cpus[-1] < 1.0                        # still beats busy-poll
 
 
+def test_table_from_the_kernel_driver_equals_the_scan_drivers(driven_by,
+                                                              table):
+    """The slots as a TPU runs them (the Pallas kernel, in interpret mode
+    off a TPU) give the same table as the ``lax.scan``, rung for rung."""
+    with driven_by("kernel"):
+        kernel = _tiny_table()
+    assert kernel.points == table.points
+
+
 def test_spot_check_against_event_engine_passes():
     # same tiny grid, now cross-examined by the exact engine
     _tiny_table(spot_check=2)

@@ -40,12 +40,15 @@ TINY_LATTICE = dict(t_s_grid=np.linspace(3.0, 80.0, 14)[[4, 5]],
                     duration_us=50_000.0, slot_us=0.5)
 
 
-@pytest.mark.parametrize("stepping", ["fixed", "adaptive"])
-def test_calibration_phase_tiny(cs, stepping):
+@pytest.mark.parametrize("driver", ["scan", "kernel"])
+def test_calibration_phase_tiny(cs, driven_by, driver):
+    """The phase as the CPU runs it (a ``lax.scan`` over slots) and as a
+    TPU runs it (the Pallas kernel, in interpret mode off a TPU)."""
     points = cs.parity_points(TINY_LATTICE, picks=((1, 250.0, 3, 0.7, 0),))
-    rec = cs.calibration(TINY_LATTICE, stepping, points)
+    with driven_by(driver):
+        rec = cs.calibration(TINY_LATTICE, points)
     assert rec["points"] == 8 and rec["parity_points"] == 1
-    assert rec["stepping"] == stepping
+    assert rec["fused"] == (driver == "kernel")
     assert rec["table"].count(":") == 2
 
 
@@ -65,7 +68,7 @@ def test_parity_points_lie_on_the_full_lattice(cs):
 def test_calibration_phase_rejects_a_point_off_the_lattice(cs):
     points = cs.parity_points(TINY_LATTICE, picks=((1, 900.0, 3, 0.7, 0),))
     with pytest.raises(cs.PhaseFailed, match="not in the lattice"):
-        cs.calibration(TINY_LATTICE, "adaptive", points)
+        cs.calibration(TINY_LATTICE, points)
 
 
 def test_fleet_phase_tiny(cs):

@@ -1,9 +1,9 @@
 """Energy accounting across every execution layer.
 
 The model (simcore.EnergyModel) is charged identically by the event
-engine, both batched kernels (fixed-slot + adaptive event-jump), and
-the fleet engine, so an *exact* conservation identity is testable on
-each:
+engine, the batched kernel (under both drivers of its slots: the
+``lax.scan`` and the Pallas kernel, in interpret mode off a TPU), and the
+fleet engine, so an *exact* conservation identity is testable on each:
 
     energy_uj == active_power_w * awake_us
                + ts_arms     * arm_energy(T_S)
@@ -14,7 +14,7 @@ spill) reproduce the totals, merge/merge_all conserve cluster energy,
 and the engines agree with each other within pinned bands on the same
 config family the latency/CPU parity tests use.
 
-Checked two ways, mirroring tests/test_stepping.py: seeded-random
+Checked two ways, mirroring tests/test_scan_invariants.py: seeded-random
 sweeps that always run, and the same properties under hypothesis when
 it is installed.
 """
@@ -40,9 +40,9 @@ from repro.runtime import (
 )
 from repro.runtime.simcore import HR_SLEEP_MODEL, WindowAccum
 
-STEPPINGS = ("fixed", "adaptive")
+DRIVERS = ("scan", "kernel")
 
-# Same f32-accumulator rationale as test_stepping.CONS_REL: the
+# Same f32-accumulator rationale as test_scan_invariants.CONS_REL: the
 # identity must hold far tighter than any physical effect, not bit-exact
 CONS_REL = 2e-3
 
@@ -149,15 +149,26 @@ def test_energy_arm_cost_matches_model_on_a_grid_of_targets():
 
 # ------------------------------------------- batched kernels: identity
 
-@pytest.mark.parametrize("stepping", STEPPINGS)
+def _driver_config(driver, duration_us, **kw):
+    """The kernel takes stationary builds without windows and runs in
+    interpret mode off a TPU: a short run without windows; the scan a
+    run of ``duration_us`` with them."""
+    if driver == "kernel":
+        return SimRunConfig(duration_us=200.0, sleep_model=HR_SLEEP_MODEL,
+                            **kw)
+    return SimRunConfig(duration_us=duration_us, sleep_model=HR_SLEEP_MODEL,
+                        window_us=1_000.0, **kw)
+
+
+@pytest.mark.parametrize("driver", DRIVERS)
 @pytest.mark.parametrize("em", (DEFAULT_ENERGY_MODEL,
                                 DEEP_CSTATE_ENERGY_MODEL),
                          ids=("default", "deep"))
-def test_kernel_energy_obeys_conservation_identity(stepping, em):
+def test_kernel_energy_obeys_conservation_identity(driven_by, driver, em):
     grid = SweepGrid.of_points(_mixed_points())
-    cfg = SimRunConfig(duration_us=30_000.0, sleep_model=HR_SLEEP_MODEL,
-                       window_us=1_000.0, energy_model=em)
-    bs = simulate_batch(grid, cfg, slot_us=0.5, stepping=stepping)
+    cfg = _driver_config(driver, 30_000.0, energy_model=em)
+    with driven_by(driver):
+        bs = simulate_batch(grid, cfg, slot_us=0.5)
     _check_conservation(bs, em)
 
 
@@ -287,40 +298,38 @@ def test_fleet_energy_per_host_identity_and_cluster_rollup():
                            rate_mpps=(0.4 * 29.76 * 3,),
                            m=(2,), n_queues=(1,), seeds=(0,))
     arm_s, arm_l = em.arm_energy_uj(25.0), em.arm_energy_uj(300.0)
-    for st in STEPPINGS:
-        fs = simulate_fleet(fg, cfg, slot_us=0.5, shard=False, stepping=st)
-        pred = (em.active_power_w * fs.awake_us
-                + fs.ts_arms * arm_s + fs.busy_tries * arm_l)
-        np.testing.assert_allclose(fs.energy_uj, pred, rtol=CONS_REL,
-                                   atol=1.0)
-        assert float(fs.total_energy_uj[0]) \
-            == pytest.approx(float(fs.energy_uj[0].sum()), rel=1e-6)
-        assert np.all(fs.host_power_w > 0.0)
-        assert float(fs.energy_per_packet_nj[0]) > 0.0
-        # cluster rollup through RunStats.merge_all conserves energy
-        hosts = fs.host_run_stats(0)
-        rolled = hosts[0].merge_all(hosts[1:])
-        assert rolled.energy_uj == pytest.approx(
-            float(fs.total_energy_uj[0]), rel=1e-6, abs=1.0)
+    fs = simulate_fleet(fg, cfg, slot_us=0.5, shard=False)
+    pred = (em.active_power_w * fs.awake_us
+            + fs.ts_arms * arm_s + fs.busy_tries * arm_l)
+    np.testing.assert_allclose(fs.energy_uj, pred, rtol=CONS_REL, atol=1.0)
+    assert float(fs.total_energy_uj[0]) \
+        == pytest.approx(float(fs.energy_uj[0].sum()), rel=1e-6)
+    assert np.all(fs.host_power_w > 0.0)
+    assert float(fs.energy_per_packet_nj[0]) > 0.0
+    # cluster rollup through RunStats.merge_all conserves energy
+    hosts = fs.host_run_stats(0)
+    rolled = hosts[0].merge_all(hosts[1:])
+    assert rolled.energy_uj == pytest.approx(
+        float(fs.total_energy_uj[0]), rel=1e-6, abs=1.0)
 
 
 # ------------------------------------------------- cross-engine parity
 
-def test_energy_parity_event_vs_both_kernels():
+@pytest.mark.parametrize("driver", DRIVERS)
+def test_energy_parity_event_vs_both_kernels(driven_by, driver):
     pts = _band_points()
     cfg = SimRunConfig(duration_us=120_000.0, sleep_model=HR_SLEEP_MODEL)
     ev = [_event_run(p, replace(cfg, seed=p["seed"])) for p in pts]
     grid = SweepGrid.of_points(pts)
-    for st in STEPPINGS:
-        bs = simulate_batch(grid, cfg, slot_us=0.5, stepping=st)
-        for i, rs in enumerate(ev):
-            e_ev, e_bs = rs.energy_uj, float(bs.energy_uj[i])
-            assert abs(e_bs - e_ev) <= E_ABS_UJ + E_REL * e_ev, \
-                (st, i, e_bs, e_ev)
-            pp_ev = rs.energy_per_packet_nj
-            pp_bs = float(bs.energy_per_packet_nj[i])
-            assert abs(pp_bs - pp_ev) <= EPP_ABS_NJ + EPP_REL * pp_ev, \
-                (st, i, pp_bs, pp_ev)
+    with driven_by(driver):
+        bs = simulate_batch(grid, cfg, slot_us=0.5)
+    for i, rs in enumerate(ev):
+        e_ev, e_bs = rs.energy_uj, float(bs.energy_uj[i])
+        assert abs(e_bs - e_ev) <= E_ABS_UJ + E_REL * e_ev, (i, e_bs, e_ev)
+        pp_ev = rs.energy_per_packet_nj
+        pp_bs = float(bs.energy_per_packet_nj[i])
+        assert abs(pp_bs - pp_ev) <= EPP_ABS_NJ + EPP_REL * pp_ev, \
+            (i, pp_bs, pp_ev)
 
 
 # --------------------------------------------------- hr_sleep calibrate
@@ -360,15 +369,14 @@ if HAVE_HYPOTHESIS:
         seed=st.integers(min_value=0, max_value=2**31 - 1),
     ))
 
+    @pytest.mark.parametrize("driver", DRIVERS)
     @settings(max_examples=10, deadline=None)
     @given(pts=st.lists(point_st, min_size=1, max_size=4),
-           stepping=st.sampled_from(STEPPINGS),
            deep=st.booleans())
-    def test_energy_identity_holds_for_random_grids(pts, stepping, deep):
+    def test_energy_identity_holds_for_random_grids(driven_by, driver, pts,
+                                                    deep):
         em = DEEP_CSTATE_ENERGY_MODEL if deep else DEFAULT_ENERGY_MODEL
-        cfg = SimRunConfig(duration_us=20_000.0,
-                           sleep_model=HR_SLEEP_MODEL,
-                           window_us=1_000.0, energy_model=em)
-        bs = simulate_batch(SweepGrid.of_points(pts), cfg, slot_us=0.5,
-                            stepping=stepping)
+        cfg = _driver_config(driver, 20_000.0, energy_model=em)
+        with driven_by(driver):
+            bs = simulate_batch(SweepGrid.of_points(pts), cfg, slot_us=0.5)
         _check_conservation(bs, em)

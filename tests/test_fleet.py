@@ -318,17 +318,6 @@ def test_hedge_only_what_is_late():
         (share, late)
 
 
-def test_adaptive_fleet_measures_no_tail():
-    fg = _fgrid(FleetConfig(n_hosts=2), hedge=(0.0,))
-    cfg = SimRunConfig(duration_us=2_000.0)
-    fs = simulate_fleet(fg, cfg, slot_us=1.0, stepping="adaptive")
-    with pytest.raises(ValueError, match="no sojourns"):
-        fs.quantile(0, 0.99)
-    with pytest.raises(ValueError, match="does not hedge"):
-        simulate_fleet(_fgrid(FleetConfig(n_hosts=2), hedge=(20.0,)), cfg,
-                       slot_us=1.0, stepping="adaptive")
-
-
 def test_hedge_deadline_zero_leaves_dynamics_untouched():
     cfg = SimRunConfig(duration_us=10_000.0)
     a = simulate_fleet(_fgrid(FleetConfig(n_hosts=4), hedge=(0.0,)),

@@ -135,18 +135,6 @@ def test_fixed_scan_compiles_calibration_sweeps(capture_kernel, on_chip,
             if "tpu_custom_call" in _computation(text, b)], loops
 
 
-def test_adaptive_sweep_kernel_compiles(capture_kernel, on_chip):
-    from repro.runtime import batched_adaptive
-
-    grid, cfg, slot_us = _lattice()
-    fn, args = capture_kernel(
-        batched_adaptive, "_compiled_adaptive_sweep",
-        batched_adaptive._build_adaptive_sweep,
-        lambda: simulate_batch(grid, cfg, slot_us=slot_us,
-                               stepping="adaptive"))
-    _compile_gather_free(fn, on_chip(args))
-
-
 def test_fleet_kernel_compiles_1000_hosts(capture_kernel, on_chip):
     from benchmarks.fleet import scale_sweep
     from repro.runtime import fleet

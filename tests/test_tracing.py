@@ -86,8 +86,8 @@ def test_ring_bound_and_totals():
     assert total >= before[1] + sum(r.duration_ns for r in recs)
 
 
-@pytest.mark.parametrize("stepping", ["fixed", "adaptive"])
-def test_one_sweep_one_scan_one_fetch_and_built(stepping):
+@pytest.mark.parametrize("driver", ["scan", "kernel"])
+def test_one_sweep_one_scan_one_fetch_and_built(driven_by, driver):
     # a shape no other test builds (five threads, 3,210 us), so the
     # first call misses the kernel cache
     cfg = SimRunConfig(duration_us=3210.0)
@@ -95,7 +95,8 @@ def test_one_sweep_one_scan_one_fetch_and_built(stepping):
                              rate_mpps=[3.0], seeds=(0, 1))
     built = []
     for _ in range(2):
-        bs = simulate_batch(grid, cfg, slot_us=0.5, stepping=stepping)
+        with driven_by(driver):
+            bs = simulate_batch(grid, cfg, slot_us=0.5)
         sweep = _last("batched.sweep")
         kids = _children(sweep)
         assert [k.name for k in kids] == ["batched.scan", "batched.fetch"]
@@ -213,7 +214,6 @@ def _program_text(compiled_text: str) -> str:
 
 def test_stage_names_and_module_names(scan_kernel):
     stages = {"fixed": STAGES,
-              "adaptive": STAGES + ("jump",),
               "fleet": STAGES + ("balancer", "topology", "hedge")}
     modules = set()
     for kind in stages:
@@ -224,8 +224,7 @@ def test_stage_names_and_module_names(scan_kernel):
             # a stage inside the fleet's host vmap reads vmap(<stage>)
             assert re.search(rf'loc\("(vmap\()?{stage}\)?/', text), \
                 (kind, stage)
-    assert modules == {"jit_fixed_sweep", "jit_adaptive_sweep",
-                       "jit_fleet_sweep"}
+    assert modules == {"jit_fixed_sweep", "jit_fleet_sweep"}
 
 
 def test_named_scopes_are_metadata_only(monkeypatch, scan_kernel):
