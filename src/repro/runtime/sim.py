@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from . import tracing
 from .policy import WakeContext
 from .simcore import (
     HR_SLEEP_MODEL,
@@ -48,7 +49,6 @@ from .simcore import (
     scheduled_workload,
 )
 from .stats import QueueStats, Reservoir, RunStats
-
 __all__ = [
     "SleepModel",
     "HR_SLEEP_MODEL",
@@ -70,6 +70,13 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
     ``SharedAssignment``) maps poller threads to queues.  Spinning
     policies use the analytic fluid model and ignore both (a sweeping
     core sees the union of all rings).
+
+    The loop's cost is the interpreter's, per wake and per drain round,
+    so its state is Python floats and ints in lists, its hot callables
+    are bound once, and what a run does not use (windows, time-series
+    bins) is skipped rather than called.  The event sequence is the
+    same draw for draw.  The open ``tracing`` span is given the run's
+    ``wakes`` and ``drain_rounds``.
     """
     cfg = cfg or SimRunConfig()
     if getattr(policy, "spin", False):
@@ -87,6 +94,9 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
     pols = setup.policies
     m = len(slots)
     mu = cfg.service_rate_mpps
+    cap = cfg.queue_capacity
+    duration_us = cfg.duration_us
+    wake_cost_us = cfg.wake_cost_us
 
     # Threads are launched actively (paper Sec 5): first wakes land within
     # one short timeout, not spread over T_L (that would fabricate a startup
@@ -96,19 +106,20 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
         idxs = [i for i, s in enumerate(slots) if s.policy is p]
         t_s0 = p.on_wake(WakeContext(primary=True)) / 1e3
         wake_at[idxs] = rng.uniform(0.0, max(t_s0, 1e-3), size=len(idxs))
+    wake_at = wake_at.tolist()
 
-    backlog = np.zeros(nq)
+    backlog = [0.0] * nq
     last_advanced = 0.0      # arrivals accounted up to here
-    busy_until = np.zeros(nq)         # each lock held until this time
-    last_busy_end = np.zeros(nq)
+    busy_until = [0.0] * nq           # each lock held until this time
+    last_busy_end = [0.0] * nq
 
     offered = dropped = serviced = busy_tries = wakeups = 0
-    truncations = 0
-    offered_q = np.zeros(nq, dtype=np.int64)
-    dropped_q = np.zeros(nq, dtype=np.int64)
-    serviced_q = np.zeros(nq, dtype=np.int64)
-    busy_tries_q = np.zeros(nq, dtype=np.int64)
-    cycles_q = np.zeros(nq, dtype=np.int64)
+    truncations = drain_rounds = 0
+    offered_q = [0] * nq
+    dropped_q = [0] * nq
+    serviced_q = [0] * nq
+    busy_tries_q = [0] * nq
+    cycles_q = [0] * nq
     vac, bus, nvs = [], [], []
     # one latency reservoir per queue, decorrelated seeds (simcore)
     lat_q = queue_reservoirs(cfg, nq)
@@ -121,15 +132,36 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
     # EnergyModel accounting: active power on awake time, sleep +
     # transition charged per armed sleep at its programmed target (the
     # same arm-time convention as the batched kernels; the m initial
-    # staggering sleeps are uncharged in every engine)
+    # staggering sleeps are uncharged in every engine).  A run arms few
+    # distinct targets (T_S and T_L, or the controller's ns-quantized
+    # values), so each target's charge is computed once.
     em = cfg.energy_model
+    p_active = em.active_power_w
+    e_wake = p_active * wake_cost_us
+    arm_uj: dict[float, float] = {}
     energy_uj = 0.0
+
+    def arm_energy(target_us: float) -> float:
+        e = arm_uj.get(target_us)
+        if e is None:
+            e = arm_uj[target_us] = em.arm_energy_uj(target_us)
+        return e
 
     nbins = int(cfg.duration_us / cfg.timeseries_bin_us) if cfg.timeseries_bin_us else 0
     b_rho = np.zeros(max(nbins, 1)); b_ts = np.zeros(max(nbins, 1))
     b_srv = np.zeros(max(nbins, 1)); b_off = np.zeros(max(nbins, 1))
     b_cnt = np.zeros(max(nbins, 1))
-    wa = WindowAccum(cfg)        # no-op when cfg.window_us == 0
+    wa = WindowAccum(cfg)
+    windowed = wa.n > 0
+
+    counts_in = workload.counts_in
+    uniform, rand = rng.uniform, rng.random
+    exponential = rng.exponential
+    sleep = cfg.sleep_model.sampler(rng)
+    interference_prob = cfg.interference_prob
+    interference_mean_us = cfg.interference_mean_us
+    stall_rate = cfg.stall_rate_per_us
+    stall_mean_us = cfg.stall_mean_us
 
     def admit(q: int, n: int, at_t: float, to_t: float) -> None:
         """Room-clipped enqueue of ``n`` arrivals spread over [at_t, to_t]
@@ -138,8 +170,9 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
         nonlocal offered, dropped
         offered += n
         offered_q[q] += n
-        wa.add(at_t, offered=n)
-        room = cfg.queue_capacity - backlog[q]
+        if windowed:
+            wa.add(at_t, offered=n)
+        room = cap - backlog[q]
         if n > room:
             d = int(n - max(room, 0))
             dropped += d
@@ -149,11 +182,10 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
             n_in = n
         if n_in > 0:
             admitted[q].extend((n_in, at_t, at_t + (to_t - at_t) * n_in / n))
-        n = n_in
-        backlog[q] += n
+        backlog[q] += n_in
         if nbins:
             b = min(int(at_t / cfg.timeseries_bin_us), nbins - 1)
-            b_off[b] += n + 0.0
+            b_off[b] += n_in + 0.0
 
     def advance_arrivals(to_t: float) -> None:
         """Accumulate workload arrivals on [last_advanced, to_t) and
@@ -161,11 +193,11 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
         nonlocal last_advanced
         if to_t <= last_advanced:
             return
-        n = workload.counts_in(last_advanced, to_t)
+        n = counts_in(last_advanced, to_t)
         if nq == 1:
             admit(0, n, last_advanced, to_t)
         elif n > 0:
-            parts = dispatcher.split(int(n), backlog)
+            parts = dispatcher.split(int(n), np.array(backlog))
             for q in range(nq):
                 if parts[q]:
                     admit(q, int(parts[q]), last_advanced, to_t)
@@ -178,80 +210,89 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
         until empty (round-capped so saturated runs still terminate;
         leftovers stay queued and the truncation is counted)."""
         nonlocal offered, dropped, last_advanced, truncations, lat_area
+        nonlocal drain_rounds
         total_t = 0.0
         served = 0.0
         cursor = t_start
         rounds = 0
-        while backlog[q] >= 1.0 and rounds < 64:
-            dt = backlog[q] / mu
-            b_r = float(backlog[q])
-            served += float(backlog[q])
+        area = lat_area
+        q_offered = q_dropped = 0
+        depart = departed[q].extend
+        enqueue = admitted[q].extend
+        b_r = backlog[q]
+        while b_r >= 1.0 and rounds < 64:
+            dt = b_r / mu
+            served += b_r
             total_t += dt
-            departed[q].extend((b_r, cursor, cursor + dt))
-            n = workload.counts_in(cursor, cursor + dt)
-            cursor += dt
+            t1 = cursor + dt
+            depart((b_r, cursor, t1))
+            n = counts_in(cursor, t1)
+            cursor = t1
             if nq == 1:
                 own = int(n)
             else:
                 own = 0
                 if n > 0:
-                    parts = dispatcher.split(int(n), backlog)
+                    parts = dispatcher.split(int(n), np.array(backlog))
                     own = int(parts[q])
                     for j in range(nq):
                         if j != q and parts[j]:
                             admit(j, int(parts[j]), cursor - dt, cursor)
-            offered += own
-            offered_q[q] += own
+            q_offered += own
             n_own = own
-            if own > cfg.queue_capacity:
-                d = own - cfg.queue_capacity
-                dropped += d
-                dropped_q[q] += d
-                own = cfg.queue_capacity
+            if own > cap:
+                q_dropped += own - cap
+                own = cap
             if own > 0:
-                admitted[q].extend((own, cursor - dt,
-                                    cursor - dt + dt * own / n_own))
-            backlog[q] = float(own)
+                enqueue((own, cursor - dt, cursor - dt + dt * own / n_own))
             # Little integral, drain round r: the b_r being served decline
             # linearly to 0 over dt while the next round's own arrivals
             # accumulate linearly to `own`
-            lat_area += dt * (b_r + own) / 2.0
-            wa.add(cursor, offered=own, served=b_r,
-                   lat_area=dt * (b_r + own) / 2.0)
+            area += dt * (b_r + own) / 2.0
+            if windowed:
+                wa.add(cursor, offered=own, served=b_r,
+                       lat_area=dt * (b_r + own) / 2.0)
             if nbins:
                 # bin the drained queue's own busy-period arrivals too, so
                 # sum(offered_series * bin) tracks RunStats.offered
                 b = min(int(cursor / cfg.timeseries_bin_us), nbins - 1)
                 b_off[b] += own + 0.0
+            b_r = backlog[q] = float(own)
             rounds += 1
-        if backlog[q] >= 1.0:
+        if b_r >= 1.0:
             truncations += 1
+        lat_area = area
+        offered += q_offered
+        offered_q[q] += q_offered
+        dropped += q_dropped
+        dropped_q[q] += q_dropped
+        drain_rounds += rounds
         last_advanced = max(last_advanced, cursor)
         return total_t, int(served)
 
     # correlated stall windows (lazy Poisson process)
-    next_stall = (rng.exponential(1.0 / cfg.stall_rate_per_us)
-                  if cfg.stall_rate_per_us else np.inf)
+    next_stall = exponential(1.0 / stall_rate) if stall_rate else np.inf
     stall_end = -1.0
 
     while True:
-        i = int(np.argmin(wake_at))
-        t = float(wake_at[i])
-        if t >= cfg.duration_us:
+        # the first of the earliest wakes, as np.argmin picks it
+        t = min(wake_at)
+        if t >= duration_us:
             break
-        if cfg.stall_rate_per_us:
+        i = wake_at.index(t)
+        if stall_rate:
             while next_stall <= t:
                 stall_end = max(stall_end,
-                                next_stall + rng.exponential(cfg.stall_mean_us))
-                next_stall += rng.exponential(1.0 / cfg.stall_rate_per_us)
+                                next_stall + exponential(stall_mean_us))
+                next_stall += exponential(1.0 / stall_rate)
             if t < stall_end:
-                wake_at[i] = stall_end + rng.uniform(0.0, 1.0)
+                wake_at[i] = stall_end + uniform(0.0, 1.0)
                 continue
         wakeups += 1
-        awake_us += cfg.wake_cost_us
-        e_wake = em.active_power_w * cfg.wake_cost_us
+        awake_us += wake_cost_us
         energy_uj += e_wake
-        wa.add(t, awake=cfg.wake_cost_us, energy_uj=e_wake)
+        if windowed:
+            wa.add(t, awake=wake_cost_us, energy_uj=e_wake)
         advance_arrivals(t)
 
         slot = slots[i]
@@ -259,8 +300,7 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
         lock_taken = False
         t_cursor = t
         srv_total = 0
-        targets = list(slot.queues)
-        visited = set(targets)
+        targets = slot.queues
         si = 0
         while si < len(targets):
             q = targets[si]
@@ -272,72 +312,77 @@ def simulate_run(policy, workload, cfg: SimRunConfig | None = None, *,
             else:
                 # trylock won: primary for this queue. Vacation ended now.
                 lock_taken = True
-                v = t_cursor - float(last_busy_end[q])
-                n_v = float(backlog[q])
+                v = t_cursor - last_busy_end[q]
+                n_v = backlog[q]
                 # Little integral, vacation phase: the n_v packets found
                 # at busy start arrived ~uniformly over the vacation
-                lat_area += n_v * max(v, 0.0) / 2.0
-                wa.add(t_cursor, lat_area=n_v * max(v, 0.0) / 2.0)
+                v_area = n_v * max(v, 0.0) / 2.0
+                lat_area += v_area
+                if windowed:
+                    wa.add(t_cursor, lat_area=v_area)
                 b_time, srv = drain(q, t_cursor)
                 serviced += srv
                 serviced_q[q] += srv
                 srv_total += srv
                 cycles_q[q] += 1
-                busy_until[q] = t_cursor + b_time
-                last_busy_end[q] = busy_until[q]
+                busy_until[q] = last_busy_end[q] = t_cursor + b_time
                 awake_us += b_time
-                e_busy = em.active_power_w * b_time
+                e_busy = p_active * b_time
                 energy_uj += e_busy
-                wa.add(t_cursor, awake=b_time, energy_uj=e_busy)
+                if windowed:
+                    wa.add(t_cursor, awake=b_time, energy_uj=e_busy)
 
                 vac.append(v); bus.append(b_time); nvs.append(n_v)
                 if n_v >= 1:
                     # consumed so that a seed's event sequence stays the
                     # one the tests pin (latencies come from the FIFO
                     # curves after the loop)
-                    rng.random(2 * min(int(n_v), 8))
+                    rand(2 * min(int(n_v), 8))
 
                 pol.on_cycle_end(b_time, max(v, 1e-9))
-                t_cursor = float(busy_until[q])
+                t_cursor = busy_until[q]
             if si == len(targets) and slot.steal:
                 # own queues done: steal from the longest unvisited backlog
                 cand, best = -1, 1.0
                 for j in range(nq):
-                    if j not in visited and backlog[j] >= best:
-                        cand, best = j, float(backlog[j])
+                    if j not in targets and backlog[j] >= best:
+                        cand, best = j, backlog[j]
                 if cand >= 0:
-                    targets.append(cand)
-                    visited.add(cand)
+                    targets = (*targets, cand)
 
         if not lock_taken:
             # every ring contended: backup role (unless this thread is its
             # ring's only home poller, in which case it keeps its cadence)
             t_b = pol.on_wake(WakeContext(primary=not slot.demote_on_miss,
                                           now_ns=int(t * 1e3))) / 1e3
-            e_arm = em.arm_energy_uj(t_b)
+            e_arm = arm_energy(t_b)
             energy_uj += e_arm
-            wa.add(t, energy_uj=e_arm)
-            delay = float(cfg.sleep_model.sample(t_b, rng))
-            if cfg.interference_prob and rng.random() < cfg.interference_prob:
-                delay += rng.exponential(cfg.interference_mean_us)
+            if windowed:
+                wa.add(t, energy_uj=e_arm)
+            delay = sleep(t_b)
+            if interference_prob and rand() < interference_prob:
+                delay += exponential(interference_mean_us)
             wake_at[i] = t + delay
             continue
 
         t_s = pol.on_wake(WakeContext(primary=True,
                                       now_ns=int(t_cursor * 1e3))) / 1e3
-        e_arm = em.arm_energy_uj(t_s)
+        e_arm = arm_energy(t_s)
         energy_uj += e_arm
-        wa.add(t_cursor, energy_uj=e_arm)
-        wa.control(t, float(getattr(pol, "rho", np.nan)), t_s)
+        if windowed:
+            wa.add(t_cursor, energy_uj=e_arm)
+            wa.control(t, float(getattr(pol, "rho", np.nan)), t_s)
         if nbins:
             b = min(int(t / cfg.timeseries_bin_us), nbins - 1)
             b_rho[b] += getattr(pol, "rho", np.nan)
             b_ts[b] += t_s; b_srv[b] += srv_total; b_cnt[b] += 1
 
-        delay = float(cfg.sleep_model.sample(t_s, rng))
-        if cfg.interference_prob and rng.random() < cfg.interference_prob:
-            delay += rng.exponential(cfg.interference_mean_us)
+        delay = sleep(t_s)
+        if interference_prob and rand() < interference_prob:
+            delay += exponential(interference_mean_us)
         wake_at[i] = t_cursor + delay
+
+    tracing.count(wakes=wakeups, drain_rounds=drain_rounds)
 
     # per-packet sojourns, FIFO on each ring: departure minus admission
     # time at the same rank, for ranks drawn stratified over every packet

@@ -72,6 +72,10 @@ class SleepModel:
     tail_mean_us: float = 0.0
 
     def sample(self, target_us: np.ndarray | float, rng: np.random.Generator):
+        """Overshot sleeps for an array of targets, each draw taken for
+        the whole array in turn.  ``sampler`` is the scalar form the
+        event engine runs; the tests hold the two to the same values and
+        the same distribution."""
         t = np.asarray(target_us, dtype=np.float64)
         noise = np.abs(rng.normal(0.0, self.sigma_us, size=t.shape))
         out = t + self.base_us + self.slope * t + noise
@@ -79,6 +83,24 @@ class SleepModel:
             hit = rng.random(size=t.shape) < self.tail_prob
             out = out + hit * rng.exponential(self.tail_mean_us, size=t.shape)
         return out
+
+    def sampler(self, rng: np.random.Generator):
+        """``sample`` of one scalar target at a time, bound to ``rng``:
+        a function of the target that draws what ``sample`` draws, in
+        its order (the tail's exponential whether it hits or not), and
+        returns the same value as a Python float."""
+        normal, random, exponential = rng.normal, rng.random, rng.exponential
+        base, slope, sigma = self.base_us, self.slope, self.sigma_us
+        tail_prob, tail_mean = self.tail_prob, self.tail_mean_us
+
+        def draw(target_us: float) -> float:
+            out = (target_us + base + slope * target_us
+                   + abs(normal(0.0, sigma)))
+            if tail_prob:
+                hit = random() < tail_prob
+                out = out + hit * exponential(tail_mean)
+            return out
+        return draw
 
 
 HR_SLEEP_MODEL = SleepModel(base_us=2.8, slope=0.027, sigma_us=0.5)
@@ -445,8 +467,8 @@ class WindowAccum:
     ``WindowedSeries`` the batched engine emits (so
     ``TrackingStats`` is one code path across backends).
 
-    Inactive (every call a no-op) when ``cfg.window_us == 0`` — the
-    engines call unconditionally and pay nothing on stationary runs.
+    Inactive (every call a no-op) when ``cfg.window_us == 0`` (``n ==
+    0``); the event engine's loop then skips its calls altogether.
 
     Contributions at event times past ``duration_us`` (the event
     engine's final-drain pass) go to the ``spill_*`` scalars, NOT the

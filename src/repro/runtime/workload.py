@@ -74,16 +74,29 @@ class PoissonWorkload:
 
     def reset(self, rng: np.random.Generator) -> None:
         self._rng = rng
+        # the run's own counts_in, bound once: the event engine calls it
+        # per wake and per drain round, where a method lookup, rate_at
+        # and the generator's attribute are most of its cost
+        self.counts_in = self._counter(rng)
 
     def rate_at(self, t_us: float) -> float:
         return float(self.profile(t_us)) if self.profile else self.rate_mpps
 
+    def _counter(self, rng: np.random.Generator):
+        poisson, profile, rate = rng.poisson, self.profile, self.rate_mpps
+
+        def counts_in(t0_us: float, t1_us: float) -> int:
+            dt = t1_us - t0_us
+            if dt <= 0:
+                return 0
+            lam = float(profile(t0_us)) if profile else rate
+            return int(poisson(lam * dt)) if lam > 0 else 0
+        return counts_in
+
     def counts_in(self, t0_us: float, t1_us: float) -> int:
-        dt = t1_us - t0_us
-        if dt <= 0:
-            return 0
-        lam = self.rate_at(t0_us)
-        return int(self._rng.poisson(lam * dt)) if lam > 0 else 0
+        """One Poisson draw at the rate at ``t0_us``; ``reset`` binds
+        the same function over this method for the run."""
+        return self._counter(self._rng)(t0_us, t1_us)
 
     def iter_arrivals(self, duration_us, rng) -> Iterator[float]:
         t = 0.0

@@ -13,7 +13,12 @@ from repro.runtime import (
     SimRunConfig,
     simulate_run,
 )
-from repro.runtime.simcore import HR_SLEEP_MODEL, SleepModel
+from repro.runtime.simcore import (
+    HR_SLEEP_MODEL,
+    NANOSLEEP_MODEL,
+    PERFECT_SLEEP_MODEL,
+    SleepModel,
+)
 
 
 def _run(cfg):
@@ -70,21 +75,28 @@ def test_interference_and_stalls_compose():
 # SleepModel tail sampling (simcore.py)
 # ---------------------------------------------------------------------------
 
+def _both_forms(model, target_us, n, seed):
+    """``n`` overshoots at one target from each form of the model: the
+    array ``sample`` and the scalar ``sampler`` the event engine runs."""
+    draw = model.sampler(np.random.default_rng(seed))
+    return (model.sample(np.full(n, target_us), np.random.default_rng(seed)),
+            np.array([draw(target_us) for _ in range(n)]))
+
+
 def test_sleep_model_tail_adds_exp_mass():
     """Golden-pinned at fixed rng: the Bernoulli x Exp tail arm adds
     ~tail_prob * tail_mean to the mean overshoot and produces samples
-    far beyond the Gaussian arm's reach."""
+    far beyond the Gaussian arm's reach, in both forms of the model."""
     base = SleepModel(base_us=2.8, slope=0.027, sigma_us=0.5)
     tailed = SleepModel(base_us=2.8, slope=0.027, sigma_us=0.5,
                         tail_prob=0.05, tail_mean_us=400.0)
-    targets = np.full(200_000, 50.0)
-    plain = base.sample(targets, np.random.default_rng(3))
-    heavy = tailed.sample(targets, np.random.default_rng(3))
-    extra = float(np.mean(heavy) - np.mean(plain))
-    assert extra == pytest.approx(0.05 * 400.0, rel=0.1)
-    # the tail reaches multi-hundred-us; the Gaussian arm never does
-    assert float(np.max(heavy)) > 1_000.0
-    assert float(np.max(plain)) < 50.0 * 1.1 + 2.8 + 10 * 0.5
+    for plain, heavy in zip(_both_forms(base, 50.0, 200_000, 3),
+                            _both_forms(tailed, 50.0, 200_000, 3)):
+        extra = float(np.mean(heavy) - np.mean(plain))
+        assert extra == pytest.approx(0.05 * 400.0, rel=0.1)
+        # the tail reaches multi-hundred-us; the Gaussian arm never does
+        assert float(np.max(heavy)) > 1_000.0
+        assert float(np.max(plain)) < 50.0 * 1.1 + 2.8 + 10 * 0.5
 
 
 def test_sleep_model_certain_tail_mean_is_pinned():
@@ -92,15 +104,35 @@ def test_sleep_model_certain_tail_mean_is_pinned():
     mean overshoot is base + slope*t + E|N| + tail_mean."""
     m = SleepModel(base_us=5.0, slope=0.0, sigma_us=0.0,
                    tail_prob=1.0, tail_mean_us=200.0)
-    s = m.sample(np.full(100_000, 10.0), np.random.default_rng(9))
-    assert float(np.mean(s)) == pytest.approx(10.0 + 5.0 + 200.0, rel=0.02)
-    assert float(np.min(s)) >= 15.0
+    for s in _both_forms(m, 10.0, 100_000, 9):
+        assert float(np.mean(s)) == pytest.approx(10.0 + 5.0 + 200.0,
+                                                  rel=0.02)
+        assert float(np.min(s)) >= 15.0
 
 
 def test_sleep_model_no_tail_is_affine_plus_halfnormal():
     m = SleepModel(base_us=2.0, slope=0.1, sigma_us=1.0)
-    s = m.sample(np.full(100_000, 20.0), np.random.default_rng(4))
     # mean = t + base + slope*t + sigma*sqrt(2/pi)
     expect = 20.0 + 2.0 + 2.0 + 1.0 * np.sqrt(2.0 / np.pi)
-    assert float(np.mean(s)) == pytest.approx(expect, rel=0.01)
-    assert float(np.min(s)) >= 24.0
+    for s in _both_forms(m, 20.0, 100_000, 4):
+        assert float(np.mean(s)) == pytest.approx(expect, rel=0.01)
+        assert float(np.min(s)) >= 24.0
+
+
+@pytest.mark.parametrize("model", [
+    HR_SLEEP_MODEL, NANOSLEEP_MODEL, PERFECT_SLEEP_MODEL,
+    SleepModel(base_us=5.0, slope=0.0, sigma_us=0.0, tail_prob=0.3,
+               tail_mean_us=200.0)])
+def test_sleep_model_sampler_draws_what_sample_draws(model):
+    """The event engine's scalar sampler consumes the stream as
+    ``sample`` does for one target (the tail's exponential drawn hit or
+    miss) and gives the same values, bit for bit, and the stream is
+    left in the same state."""
+    targets = np.random.default_rng(0).uniform(1.0, 900.0, 2_000).tolist()
+    a, b = np.random.default_rng(21), np.random.default_rng(21)
+    draw = model.sampler(b)
+    for t in targets:
+        want = float(model.sample(t, a))
+        got = draw(t)
+        assert type(got) is float and got == want
+    assert a.random() == b.random()

@@ -157,7 +157,10 @@ def test_fleet_sweep_tail_and_rollup_spans():
     assert _last("fleet.rollup").counts == {"points": 2}
 
 
-def test_table_spans_spot_checks_under_one_table():
+@pytest.fixture(scope="module")
+def spot_checked_table():
+    """A table over three rhos with three spot checks: the config, the
+    table, its span and the spans of its children."""
     cfg = SimRunConfig(duration_us=30_000.0)
     lattice = dict(t_s_grid=np.linspace(4.0, 48.0, 6), t_l_grid=[150.0, 500.0],
                    m_grid=(2, 3), seeds=(0,), slot_us=1.0)
@@ -167,14 +170,31 @@ def test_table_spans_spot_checks_under_one_table():
         m=lattice["m_grid"], rate_mpps=np.asarray(rhos) * cfg.service_rate_mpps,
         seeds=(0,))
     bs = simulate_batch(grid, cfg, slot_us=1.0)
-    build_operating_table(rhos=rhos, target_mean_latency_us=15.0, cfg=cfg,
-                          spot_check=3, sweep=bs, **lattice)
-    table = _last("calibrate.table")
-    kids = _children(table)
+    table = build_operating_table(rhos=rhos, target_mean_latency_us=15.0,
+                                  cfg=cfg, spot_check=3, sweep=bs, **lattice)
+    span = _last("calibrate.table")
+    return cfg, table, span, _children(span)
+
+
+def test_table_spans_spot_checks_under_one_table(spot_checked_table):
+    _, _, table, kids = spot_checked_table
     assert [k.name for k in kids] == ["calibrate.spot_check"] * 3
     assert [k.counts["rho"] for k in kids] == [0, 1, 2]
     assert tracing.self_ns(table) == table.duration_ns - sum(
         k.duration_ns for k in kids)
+
+
+def test_spot_check_spans_count_the_event_engines_work(spot_checked_table):
+    """Each spot check's span carries the wakes of its run, the same
+    point run again outside any span, and the drain rounds."""
+    from repro.runtime.calibrate import _event_sim_point
+
+    cfg, table, _, kids = spot_checked_table
+    for k in kids:
+        p = table.points[k.counts["rho"]]
+        rs = _event_sim_point(p, cfg, p.rho * cfg.service_rate_mpps)
+        assert k.counts["wakes"] == rs.wakeups > 0
+        assert k.counts["drain_rounds"] > 0
 
 
 def test_profiler_trace_holds_the_program_spans(tmp_path):
